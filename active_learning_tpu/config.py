@@ -12,8 +12,8 @@ import dataclasses
 from typing import Any, Dict, Mapping, Optional, Sequence, Tuple
 
 # Fallback budget for device-resident acquisition-scoring pools when the
-# backend exposes no HBM statistics to auto-size from (CPU, some tunneled
-# runtimes) — see TrainConfig.resident_scoring_bytes and
+# backend keeps no HBM statistics to auto-size from (CPU) — see
+# TrainConfig.resident_scoring_bytes and
 # parallel/resident.resolve_budget.
 RESIDENT_SCORING_BYTES_DEFAULT = 2 ** 31
 

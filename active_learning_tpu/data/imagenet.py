@@ -150,6 +150,14 @@ class _DiskImageDataset(Dataset):
         box = int(round(self.image_size * short / self.resize_size))
         return (h - box) // 2, (w - box) // 2, box, box
 
+    @property
+    def decoder(self) -> str:
+        """The decode path gathers take: "native" (native/decode.cpp,
+        built from this checkout on first use) or "pil"."""
+        from . import native
+        return ("native" if self._use_native and native.load() is not None
+                else "pil")
+
     def _native_dims(self, idxs: np.ndarray) -> Optional[np.ndarray]:
         """Per-index (h, w) via the header cache; -1 rows mean libjpeg
         can't handle that file (PIL decodes it instead)."""

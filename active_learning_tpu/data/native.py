@@ -6,14 +6,19 @@ thread pool.  Crop-rectangle RANDOMNESS stays in Python
 (data/imagenet.py) so augmentation remains a pure function of
 (seed, epoch, index).
 
-The library is built lazily with g++ on first use and cached under
-native/build/; if the toolchain or libjpeg is missing, callers fall back to
-the PIL path (``load() returns None``).
+The library is built lazily with g++ on first use under native/build/,
+under a name that carries a hash of ``decode.cpp`` and the compile command:
+only what the source in THIS checkout produces is ever loaded — a binary
+left on disk by another source or another command has another name and is
+ignored.  If the toolchain or libjpeg is missing, callers fall back to the
+PIL path (``load() returns None``); the dataset's ``decoder`` property
+(data/imagenet.py) says which one a run got.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -26,26 +31,43 @@ from ..utils.logging import get_logger
 _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), "native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "build", "libaldata.so")
+
+
+def _build_cmd(src: str, out: str) -> list:
+    return ["g++", "-O3", "-fPIC", "-std=c++17", "-shared", src,
+            "-o", out, "-ljpeg", "-lpthread"]
+
 
 _lock = threading.Lock()
 _lib = None
 _load_failed = False
 
 
-def _build() -> bool:
-    src = os.path.join(_NATIVE_DIR, "decode.cpp")
-    if not os.path.exists(src):
-        return False
-    os.makedirs(os.path.dirname(_SO_PATH), exist_ok=True)
+def so_path(native_dir: str = _NATIVE_DIR) -> Optional[str]:
+    """The one library file a checkout may load:
+    ``<native_dir>/build/libaldata-<hash>.so``, the hash taken over the
+    bytes of ``decode.cpp`` and the compile command.  None without the
+    source."""
+    try:
+        with open(os.path.join(native_dir, "decode.cpp"), "rb") as fh:
+            digest = hashlib.sha256(fh.read())
+    except OSError:
+        return None
+    digest.update(" ".join(_build_cmd("decode.cpp", "libaldata.so")).encode())
+    return os.path.join(native_dir, "build",
+                        f"libaldata-{digest.hexdigest()[:16]}.so")
+
+
+def _build(native_dir: str, target: str) -> bool:
+    os.makedirs(os.path.dirname(target), exist_ok=True)
     # Compile to a process-unique temp name, then rename: the publish is
     # atomic, so concurrent first-users can never dlopen a half-written .so.
-    tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
-    cmd = ["g++", "-O3", "-fPIC", "-std=c++17", "-shared", src,
-           "-o", tmp, "-ljpeg", "-lpthread"]
+    tmp = f"{target}.{os.getpid()}.tmp"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO_PATH)
+        subprocess.run(
+            _build_cmd(os.path.join(native_dir, "decode.cpp"), tmp),
+            check=True, capture_output=True, timeout=120)
+        os.replace(tmp, target)
         return True
     except (subprocess.SubprocessError, FileNotFoundError, OSError) as e:
         get_logger().warning(
@@ -57,32 +79,39 @@ def _build() -> bool:
         return False
 
 
+def _open_library(native_dir: str = _NATIVE_DIR) -> Optional[ctypes.CDLL]:
+    """Build (if its file is not there yet) and open the library of the
+    ``decode.cpp`` in ``native_dir``; None if unavailable.  No other file
+    in ``build/`` is ever opened."""
+    target = so_path(native_dir)
+    if target is None or (not os.path.exists(target)
+                          and not _build(native_dir, target)):
+        return None
+    try:
+        lib = ctypes.CDLL(target)
+    except OSError as e:
+        get_logger().warning(f"native decode load failed ({e!r})")
+        return None
+    lib.al_jpeg_dims.restype = ctypes.c_int
+    lib.al_jpeg_dims.argtypes = [
+        ctypes.POINTER(ctypes.c_char_p), ctypes.c_int,
+        ctypes.POINTER(ctypes.c_int32), ctypes.c_int]
+    lib.al_decode_crop_resize.restype = ctypes.c_int
+    lib.al_decode_crop_resize.argtypes = [
+        ctypes.POINTER(ctypes.c_char_p), ctypes.c_int,
+        ctypes.POINTER(ctypes.c_int32), ctypes.c_int,
+        ctypes.POINTER(ctypes.c_uint8),
+        ctypes.POINTER(ctypes.c_uint8), ctypes.c_int]
+    return lib
+
+
 def load() -> Optional[ctypes.CDLL]:
     """The shared library, building it if needed; None if unavailable."""
     global _lib, _load_failed
     with _lock:
-        if _lib is not None or _load_failed:
-            return _lib
-        if not os.path.exists(_SO_PATH) and not _build():
-            _load_failed = True
-            return None
-        try:
-            lib = ctypes.CDLL(_SO_PATH)
-        except OSError as e:
-            get_logger().warning(f"native decode load failed ({e!r})")
-            _load_failed = True
-            return None
-        lib.al_jpeg_dims.restype = ctypes.c_int
-        lib.al_jpeg_dims.argtypes = [
-            ctypes.POINTER(ctypes.c_char_p), ctypes.c_int,
-            ctypes.POINTER(ctypes.c_int32), ctypes.c_int]
-        lib.al_decode_crop_resize.restype = ctypes.c_int
-        lib.al_decode_crop_resize.argtypes = [
-            ctypes.POINTER(ctypes.c_char_p), ctypes.c_int,
-            ctypes.POINTER(ctypes.c_int32), ctypes.c_int,
-            ctypes.POINTER(ctypes.c_uint8),
-            ctypes.POINTER(ctypes.c_uint8), ctypes.c_int]
-        _lib = lib
+        if _lib is None and not _load_failed:
+            _lib = _open_library()
+            _load_failed = _lib is None
         return _lib
 
 

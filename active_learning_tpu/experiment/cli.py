@@ -240,8 +240,9 @@ def get_parser() -> argparse.ArgumentParser:
                         "pass (exact re-check keeps selection identical "
                         "to 1); 1 = sequential scan")
     p.add_argument("--compilation_cache_dir", type=str, default=None,
-                   help="persistent XLA compilation cache (default "
-                        "~/.cache/al_tpu_xla_cache; '' disables)")
+                   help="persistent XLA compilation cache directory; "
+                        "$JAX_COMPILATION_CACHE_DIR wins when set, the "
+                        "default is <checkout>/.jax_cache, '' sets none")
     # VAAL (parser.py:81-92)
     p.add_argument("--vae_latent_dim", type=int, default=64)
     # Reference spelling (parser.py:84); --adversary_param kept as an alias

@@ -56,20 +56,71 @@ from . import pipeline as pipeline_lib
 from . import resume as resume_lib
 
 
-def _platform_is_cpu() -> bool:
-    """True when the configured JAX platform list names cpu first —
-    WITHOUT initializing a backend (this runs before the multi-host
-    rendezvous on some call paths).  Unset platform config reads as
-    not-CPU: accelerator machines rarely set it, CPU test/smoke
-    environments always do (conftest, the tier-1 recipe, bench's CPU
-    children)."""
-    spec = (os.environ.get("JAX_PLATFORMS") or "")
-    try:
-        spec = jax.config.jax_platforms or spec
-    except AttributeError:  # pragma: no cover - very old jax
-        pass
-    first = spec.split(",")[0].strip().lower() if spec else ""
-    return first == "cpu"
+# The one "is the configured platform CPU" rule (parallel/mesh.py), under
+# the name this module's cache gate — and its tests — know it by.
+_platform_is_cpu = mesh_lib.platform_is_cpu
+
+
+# Where the persistent compilation cache lives when nothing outside the
+# program places it: ONE fixed directory inside the checkout (git-ignored).
+# The path is part of every cache key's lookup, so it must never carry a
+# temporary name, pid or time — a directory that moves never hits.
+DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def resolve_compilation_cache_dir(cache_dir: Optional[str] = None
+                                  ) -> Optional[str]:
+    """WHERE the persistent compilation cache goes — the one rule, no
+    side effects (``enable_compilation_cache`` applies it; bench.py asks
+    it whether a run starts cache-warm).
+
+    The cache is PLACED FROM OUTSIDE: where ``$JAX_COMPILATION_CACHE_DIR``
+    is set, that directory is used whatever ``cache_dir`` says (a
+    launcher that mounts a cache volume must find every entry point
+    caching there and nowhere else).  Unset: ``cache_dir`` (the
+    --compilation_cache_dir flag) if given, else
+    ``DEFAULT_COMPILATION_CACHE_DIR``; ``cache_dir == ""`` asks for
+    none (None).
+
+    CPU backends get NO cache by default (the gate was written against
+    jax 0.4.37, whose CPU runtime corrupted donated buffers in
+    cache-deserialized executables; not re-verified on 0.9.0, and
+    compiles are cheap on CPU anyway): only an EXPLICIT choice — the
+    environment variable or ``cache_dir`` — enables it there.
+    Accelerators are unaffected."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    if cache_dir == "" or (not cache_dir and _platform_is_cpu()):
+        return None
+    return cache_dir or DEFAULT_COMPILATION_CACHE_DIR
+
+
+# Persistent-cache traffic of THIS process, counted off JAX's own
+# monitoring events (a hit = an executable read back from the directory,
+# a miss = one compiled and written there).  The driver journals the
+# counts at every round end: whether a second process really found the
+# first one's programs is otherwise invisible.
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
+                 "/jax/compilation_cache/cache_misses": "misses"}
+_cache_counts = {"hits": 0, "misses": 0}
+
+
+def _count_cache_event(event: str, **_kwargs) -> None:
+    key = _CACHE_EVENTS.get(event)
+    if key is not None:
+        _cache_counts[key] += 1
+
+
+jax.monitoring.register_event_listener(_count_cache_event)
+
+
+def compilation_cache_counts() -> dict:
+    """{"hits", "misses"} of the persistent compilation cache in this
+    process so far."""
+    return dict(_cache_counts)
 
 
 def enable_compilation_cache(cache_dir: Optional[str] = None
@@ -77,53 +128,29 @@ def enable_compilation_cache(cache_dir: Optional[str] = None
     """Turn on JAX's persistent (on-disk) compilation cache for the whole
     process, so AL round N+1 — and the next RUN of the same protocol —
     reuse round N's compiled executables instead of re-paying the
-    cold-compile tax (measured ~58 s of the cold/warm round gap on the
-    CIFAR protocol, BENCH r5).  Shape bucketing (pool.bucket_size in the
-    trainer and k-center) keeps the keys stable as the labeled set grows;
-    this cache keeps the hits across process restarts.
+    cold-compile tax.  Shape bucketing (pool.bucket_size in the trainer
+    and k-center) keeps the keys stable as the labeled set grows; this
+    cache keeps the hits across process restarts.
 
-    ``cache_dir``: None -> $JAX_COMPILATION_CACHE_DIR or
-    ~/.cache/al_tpu_xla_cache; "" disables.  Returns the directory in
-    use, or None when disabled/unavailable (old jax without the config
-    knobs — the run proceeds uncached, never fails).
-
-    CPU backends get NO cache by default: jax 0.4.37's CPU runtime
-    corrupts donated buffers when an executable is deserialized from the
-    persistent cache (a donate_argnums jit re-jitted in-process dies
-    with heap corruption or silently computes on freed memory — the
-    root cause of the once-flaky mid-round-resume tests).  Compiles are
-    cheap on CPU anyway; an EXPLICIT choice — the cache_dir argument OR
-    $JAX_COMPILATION_CACHE_DIR — still enables it (both are deliberate
-    operator opt-ins), and accelerators are unaffected.
-    """
-    if cache_dir == "":
+    The directory comes from ``resolve_compilation_cache_dir``.  When
+    the environment variable placed it, JAX has already read it and NO
+    ``jax_compilation_cache_dir`` update is made here at all.  Returns
+    the directory in use, or None when this call enabled none."""
+    resolved = resolve_compilation_cache_dir(cache_dir)
+    if resolved is None:
+        if cache_dir != "":
+            get_logger().info(
+                "persistent compilation cache off on the CPU backend by "
+                "default; pass --compilation_cache_dir or set "
+                "$JAX_COMPILATION_CACHE_DIR to force it")
         return None
-    # The env var is an explicit operator opt-in, same as the flag — it
-    # must be resolved BEFORE the CPU gate, which suppresses only the
-    # implicit ~/.cache default.
-    cache_dir = cache_dir or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if not cache_dir and _platform_is_cpu():
-        get_logger().info(
-            "persistent compilation cache disabled on the CPU backend "
-            "(jax 0.4.37 corrupts donated buffers in cache-deserialized "
-            "executables); pass --compilation_cache_dir or set "
-            "$JAX_COMPILATION_CACHE_DIR to force it")
-        return None
-    cache_dir = (cache_dir
-                 or os.path.join(os.path.expanduser("~"), ".cache",
-                                 "al_tpu_xla_cache"))
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # Sub-second compiles aren't worth a disk entry; everything else
-        # is (the round tax is dominated by a handful of large modules).
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception as e:  # pragma: no cover - jax-version-dependent
-        get_logger().warning(
-            f"persistent compilation cache unavailable ({e!r}); "
-            "continuing without it")
-        return None
-    return cache_dir
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", resolved)
+    # Sub-second compiles aren't worth a disk entry; everything else
+    # is (the round tax is dominated by a handful of large modules).
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return resolved
 
 
 # The int8 gradient sync's pinned accuracy-delta bound: the probe model
@@ -620,6 +647,64 @@ def _emit_round_telemetry(telemetry, sink: MetricsSink, rd: int,
     telemetry.tick(force=True, phase="round_end", round=rd)
 
 
+def _runtime_record(strategy, xla_cache_dir: Optional[str]) -> dict:
+    """The resolved side of every device-dependent ``auto``: the mesh's
+    devices as JAX reports them, the model's compute dtype, the pool
+    layout, where the resident budget comes from (``memory_stats`` on a
+    device that keeps them, the static default otherwise, ``explicit``
+    under --resident_scoring_bytes), the ImageFolder decode path (None
+    for in-memory datasets), the global evaluation and scoring batches
+    (raised on accelerators) and the persistent compile cache directory
+    (None = off)."""
+    from ..parallel import resident as resident_lib
+    dev = strategy.mesh.devices.flat[0]
+    dtype = getattr(strategy.model, "dtype", None)  # test models: none
+    if strategy.train_cfg.resident_scoring_bytes is not None:
+        budget_source = "explicit"
+    else:
+        budget_source = ("memory_stats"
+                         if resident_lib.local_headroom_stats()
+                         else "static_default")
+    return {
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "device_count": int(strategy.mesh.devices.size),
+        "compute_dtype": None if dtype is None else np.dtype(dtype).name,
+        "pool_sharding": strategy.trainer.pool_sharding,
+        "resident_budget_source": budget_source,
+        "decoder": getattr(strategy.al_set, "decoder", None),
+        "eval_batch": strategy.trainer.eval_batch_size(strategy.al_set),
+        "score_batch": strategy._score_batch_size(),
+        "compilation_cache_dir": xla_cache_dir,
+    }
+
+
+def _placement_record(strategy) -> dict:
+    """WHERE the run's state sits, asked of EVERY local device — a pool
+    or a parameter tree that landed whole on the first chip of a mesh is
+    invisible from device 0's statistics alone.  ``pool_rows``: rows of
+    each pinned pool array held per device id (a quarter each under the
+    row layout on four chips, all of them under replicated);
+    ``param_devices``: how many devices hold the parameters; ``hbm``:
+    each device's ``memory_stats`` in-use and peak bytes (None where the
+    backend keeps none)."""
+    from ..parallel import resident as resident_lib
+    pool_rows = resident_lib.rows_per_device(strategy.trainer.resident_pool)
+    leaves = jax.tree.leaves(strategy.state.params)
+    hbm = []
+    for dev in jax.local_devices():
+        stats = dev.memory_stats() or {}
+        hbm.append({"id": dev.id,
+                    "bytes_in_use": stats.get("bytes_in_use"),
+                    "peak_bytes_in_use": stats.get("peak_bytes_in_use")})
+    return {
+        "pool_rows": pool_rows,
+        "param_devices": (len(leaves[0].sharding.device_set)
+                          if leaves else 0),
+        "hbm": hbm,
+    }
+
+
 def _labeled_crc(pool: PoolState) -> int:
     """CRC of the labeled mask — the round journal's cheap labeled-set
     digest (a resume/retry that diverged would show a different CRC at
@@ -714,7 +799,7 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
                                     cfg.num_processes, cfg.process_id)
     # Persistent executable reuse across rounds AND runs (config update
     # only — safe before or after backend init).
-    enable_compilation_cache(cfg.compilation_cache_dir)
+    xla_cache_dir = enable_compilation_cache(cfg.compilation_cache_dir)
     # Arm the fault-injection registry (DESIGN.md §10) ONLY when a spec
     # is explicitly given — a run with neither --fault_spec nor
     # $AL_FAULT_SPEC must not clobber an arming a test installed
@@ -910,6 +995,14 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
         strategy = build_experiment(cfg, sink=sink, data=data, mesh=mesh,
                                     train_cfg=train_cfg, model=model,
                                     skip_init_pool=resuming)
+        # What the run actually got, as opposed to what the config asked
+        # for — every "auto" resolves against the live device, and the
+        # side it took is otherwise only visible in behavior: journaled
+        # once (and logged) so `status`, post-mortems and chip_smoke.py
+        # read it with no jax import.
+        runtime = _runtime_record(strategy, xla_cache_dir)
+        journal.write(runtime=runtime)
+        logger.info(f"Runtime: {runtime}")
         if getattr(strategy.trainer, "grad_allreduce_degraded", False):
             # The int8 learning probe failed (build_experiment already
             # fell back to f32 and logged): surface it LOUDLY through
@@ -1159,6 +1252,8 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
                 _emit_round_telemetry(telemetry, sink, rd, strategy,
                                       ladder,
                                       retries_baseline=run_retries0)
+                journal.write(compile_cache=compilation_cache_counts(),
+                              placement=_placement_record(strategy))
                 if write_report:
                     row = {
                         "round": rd,
@@ -1172,6 +1267,10 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
                             + (time.monotonic() - run_t0), 3),
                         "phases_s": {k: round(v, 3)
                                      for k, v in phase_s.items()},
+                        # The feed this round's fit resolved and the
+                        # execution form it ran in (trainer.last_feed).
+                        "feed": strategy.trainer.last_feed.get("source"),
+                        "feed_form": strategy.trainer.last_feed.get("form"),
                     }
                     diag = getattr(strategy, "diagnostics", None)
                     if diag is not None:

@@ -32,6 +32,17 @@ from .. import faults
 DATA_AXIS = "data"
 
 
+def platform_is_cpu() -> bool:
+    """True when the configured JAX platform list names cpu first — read
+    from the jax config knob OR the env var, WITHOUT initializing a
+    backend (callers run before the multi-host rendezvous).  Unset reads
+    as not-CPU: accelerator machines rarely set it, CPU test/smoke
+    environments always do (conftest, the tier-1 recipe, bench's CPU
+    children)."""
+    spec = jax.config.jax_platforms or os.environ.get("JAX_PLATFORMS") or ""
+    return spec.split(",")[0].strip().lower() == "cpu"
+
+
 def initialize_distributed(coordinator_address: Optional[str] = None,
                            num_processes: Optional[int] = None,
                            process_id: Optional[int] = None) -> None:
@@ -55,21 +66,11 @@ def initialize_distributed(coordinator_address: Optional[str] = None,
     # backend".  The gloo implementation CAN, and it is how the pod-tier
     # contract is tested without hardware (the 2-process localhost
     # harness in tests/test_pod_tier.py).  Armed only when the
-    # configured platform is CPU — read from the env var OR the jax
-    # config knob (both settable without initializing a backend; a
-    # jax.config.update("jax_platforms", "cpu") launch must arm too);
-    # accelerators keep their native ICI/DCN collectives, and a jax too
-    # old to know the knob just proceeds.
-    spec = os.environ.get("JAX_PLATFORMS") or ""
-    try:
-        spec = jax.config.jax_platforms or spec
-    except AttributeError:  # pragma: no cover - very old jax
-        pass
-    if spec.split(",")[0].strip().lower() == "cpu":
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # pragma: no cover - jax-version-dependent
-            pass
+    # configured platform is CPU (a jax.config.update("jax_platforms",
+    # "cpu") launch must arm too); accelerators keep their native
+    # ICI/DCN collectives.
+    if platform_is_cpu():
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes, process_id=process_id)

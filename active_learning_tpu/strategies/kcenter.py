@@ -69,7 +69,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..parallel import mesh as mesh_lib
@@ -635,7 +635,7 @@ def _build_sharded_fns(mesh, nf: int):
                                                      budget, q),
             mesh=mesh, in_specs=(fspec, vec, vec, vec),
             out_specs=(rep, rep),
-            check_rep=False)(factors, sqn, min_dist, selectable)
+            check_vma=False)(factors, sqn, min_dist, selectable)
 
     @functools.partial(jax.jit, static_argnames=("budget", "randomize"))
     def scan_q1(factors, sqn, min_dist, selectable, key, budget, randomize):
@@ -643,7 +643,7 @@ def _build_sharded_fns(mesh, nf: int):
             lambda f, s, md, sel, k: _scan_body(f, s, md, sel, k, budget,
                                                 randomize),
             mesh=mesh, in_specs=(fspec, vec, vec, vec, rep),
-            out_specs=(rep, rep), check_rep=False)(factors, sqn, min_dist,
+            out_specs=(rep, rep), check_vma=False)(factors, sqn, min_dist,
                                                    selectable, key)
 
     @jax.jit
@@ -651,18 +651,18 @@ def _build_sharded_fns(mesh, nf: int):
         return shard_map(
             _ring_min_body, mesh=mesh,
             in_specs=(fspec, vec, rep, rep, vec), out_specs=vec,
-            check_rep=False)(factors, sqn, cidx, cvalid, min_dist)
+            check_vma=False)(factors, sqn, cidx, cvalid, min_dist)
 
     @jax.jit
     def ring_minimax(factors, sqn, valid):
         return shard_map(
             _ring_minimax_body, mesh=mesh, in_specs=(fspec, vec, vec),
-            out_specs=vec, check_rep=False)(factors, sqn, valid)
+            out_specs=vec, check_vma=False)(factors, sqn, valid)
 
     @jax.jit
     def argmin_valid(row_max, valid):
         return shard_map(_argmin_body, mesh=mesh, in_specs=(vec, vec),
-                         out_specs=rep, check_rep=False)(row_max, valid)
+                         out_specs=rep, check_vma=False)(row_max, valid)
 
     return {"scan_batched": scan_batched, "scan_q1": scan_q1,
             "ring_min": ring_min, "ring_minimax": ring_minimax,

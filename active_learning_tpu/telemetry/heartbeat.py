@@ -1,6 +1,6 @@
 """Liveness: the atomically-rewritten ``heartbeat.json`` + stall watchdog.
 
-A multi-hour pod run whose host stalls (hung NFS, dead tunnel, wedged
+A multi-hour pod run whose host stalls (hung NFS, lost runtime, wedged
 collective) previously produced NO signal at all until the outer timeout
 killed it.  The heartbeat file is the liveness contract: the driver
 ticks it on every progress event (round/phase/epoch/step transitions),

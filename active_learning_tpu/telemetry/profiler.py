@@ -185,7 +185,7 @@ def trace_annotation(name: str):
 def arm_hlo_dump(dump_dir: str) -> Optional[str]:
     """Point XLA's HLO text dump at ``dump_dir`` for the collective-bytes
     table.  XLA parses ``XLA_FLAGS`` once, at backend initialization
-    (verified empirically on jax 0.4.37: set after ``jax.devices()`` the
+    (re-verified on jax 0.9.0: set after ``jax.devices()`` the
     flag is inert; set before, every module compiled in the run lands in
     the dump) — so the driver arms this BEFORE its multi-host rendezvous,
     which is the run's first backend touch on the production CLI path.

@@ -51,15 +51,15 @@ def percentile(values: List[float], q: float) -> Optional[float]:
 
 
 def hbm_high_water_gb() -> Optional[float]:
-    """Peak device HBM in GB via ``memory_stats()`` — None where the
-    backend exposes no statistics (CPU, some tunneled runtimes)."""
-    try:
-        import jax
-        stats = jax.local_devices()[0].memory_stats() or {}
-        peak = stats.get("peak_bytes_in_use")
-        return round(peak / 2**30, 3) if peak else None
-    except Exception:  # noqa: BLE001 - backend-dependent, absence is fine
-        return None
+    """Peak device HBM in GB via ``memory_stats()``, the HIGHEST of the
+    local devices (on a mesh the chip that holds the most is the one
+    that runs out first) — None where the backend keeps no statistics
+    (CPU)."""
+    import jax
+    peaks = [(dev.memory_stats() or {}).get("peak_bytes_in_use")
+             for dev in jax.local_devices()]
+    peaks = [p for p in peaks if p]
+    return round(max(peaks) / 2**30, 3) if peaks else None
 
 
 class RunTelemetry:
@@ -133,10 +133,11 @@ class RunTelemetry:
             jits = dict(self._jits)
         sizes = {}
         for name, fn in jits.items():
-            try:
-                sizes[name] = int(fn._cache_size())
-            except Exception:  # noqa: BLE001 - jax-version-dependent
-                pass
+            # jax.jit wrappers count their compiled specializations; a
+            # registered plain callable has no cache to count.
+            cache_size = getattr(fn, "_cache_size", None)
+            if cache_size is not None:
+                sizes[name] = int(cache_size())
         return sizes
 
     def jit_cache_total(self) -> int:

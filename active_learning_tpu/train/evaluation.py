@@ -88,8 +88,8 @@ def accumulate_metrics(count_iter: Iterator[Dict[str, jnp.ndarray]]
     corrects_byclass, count_byclass, count."""
     # Accumulate WITHOUT fetching: summing device arrays dispatches a tiny
     # async add per batch, and the single np.asarray at the end is the only
-    # host round-trip — a per-batch fetch would serialize the eval pipeline
-    # on a remote/tunneled runtime.
+    # host sync — a per-batch fetch would block the host on every batch
+    # and serialize the eval pipeline.
     totals = None
     for counts in count_iter:
         if totals is None:

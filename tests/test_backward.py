@@ -116,7 +116,7 @@ class TestStemConvVJP:
         hand-written formulas must reproduce autodiff to accumulated
         rounding noise (~1e-10) — the bf16 delta above is rounding
         order, not an algebraic error."""
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             rng = np.random.default_rng(2)
             x = jnp.asarray(rng.normal(size=(2, 10, 10, 12)))
             k = jnp.asarray(rng.normal(size=(4, 4, 12, 8)))
@@ -135,7 +135,7 @@ class TestStemConvVJP:
         then-cast derivation (strictly closer as the contraction
         grows; never worse)."""
         x, k, cot = self._data(seed=3, b=4, hw=16, c=12, f=24)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             x64 = jnp.asarray(np.asarray(x), jnp.float64)
             k64 = jnp.asarray(np.asarray(k), jnp.float64)
             cot64 = jnp.asarray(np.asarray(cot), jnp.float64)
@@ -259,7 +259,7 @@ class TestFusedBNVJP:
             assert float(np.max(np.abs(a32 - b32))) <= tol * ref_mag
 
     def test_f64_identity(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             rng = np.random.default_rng(3)
             x = jnp.asarray(rng.normal(size=(3, 5, 5, 8)) + 0.5)
             scale = jnp.asarray(rng.normal(size=(8,)) + 1.0)
@@ -281,7 +281,7 @@ class TestFusedBNVJP:
 
         from active_learning_tpu.models.resnet import FusedBatchNorm
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             rng = np.random.default_rng(4)
             x = jnp.asarray(rng.normal(size=(4, 5, 5, 6)) + 1.0)
             cot = jnp.asarray(rng.normal(size=x.shape))
@@ -535,7 +535,7 @@ class TestInt8Allreduce:
         """The unit contract on the multi-device CPU mesh: the
         block-scaled int8 sum lands within ndev * scale / 2 of the
         exact f32 psum per element, and is identical across devices."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from active_learning_tpu.parallel import mesh as mesh_lib
@@ -554,7 +554,7 @@ class TestInt8Allreduce:
             return mesh_lib.int8_allreduce({"g": x}, "data")["g"]
 
         got = shard_map(body, mesh=mesh, in_specs=P("data"),
-                        out_specs=P("data"), check_rep=False)(full)
+                        out_specs=P("data"), check_vma=False)(full)
         got = np.asarray(got).reshape(ndev, -1)
         # Replicated result: every device's copy identical.
         assert all(np.array_equal(got[0], got[i]) for i in range(ndev))
@@ -580,7 +580,7 @@ class TestInt8Allreduce:
         """A loss spike must stay VISIBLE: an inf/NaN gradient block
         comes back NaN (like the f32 psum would surface it), never
         quantized to silent zeros."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from active_learning_tpu.parallel import mesh as mesh_lib
@@ -596,7 +596,7 @@ class TestInt8Allreduce:
 
         got = np.asarray(shard_map(
             body, mesh=mesh, in_specs=P("data"), out_specs=P("data"),
-            check_rep=False)(jnp.asarray(local.reshape(-1))))
+            check_vma=False)(jnp.asarray(local.reshape(-1))))
         got = got.reshape(ndev, -1)
         # The poisoned BLOCK is all-NaN; the clean block sums exactly.
         assert np.all(np.isnan(got[0][:block]))
@@ -643,7 +643,7 @@ class TestInt8Allreduce:
                    rng=np.random.default_rng(0))
 
     def test_int_leaves_psum_exactly(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from active_learning_tpu.parallel import mesh as mesh_lib
@@ -656,7 +656,7 @@ class TestInt8Allreduce:
             return mesh_lib.int8_allreduce({"c": v}, "data")["c"]
 
         got = shard_map(body, mesh=mesh, in_specs=P("data"),
-                        out_specs=P("data"), check_rep=False)(x)
+                        out_specs=P("data"), check_vma=False)(x)
         exact = np.asarray(x).reshape(ndev, -1).sum(axis=0)
         assert np.array_equal(np.asarray(got).reshape(ndev, -1)[0], exact)
 

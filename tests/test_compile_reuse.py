@@ -8,6 +8,8 @@ one bucket trigger ZERO new jit compilations, measured directly off the
 jitted functions' compilation caches.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -570,6 +572,7 @@ class TestCompilationCacheConfig:
     def test_driver_enables_persistent_cache(self, tmp_path, monkeypatch):
         from active_learning_tpu.experiment import driver
 
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         target = str(tmp_path / "xla_cache")
         old = jax.config.jax_compilation_cache_dir
         try:
@@ -578,13 +581,66 @@ class TestCompilationCacheConfig:
             assert jax.config.jax_compilation_cache_dir == target
         finally:
             # Undo the process-wide config leak: the rest of the session
-            # must keep running cache-less — jax 0.4.37's CPU backend
-            # corrupts donated buffers in cache-DESERIALIZED executables
-            # (see conftest.py), so a leaked cache dir here could make
-            # any later donating jit nondeterministic.
+            # must keep running cache-less (the CPU default-off gate of
+            # enable_compilation_cache, see conftest.py) — a leaked cache
+            # dir here would turn the cache on for every later test.
             jax.config.update("jax_compilation_cache_dir", old)
 
     def test_empty_string_disables(self):
         from active_learning_tpu.experiment import driver
 
         assert driver.enable_compilation_cache("") is None
+
+    @pytest.mark.parametrize("flag", [None, "flag_cache"])
+    def test_environment_places_the_cache_and_nothing_else_is_set(
+            self, tmp_path, monkeypatch, flag):
+        """Where $JAX_COMPILATION_CACHE_DIR is set, that directory is the
+        one in use whatever the flag says, and NO jax_compilation_cache_dir
+        update is made (JAX read the variable itself at start-up)."""
+        from active_learning_tpu.experiment import driver
+
+        env_dir = str(tmp_path / "env_cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        updates = []
+        real_update = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda k, v: (updates.append(k), real_update(k, v))[1])
+        old_min = jax.config.jax_persistent_cache_min_compile_time_secs
+        try:
+            flag_dir = flag and str(tmp_path / flag)
+            assert driver.resolve_compilation_cache_dir(flag_dir) == env_dir
+            assert driver.enable_compilation_cache(flag_dir) == env_dir
+            assert "jax_compilation_cache_dir" not in updates
+        finally:
+            real_update("jax_persistent_cache_min_compile_time_secs",
+                        old_min)
+
+    def test_unset_gives_the_fixed_in_checkout_path(self, monkeypatch):
+        """No variable, no flag, an accelerator platform: ONE constant
+        path inside the checkout — never a temporary name, pid or time
+        (the path is part of the cache lookup; one that moves never
+        hits)."""
+        from active_learning_tpu.experiment import driver
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setattr(driver, "_platform_is_cpu", lambda: False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert driver.DEFAULT_COMPILATION_CACHE_DIR == os.path.join(
+            repo, ".jax_cache")
+        # Resolution only: enabling it would turn the cache on for the
+        # rest of this CPU test session.
+        assert (driver.resolve_compilation_cache_dir(None)
+                == driver.DEFAULT_COMPILATION_CACHE_DIR)
+        assert (driver.resolve_compilation_cache_dir(None)
+                == driver.resolve_compilation_cache_dir(None))
+
+    def test_cpu_default_stays_off(self, monkeypatch):
+        from active_learning_tpu.experiment import driver
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        old = jax.config.jax_compilation_cache_dir
+        assert driver.resolve_compilation_cache_dir(None) is None
+        assert driver.enable_compilation_cache(None) is None
+        assert jax.config.jax_compilation_cache_dir == old

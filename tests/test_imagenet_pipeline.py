@@ -187,6 +187,71 @@ class TestThreadedPipeline:
         gen.close()  # must not deadlock or leak
 
 
+class TestNativeBuiltFromThisCheckout:
+    """Only what the committed decode.cpp produces is ever loaded: the
+    library's file name carries a hash of the source and the compile
+    command, so a binary left on disk by another source (the build dir is
+    git-ignored, and a copied disk brings it along) has another name.
+    Driven on a private copy of ``native/`` through the functions that
+    take the directory — the process-wide cached handle is never touched
+    (prefetch threads of earlier tests may still be using it)."""
+
+    @pytest.fixture
+    def sandbox(self, tmp_path):
+        import shutil
+
+        from active_learning_tpu.data import native
+        root = tmp_path / "native"
+        (root / "build").mkdir(parents=True)
+        shutil.copy(os.path.join(native._NATIVE_DIR, "decode.cpp"),
+                    root / "decode.cpp")
+        return native, root
+
+    def test_name_follows_the_source(self, sandbox):
+        native, root = sandbox
+        first = native.so_path(str(root))
+        assert os.path.basename(first).startswith("libaldata-")
+        assert os.path.dirname(first) == str(root / "build")
+        with open(root / "decode.cpp", "a") as fh:
+            fh.write("\n// another source\n")
+        assert native.so_path(str(root)) != first
+
+    def test_name_follows_the_command(self, sandbox, monkeypatch):
+        native, root = sandbox
+        first = native.so_path(str(root))
+        real = native._build_cmd
+        monkeypatch.setattr(native, "_build_cmd",
+                            lambda s, o: [*real(s, o), "-DOTHER"])
+        assert native.so_path(str(root)) != first
+
+    def test_no_source_no_library(self, sandbox):
+        native, root = sandbox
+        os.unlink(root / "decode.cpp")
+        (root / "build" / "libaldata.so").write_bytes(b"stale")
+        assert native.so_path(str(root)) is None
+        assert native._open_library(str(root)) is None
+
+    def test_a_foreign_binary_is_refused(self, sandbox):
+        """Stale binaries under the old fixed name AND under another
+        source's hash sit in build/; the library opened is the one built
+        from THIS source (CDLL on either stale file would raise — they
+        are not ELF)."""
+        native, root = sandbox
+        theirs = native.so_path(str(root))
+        with open(root / "decode.cpp", "a") as fh:
+            fh.write("\n// the source this checkout really has\n")
+        mine = native.so_path(str(root))
+        for stale in (root / "build" / "libaldata.so", theirs):
+            with open(stale, "wb") as fh:
+                fh.write(b"built elsewhere from another decode.cpp")
+        assert not os.path.exists(mine)
+        lib = native._open_library(str(root))
+        if lib is None:
+            pytest.skip("no toolchain to build the native library")
+        assert os.path.exists(mine)
+        assert lib._name == mine
+
+
 class TestNativeDecode:
     def test_identity_decode_matches_pil_exactly(self, tmp_path):
         """Whole-image rect + same-size output is a pure decode: must match

@@ -37,7 +37,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from active_learning_tpu.parallel import mesh as mesh_lib
@@ -59,7 +59,7 @@ def _run_sync(fn, x_global):
         return fn({"g": v})["g"]
 
     out = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("data"),),
-                            out_specs=P("data"), check_rep=False))(
+                            out_specs=P("data"), check_vma=False))(
         jnp.asarray(x_global).reshape(-1))
     return np.asarray(out).reshape(NDEV, -1)
 
@@ -127,7 +127,7 @@ class TestMeasuredWireBytes:
             body = lambda v: fn({"g": v})["g"]  # noqa: E731
             return jax.jit(shard_map(
                 body, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
-                check_rep=False)).lower(
+                check_vma=False)).lower(
                     jnp.zeros((n,), jnp.float32)).compile()
 
         ag = prof.hlo_text_collective_bytes(
@@ -158,7 +158,7 @@ class TestMeasuredWireBytes:
             {"g": v}, NDEV, "data")["g"]
         text = jax.jit(shard_map(
             body, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
-            check_rep=False)).lower(
+            check_vma=False)).lower(
                 jnp.zeros((n,), jnp.float32)).compile().as_text()
         table = prof.hlo_text_collective_bytes(text)
         per_shard = n // NDEV
@@ -243,7 +243,7 @@ class TestRingPrimitives:
 
         one, closed = jax.jit(shard_map(
             body, mesh=mesh, in_specs=(P("data"),),
-            out_specs=(P("data"), P("data")), check_rep=False))(
+            out_specs=(P("data"), P("data")), check_vma=False))(
                 jnp.asarray(x))
         # One shift: shard i holds shard i-1's block (right rotation).
         np.testing.assert_array_equal(np.asarray(one),
@@ -268,7 +268,7 @@ class TestRingPrimitives:
 
         out = jax.jit(shard_map(
             body, mesh=mesh, in_specs=(P("data", None), P()),
-            out_specs=P("data", None), check_rep=False))(
+            out_specs=P("data", None), check_vma=False))(
                 jnp.asarray(arr), jnp.asarray(ids))
         got = np.asarray(out)
         want = np.where((ids < NDEV * 4)[:, None], arr[np.minimum(

@@ -506,7 +506,7 @@ class TestPerfReport:
         assert "REGRESSION al_round_cifar warm_s" in err
         assert "REGRESSION resnet18_cifar_train ips_per_chip" in err
         # A phase the latest round simply did not capture is absence,
-        # not regression (the flaky-tunnel rule).
+        # not regression (the absence rule).
         missing = tmp_path / "missing.json"
         missing.write_text(json.dumps({"phases": {
             "kcenter_select": {"ips": 500.0}}}))

@@ -55,7 +55,15 @@ class TestS2DEquivalence:
         variables_s2d = _s2d_variables_from_baseline(variables)
         y_base = np.asarray(base.apply(variables, xf, train=False))
         y_s2d = np.asarray(s2d.apply(variables_s2d, xf, train=False))
-        np.testing.assert_allclose(y_s2d, y_base, rtol=2e-5, atol=2e-5)
+        # The logits are sums of terms up to ~300 in magnitude, so a
+        # small logit carries the rounding of the large terms: the
+        # absolute floor scales with the largest logit (about eight f32
+        # ulps there).  A fixed 2e-5 held on jax 0.4.37's XLA:CPU; the
+        # 0.9.0 conv sums in another order and leaves one small logit
+        # 9e-5 off — the f64 test below shows the fold itself is exact.
+        np.testing.assert_allclose(
+            y_s2d, y_base, rtol=2e-5,
+            atol=1e-6 * float(np.abs(y_base).max()))
         # Host-side pre-transformed input must land in the same place.
         x12 = jnp.asarray(pipeline.space_to_depth(x), jnp.float32)
         y_host = np.asarray(s2d.apply(variables_s2d, x12, train=False))
@@ -65,7 +73,7 @@ class TestS2DEquivalence:
         """The fold itself is exact: in float64 the two stems agree to
         accumulated-rounding noise (~1e-12), proving the f32 delta above
         is summation order, not an algebraic error."""
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             rng = np.random.default_rng(1)
             x = jnp.asarray(rng.normal(size=(1, 32, 32, 3)))
             k7 = jnp.asarray(rng.normal(size=(7, 7, 3, 16)))

@@ -887,9 +887,9 @@ class TestSatelliteFixes:
 
     def test_compilation_cache_default_off_on_cpu(self, tmp_path,
                                                   monkeypatch):
-        """The donation-corruption gate: on a CPU-configured platform
-        the DEFAULT persistent cache stays off; an explicit dir still
-        wins (deliberate operator choice, and what the existing
+        """The CPU gate: on a CPU-configured platform the DEFAULT
+        persistent cache stays off; an explicit dir still wins
+        (deliberate operator choice, and what the existing
         test_compile_reuse config test exercises)."""
         import jax
 
@@ -904,12 +904,13 @@ class TestSatelliteFixes:
             assert driver.enable_compilation_cache(explicit) == explicit
             # $JAX_COMPILATION_CACHE_DIR is the same explicit opt-in as
             # the flag — the CPU gate suppresses only the implicit
-            # default.
+            # default — and it BEATS the flag: the cache is placed from
+            # outside.
             env_dir = str(tmp_path / "env_cache")
             monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
             assert driver.enable_compilation_cache(None) == env_dir
+            assert driver.enable_compilation_cache(explicit) == env_dir
         finally:
             # The enable leaks process-wide jax config; the REST of the
-            # session must keep running cache-less (the very corruption
-            # this gate exists for).
+            # session must keep running cache-less.
             jax.config.update("jax_compilation_cache_dir", old)
