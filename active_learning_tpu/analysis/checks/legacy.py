@@ -15,7 +15,7 @@ documented in the shim's module docstring and DESIGN.md §12; ids here:
   1  phase-timer-span      phase_timer derives its seconds from a span
   2  phase-timer-fork      nobody else defines a phase_timer
   3  phase-timer-import    call sites import it from utils.tracing
-  4  trace-annotation      TraceAnnotation stays behind tracing.annotate
+  4  trace-annotation      TraceAnnotation is opened by SpanTracer.span only
   5  resident-feed         zero-host-copy resident train feed
   6  sharded-selection     row-sharded selection never un-shards
   7  pipeline-coordinator  speculative scorer never syncs the train stream
@@ -42,7 +42,9 @@ TRACING = os.path.join(PKG, "utils", "tracing.py")
 PROFILER = os.path.join(PKG, "telemetry", "profiler.py")
 
 # The one module allowed to touch jax.profiler (TraceAnnotation included):
-# the device-truth layer.  tracing.annotate delegates here.
+# the device-truth layer.  Its trace_annotation is handed to the span
+# tracer as a hook (runtime.start_run) and CALLED nowhere else: one
+# annotation per span, under the span's own name.
 ANNOTATION_WHITELIST = {PROFILER}
 
 _CAPTURE_CALLS = {"start_trace", "stop_trace"}
@@ -95,8 +97,10 @@ def _tree(cache: Optional[AstCache], path: str):
 def check_phase_timer_span(tracing_path: str = TRACING,
                            cache: Optional[AstCache] = None
                            ) -> List[Finding]:
-    """Check 1: ``phase_timer`` itself opens a tracer span and reports
-    the span's own seconds (two clocks = metric/trace drift)."""
+    """Check 1: ``phase_timer`` itself opens a tracer span, reports the
+    span's own seconds (two clocks = metric/trace drift) and opens no
+    device annotation of its own (the span carries one: a second would
+    put two names on one interval)."""
     cache = cache or AstCache()
     problems: List[Finding] = []
     src = cache.source(tracing_path)
@@ -123,6 +127,11 @@ def check_phase_timer_span(tracing_path: str = TRACING,
             "phase-timer-span", tracing_path, 0,
             "phase_timer does not take its seconds from the span (two "
             "clocks = metric/trace drift)"))
+    if re.search(r"\b(annotate|trace_annotation)\(", timer_src):
+        problems.append(_mk(
+            "phase-timer-span", tracing_path, 0,
+            "phase_timer opens a device annotation of its own — the "
+            "span it opens already carries one (two names per span)"))
     return problems
 
 
@@ -189,9 +198,13 @@ def check_phase_timer_import(files=None, tracing_path: str = TRACING,
 def check_trace_annotation(files=None, whitelist=None,
                            cache: Optional[AstCache] = None
                            ) -> List[Finding]:
-    """Check 4: jax.profiler.TraceAnnotation stays behind
-    tracing.annotate (AST-level: docstring mentions are fine, attribute
-    uses are not)."""
+    """Check 4: a device annotation is opened by ``SpanTracer.span``
+    and nobody else.  ``jax.profiler.TraceAnnotation`` stays inside the
+    gate module (AST-level: docstring mentions are fine, attribute uses
+    are not), and the gate's ``trace_annotation`` is CALLED nowhere
+    outside it — the run hands the function to the tracer as its
+    ``annotate`` hook (a reference, not a call), so every annotation has
+    a span of the same name and no span has two."""
     cache = cache or AstCache()
     whitelist = ({os.path.abspath(p) for p in whitelist}
                  if whitelist is not None
@@ -208,9 +221,21 @@ def check_trace_annotation(files=None, whitelist=None,
                     and node.attr == "TraceAnnotation":
                 problems.append(_mk(
                     "trace-annotation", path, node.lineno,
-                    "uses jax.profiler.TraceAnnotation directly — use "
-                    "utils.tracing.annotate so device spans keep one "
-                    "naming convention"))
+                    "uses jax.profiler.TraceAnnotation directly — open a "
+                    "span (SpanTracer.span annotates under the span's "
+                    "own name) so device spans keep one naming "
+                    "convention"))
+            if isinstance(node, ast.Call):
+                fn = node.func
+                called = (fn.attr if isinstance(fn, ast.Attribute)
+                          else fn.id if isinstance(fn, ast.Name) else "")
+                if called == "trace_annotation":
+                    problems.append(_mk(
+                        "trace-annotation", path, node.lineno,
+                        "calls trace_annotation() — a device annotation "
+                        "without a span; open a span instead "
+                        "(SpanTracer.span is the only opener, through "
+                        "its annotate hook)"))
     return problems
 
 
@@ -769,8 +794,8 @@ LEGACY_CHECKERS = (
                    "phase_timer call sites import it from utils.tracing",
                    check_phase_timer_import, files_arg=True),
     _LegacyChecker("trace-annotation",
-                   "jax.profiler.TraceAnnotation stays behind "
-                   "tracing.annotate",
+                   "jax.profiler.TraceAnnotation is opened by "
+                   "SpanTracer.span only",
                    check_trace_annotation, files_arg=True),
     _LegacyChecker("resident-feed",
                    "resident train feed never materializes images on host",

@@ -123,6 +123,10 @@ class Report:
     files_scanned: int = 0
     parse_counts: dict = field(default_factory=dict)
     elapsed_s: float = 0.0
+    # The run's own CPU seconds (the calling thread's): what the
+    # shared-parse budget is held to — the wall also counts whoever else
+    # had the cores (six pytest workers, in the tier-1 run).
+    cpu_s: float = 0.0
 
     @property
     def unsuppressed(self):
@@ -147,6 +151,7 @@ class Report:
             "max_parses_per_file": max(self.parse_counts.values(),
                                        default=0),
             "elapsed_s": round(self.elapsed_s, 3),
+            "cpu_s": round(self.cpu_s, 3),
             "counts": self.counts(),
             "total_findings": len(self.unsuppressed),
             "total_suppressed": len(self.suppressed),

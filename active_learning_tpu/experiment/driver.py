@@ -1183,8 +1183,10 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
                 # work is done, so the completed round is persisted
                 # FIRST and the signal honored at the next boundary.
                 if mesh_lib.is_coordinator():
-                    save_retry.call(resume_lib.save_experiment,
-                                    strategy, cfg)
+                    with tele_spans.get_tracer().span(
+                            "ckpt/save_experiment"):
+                        save_retry.call(resume_lib.save_experiment,
+                                        strategy, cfg)
                 cfg.resume_training = True  # crash after this resumes (main_al.py:181)
                 journal.write(round=rd, phase="round_end",
                               labeled=strategy.pool.num_labeled,
@@ -1200,7 +1202,14 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
                 # capability; a systematic fault re-engages the ladder,
                 # a transient one stays recovered.
                 ladder.relax(rd)
-                snapshot = _round_snapshot(strategy)
+                # A host copy of the model every round (the ladder's
+                # rollback point): a device->host fetch outside the
+                # round span, so it is one of the ckpt/* spans.
+                with tele_spans.get_tracer().span(
+                        "ckpt/round_snapshot", args={"round": rd}) as sp:
+                    snapshot = _round_snapshot(strategy)
+                    sp.args["bytes"] = ckpt_lib.tree_bytes(
+                        snapshot["variables"])
                 for attempt in range(ladder.max_attempts()):
                     try:
                         # The device-truth capture window (DESIGN.md
@@ -1231,53 +1240,66 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
                         if ladder.escalate(exc, rd) is None:
                             raise
                         _restore_round_snapshot(strategy, snapshot, rd)
-                pipe = strategy.pipeline
-                if pipe is not None:
-                    # Scorer busy minus the round's gate contention on
-                    # BOTH sides: chunk busy already excludes the
-                    # scorer's own gate waits (pipeline._score_chunk),
-                    # and the main thread's waits on scorer holds are
-                    # inside the phase walls — leaving them in spec_s
-                    # would double-count serialized time as overlap
-                    # (most visible in drain-mode CPU rounds, where a
-                    # chunk's whole execution can stall the fit).
-                    spec_s = max(
-                        0.0, pipe.take_busy_s()
-                        - strategy.trainer.dispatch_lock.take_wait_s())
-                else:
-                    spec_s = 0.0
-                _emit_overlap_telemetry(
-                    telemetry, sink, rd, round_sp.duration_s, phase_s,
-                    spec_s, pipeline_mode if pipe is not None else "off")
-                _emit_round_telemetry(telemetry, sink, rd, strategy,
-                                      ladder,
-                                      retries_baseline=run_retries0)
-                journal.write(compile_cache=compilation_cache_counts(),
-                              placement=_placement_record(strategy))
-                if write_report:
-                    row = {
-                        "round": rd,
-                        "labeled": int(strategy.pool.num_labeled),
-                        "cumulative_budget":
-                            float(strategy.pool.cumulative_cost),
-                        "test_accuracy": strategy.last_test_acc,
-                        "round_time_s": round(round_sp.duration_s, 3),
-                        "wall_clock_s": round(
-                            report_wall_base
-                            + (time.monotonic() - run_t0), 3),
-                        "phases_s": {k: round(v, 3)
-                                     for k, v in phase_s.items()},
-                        # The feed this round's fit resolved and the
-                        # execution form it ran in (trainer.last_feed).
-                        "feed": strategy.trainer.last_feed.get("source"),
-                        "feed_form": strategy.trainer.last_feed.get("form"),
-                    }
-                    diag = getattr(strategy, "diagnostics", None)
-                    if diag is not None:
-                        row.update(diag.last_row)
-                    report_rows.append(row)
-                    diag_lib.write_run_report(run_report_path,
-                                              report_header, report_rows)
+                # Everything between the round span and the next round:
+                # the per-round emitters, the journal, the run report and
+                # the incremental trace export — host work with the
+                # device idle, so it gets a span of its own.
+                with tele_spans.get_tracer().span(
+                        "round_epilogue", args={"round": rd}) as epilogue:
+                    pipe = strategy.pipeline
+                    if pipe is not None:
+                        # Scorer busy minus the round's gate contention on
+                        # BOTH sides: chunk busy already excludes the
+                        # scorer's own gate waits (pipeline._score_chunk),
+                        # and the main thread's waits on scorer holds are
+                        # inside the phase walls — leaving them in spec_s
+                        # would double-count serialized time as overlap
+                        # (most visible in drain-mode CPU rounds, where a
+                        # chunk's whole execution can stall the fit).
+                        spec_s = max(
+                            0.0, pipe.take_busy_s()
+                            - strategy.trainer.dispatch_lock.take_wait_s())
+                    else:
+                        spec_s = 0.0
+                    _emit_overlap_telemetry(
+                        telemetry, sink, rd, round_sp.duration_s, phase_s,
+                        spec_s, pipeline_mode if pipe is not None else "off")
+                    _emit_round_telemetry(telemetry, sink, rd, strategy,
+                                          ladder,
+                                          retries_baseline=run_retries0)
+                    if telemetry.jit_grown:
+                        # A recompile says which step it was: the
+                        # registered programs whose cache grew since the
+                        # last round's jit_cache_miss_delta.
+                        epilogue.args["recompiled"] = list(
+                            telemetry.jit_grown)
+                    journal.write(compile_cache=compilation_cache_counts(),
+                                  placement=_placement_record(strategy))
+                    if write_report:
+                        row = {
+                            "round": rd,
+                            "labeled": int(strategy.pool.num_labeled),
+                            "cumulative_budget":
+                                float(strategy.pool.cumulative_cost),
+                            "test_accuracy": strategy.last_test_acc,
+                            "round_time_s": round(round_sp.duration_s, 3),
+                            "wall_clock_s": round(
+                                report_wall_base
+                                + (time.monotonic() - run_t0), 3),
+                            "phases_s": {k: round(v, 3)
+                                         for k, v in phase_s.items()},
+                            # The feed this round's fit resolved and the
+                            # execution form it ran in (trainer.last_feed).
+                            "feed": strategy.trainer.last_feed.get("source"),
+                            "feed_form":
+                                strategy.trainer.last_feed.get("form"),
+                        }
+                        diag = getattr(strategy, "diagnostics", None)
+                        if diag is not None:
+                            row.update(diag.last_row)
+                        report_rows.append(row)
+                        diag_lib.write_run_report(run_report_path,
+                                                  report_header, report_rows)
                 if len(strategy.available_query_idxs(shuffle=False)) == 0:
                     logger.info("Finished querying all Images!")
                     break

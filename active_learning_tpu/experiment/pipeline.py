@@ -650,18 +650,19 @@ class RoundPipeline:
         faults.site("spec_scorer")
         gate = self._strategy.trainer.dispatch_lock
         gate.take_wait_s()  # drop waits accrued outside this chunk
-        t0 = time.perf_counter()
-        out = self._score_slice(plan, sl, variables)
-        t1 = time.perf_counter()
+        # The chunk's collect_pool span nests under this one on the
+        # spec-scorer thread and inherits its round.
+        with tele_spans.get_tracer().span(
+                "spec_score_chunk",
+                args={"chunk": chunk_i, "round": tag[0],
+                      "src_epoch": tag[1],
+                      "rows": int(sl.stop - sl.start)}) as sp:
+            out = self._score_slice(plan, sl, variables)
         # Busy = chunk wall minus this thread's time blocked on the
         # dispatch gate (the train stream held it): gate waits are idle,
         # not scoring compute, and counting them would overstate both
         # the overlap accounting and pool_rows_per_sec.
-        busy = max(0.0, (t1 - t0) - gate.take_wait_s())
-        tele_spans.get_tracer().complete(
-            "spec_score_chunk", t0, t1,
-            args={"chunk": chunk_i, "round": tag[0], "src_epoch": tag[1],
-                  "rows": int(sl.stop - sl.start)})
+        busy = max(0.0, sp.duration_s - gate.take_wait_s())
         return out, busy
 
     # -- select-time train prefetch ---------------------------------------
@@ -701,14 +702,13 @@ class RoundPipeline:
         before the driver's strategy.update can race them."""
         tele_spans.get_tracer().name_thread("feed-prefetch")
         strategy = self._strategy
-        t0 = time.perf_counter()
-        try:
-            feed = strategy.trainer.prepare_next_fit(
-                strategy.train_set, labeled_now, expected)
-        except Exception:  # noqa: BLE001 - prefetch is best-effort
-            self.logger.exception("round pipeline: train-feed prefetch "
-                                  "failed (fit resolves from scratch)")
-            return
-        tele_spans.get_tracer().complete(
-            "train_feed_prefetch", t0, time.perf_counter(),
-            args={"feed": feed, "expected_labeled": expected})
+        with tele_spans.get_tracer().span(
+                "train_feed_prefetch",
+                args={"expected_labeled": expected}) as sp:
+            try:
+                sp.args["feed"] = strategy.trainer.prepare_next_fit(
+                    strategy.train_set, labeled_now, expected)
+            except Exception:  # noqa: BLE001 - prefetch is best-effort
+                self.logger.exception(
+                    "round pipeline: train-feed prefetch failed (fit "
+                    "resolves from scratch)")

@@ -392,6 +392,24 @@ def sharded_pool_gather(images, ids, mesh, labels=None):
         out_specs=(img_spec, P(axis)), check_vma=False)(images, labels, ids)
 
 
+def pool_gather(images, ids, mesh, labels=None, sharded: bool = False):
+    """One batch of pool rows (and labels) for a replicated [batch] index
+    vector, batch-sharded — the ONE spelling of the per-batch gather in
+    the scoring/eval runners, the resident batch step and the epoch
+    scan, under the named scope ``pool_gather`` so a device trace says
+    which operations are the gather (scopes are metadata: the compiled
+    program is the same with and without them).  ``sharded`` follows the
+    pool entry's actual layout: shard-local pick + owner psum
+    (sharded_pool_gather) against a full-array index + sharding
+    constraint; both land the batch in the same batch sharding."""
+    with jax.named_scope("pool_gather"):
+        if sharded:
+            return sharded_pool_gather(images, ids, mesh, labels=labels)
+        img = jax.lax.with_sharding_constraint(
+            images[ids], mesh_lib.batch_sharding(mesh))
+        return img if labels is None else (img, labels[ids])
+
+
 # The incremental row update's FIXED window width (rows): every drain,
 # whatever its size, applies as a sequence of exactly-this-wide blocks
 # (the tail block slides back over already-current rows, an identity
@@ -684,47 +702,43 @@ def enforce_budget(cache: Optional[Dict], max_bytes: int) -> list:
     return demoted
 
 
-def get_runner(cache: Dict, step_fn: Callable, mesh,
+def get_runner(cache: Dict, step_fn: Callable, mesh, name: str,
                with_labels: bool = False, sharded: bool = False) -> Callable:
     """Jitted gather+step over a resident pool: rows are picked out on
     device and constrained to the batch sharding, so each batch costs one
-    tiny [batch]-int32 transfer instead of the image rows.  ``sharded``
-    (caller reads it off the entry via mesh_lib.is_row_sharded): the
-    gather goes through sharded_pool_gather — shard-local row pick +
-    owner psum instead of a full-array index — landing the batch in the
-    SAME batch sharding, so the step partitions identically and scores
-    are bit-identical across pool layouts."""
+    tiny [batch]-int32 transfer instead of the image rows.  ``name`` is
+    the caller's name for the program (``run_score_<kind>`` from
+    scoring.collect_pool, ``run_eval`` from Trainer.evaluate): it is set
+    on the function that is jitted, so the device trace shows
+    ``jit_<name>`` and the scoring and evaluation runners are told apart
+    by name.  ``sharded`` (caller reads it off the entry via
+    mesh_lib.is_row_sharded): the gather goes through
+    sharded_pool_gather — shard-local row pick + owner psum instead of a
+    full-array index — landing the batch in the SAME batch sharding, so
+    the step partitions identically and scores are bit-identical across
+    pool layouts."""
     key = (id(step_fn), with_labels, bool(sharded))
     with _CACHE_LOCK:
         steps = cache.setdefault("steps", {})
         if key in steps:
             return steps[key]
-    batch_sharding = mesh_lib.batch_sharding(mesh)
 
     if with_labels:
 
-        @jax.jit
         def run(variables, images, labels, ids, mask):
-            if sharded:
-                img, lab = sharded_pool_gather(images, ids, mesh,
-                                               labels=labels)
-            else:
-                img = jax.lax.with_sharding_constraint(
-                    images[ids], batch_sharding)
-                lab = labels[ids]
+            img, lab = pool_gather(images, ids, mesh, labels=labels,
+                                   sharded=sharded)
             batch = {"image": img, "label": lab, "mask": mask}
             return step_fn(variables, batch)
     else:
 
-        @jax.jit
         def run(variables, images, ids, mask):
-            if sharded:
-                img = sharded_pool_gather(images, ids, mesh)
-            else:
-                img = jax.lax.with_sharding_constraint(
-                    images[ids], batch_sharding)
+            img = pool_gather(images, ids, mesh, sharded=sharded)
             batch = {"image": img, "mask": mask}
             return step_fn(variables, batch)
+
+    run.__name__ = run.__qualname__ = name
+    run = jax.jit(run)
 
     # setdefault under the lock: if another thread built the same runner
     # meanwhile, ONE wins and both callers share it — two live runner

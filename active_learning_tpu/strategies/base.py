@@ -39,6 +39,7 @@ from ..data.core import Dataset
 from ..pool import PoolState
 from ..registry import STRATEGIES
 from ..telemetry import diagnostics as diag_lib
+from ..telemetry import spans as tele_spans
 from ..train import checkpoint as ckpt_lib
 from ..train.trainer import Trainer, TrainState
 from ..utils.logging import get_logger
@@ -171,19 +172,32 @@ class Strategy:
         """Fresh random init every round (so the linear head always resets,
         strategy.py:182-184), then overlay a pretrained SSL/transfer ckpt if
         one is configured (strategy.py:185-196)."""
+        tracer = tele_spans.get_tracer()
         self._init_key, sub = jax.random.split(self._init_key)
         sample = self.train_set.gather(np.zeros(1, dtype=np.int64))
-        if self.state is None:
-            self.state = self.trainer.init_state(sub, sample)
-        else:
-            variables = self.model.init(sub, sample.astype(np.float32),
-                                        train=False)
-            self.state = self.trainer.replace_variables(self.state, variables)
+        # The three reinit/* spans end at an ENQUEUE: model.init and the
+        # device copy behind replace_variables are asynchronous, so the
+        # device work they start may finish under a later span.
+        with tracer.span("reinit/model_init"):
+            if self.state is None:
+                self.state = self.trainer.init_state(sub, sample)
+            else:
+                variables = self.model.init(sub, sample.astype(np.float32),
+                                            train=False)
+                self.state = self.trainer.replace_variables(self.state,
+                                                            variables)
         if self.train_cfg.has_pretrained:
-            from ..utils.pretrained import apply_pretrained
-            variables = apply_pretrained(
-                dict(self.state.variables), self.train_cfg.pretrained)
-            self.state = self.trainer.replace_variables(self.state, variables)
+            from ..utils import pretrained as pretrained_lib
+            cfg = self.train_cfg.pretrained
+            with tracer.span("reinit/pretrained_read"):
+                torch_state = pretrained_lib.load_torch_state_dict(cfg.path)
+            # Key surgery, the torch->flax mapping and the copy to the
+            # device.
+            with tracer.span("reinit/overlay"):
+                variables = pretrained_lib.apply_pretrained(
+                    dict(self.state.variables), cfg, state=torch_state)
+                self.state = self.trainer.replace_variables(self.state,
+                                                            variables)
             self.logger.info(
                 f"Initialized network weights from "
                 f"{self.train_cfg.pretrained.path}")
@@ -193,8 +207,12 @@ class Strategy:
     def load_best_ckpt(self) -> None:
         path = self.weight_paths()["best_ckpt"]
         self.logger.info(f"Loading best ckpt so far from: {path}")
-        variables = ckpt_lib.load_variables(path, like=self.state.variables)
-        self.state = self.trainer.replace_variables(self.state, variables)
+        like = self.state.variables
+        with tele_spans.get_tracer().span(
+                "ckpt/load_best", args={"bytes": ckpt_lib.tree_bytes(like)}):
+            variables = ckpt_lib.load_variables(path, like=like)
+            self.state = self.trainer.replace_variables(self.state,
+                                                        variables)
 
     # -- auxiliary round-level state (resume seam) ------------------------
 
@@ -307,8 +325,9 @@ class Strategy:
         if self.test_set is None:
             self.logger.info("Skipped testing loop, no testing dataset found.")
             return None
-        perf = self.trainer.evaluate(self.state, self.test_set,
-                                     np.arange(len(self.test_set)))
+        with tele_spans.get_tracer().span("test/evaluate"):
+            perf = self.trainer.evaluate(self.state, self.test_set,
+                                         np.arange(len(self.test_set)))
         acc = float(perf["accuracy"])
         self.last_test_acc = acc
         # Calibration (ECE + confidence histogram) piggybacks on the

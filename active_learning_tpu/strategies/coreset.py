@@ -25,6 +25,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from ..telemetry import spans as tele_spans
 from . import kcenter as kcenter_lib
 from .base import Strategy, register_strategy
 from .kcenter import kcenter_greedy
@@ -142,11 +143,12 @@ class CoresetSampler(Strategy):
         factors = self._factors_with_cache(idxs_for_coreset)
         labeled_mask = self.already_labeled_mask()[idxs_for_coreset]
         budget = int(min(len(idxs_for_query), budget))
-        picks = kcenter_greedy(factors, labeled_mask, budget,
-                               randomize=self.randomize, rng=self.rng,
-                               batch_q=self.cfg.kcenter_batch,
-                               mesh=self.mesh,
-                               pool_sharding=self.trainer.pool_sharding)
+        with tele_spans.get_tracer().span("query/select"):
+            picks = kcenter_greedy(factors, labeled_mask, budget,
+                                   randomize=self.randomize, rng=self.rng,
+                                   batch_q=self.cfg.kcenter_batch,
+                                   mesh=self.mesh,
+                                   pool_sharding=self.trainer.pool_sharding)
         # Pick-time distance-to-labeled, captured from the selection
         # scan's own values (telemetry/diagnostics, DESIGN.md §13) —
         # one gated call, picks unaffected.
@@ -237,11 +239,12 @@ class PartitionedCoresetSampler(CoresetSampler):
             factors = self.get_factors(part)
             labeled_mask = np.zeros(len(part), dtype=bool)
             labeled_mask[:len(labeled_parts[i])] = True
-            picks = kcenter_greedy(factors, labeled_mask, cur_budget,
-                                   randomize=self.randomize, rng=self.rng,
-                                   batch_q=self.cfg.kcenter_batch,
-                                   mesh=self.mesh,
-                                   pool_sharding=self.trainer.pool_sharding)
+            with tele_spans.get_tracer().span("query/select"):
+                picks = kcenter_greedy(
+                    factors, labeled_mask, cur_budget,
+                    randomize=self.randomize, rng=self.rng,
+                    batch_q=self.cfg.kcenter_batch, mesh=self.mesh,
+                    pool_sharding=self.trainer.pool_sharding)
             # Per-partition pick distances accumulate into the same
             # round diagnostics (each call refreshes the scan global).
             self._record_pick_dist_diagnostics(

@@ -17,6 +17,7 @@ from typing import Tuple
 
 import numpy as np
 
+from ..telemetry import spans as tele_spans
 from .base import Strategy, register_strategy
 
 
@@ -46,7 +47,8 @@ class MASESampler(Strategy):
             return idxs, 0
         min_margins, _, _ = self.compute_margins(idxs)
         budget = int(min(len(idxs), budget))
-        order = np.argsort(min_margins, kind="stable")[:budget]
+        with tele_spans.get_tracer().span("query/select"):
+            order = np.argsort(min_margins, kind="stable")[:budget]
         return idxs[order], budget
 
 
@@ -66,15 +68,16 @@ class BASESampler(MASESampler):
 
         taken = np.zeros(len(idxs), dtype=bool)
         selected = []
-        for c in range(self.num_classes):
-            quota = budget // self.num_classes + int(
-                c < budget % self.num_classes)
-            if quota == 0:
-                continue
-            dist = np.where(preds == c, min_margins, radii[:, c])
-            dist = np.where(taken, np.inf, dist)
-            picks = np.argsort(dist, kind="stable")[:quota]
-            taken[picks] = True
-            selected.extend(picks.tolist())
+        with tele_spans.get_tracer().span("query/select"):
+            for c in range(self.num_classes):
+                quota = budget // self.num_classes + int(
+                    c < budget % self.num_classes)
+                if quota == 0:
+                    continue
+                dist = np.where(preds == c, min_margins, radii[:, c])
+                dist = np.where(taken, np.inf, dist)
+                picks = np.argsort(dist, kind="stable")[:quota]
+                taken[picks] = True
+                selected.extend(picks.tolist())
         assert len(selected) == len(set(selected))
         return idxs[np.asarray(selected, dtype=np.int64)], budget

@@ -27,12 +27,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..data.augment import apply_view
 from ..data.core import Dataset, ViewSpec
 from ..parallel import mesh as mesh_lib
 from ..pool import bucket_size
 from ..data.pipeline import (batch_index_lists, iterate_batches,
                              padded_batch_layout)
+from ..telemetry import runtime as tele_runtime
+from ..telemetry import spans as tele_spans
+from ..train.evaluation import view_forward
 
 # Registered step-builders (scripts/al_lint.py recompile-hazard): every
 # jax.jit in this module sits inside one of these factories (one step
@@ -107,25 +109,25 @@ def make_prob_stats_step(model, view: ViewSpec) -> Callable:
     score bit-for-bit the offline score at the same batch shape."""
 
     @jax.jit
-    def step(variables, batch):
-        x = apply_view(batch["image"], view, train=False)
-        logits = model.apply(variables, x, train=False)
-        logits32 = logits.astype(jnp.float32)
-        probs = jax.nn.softmax(logits32, axis=-1)
-        logp = jax.nn.log_softmax(logits32, axis=-1)
-        top2, top2_idx = jax.lax.top_k(probs, 2)
-        return {
-            "confidence": top2[:, 0],
-            "margin": top2[:, 0] - top2[:, 1],
-            # -sum p log p via log_softmax; a prob that underflowed to
-            # exactly 0 would make 0 * -inf = NaN, so those entries are
-            # pinned to the limit value 0.
-            "entropy": -jnp.sum(jnp.where(probs > 0, probs * logp, 0.0),
-                                axis=-1),
-            "pred": top2_idx[:, 0].astype(jnp.int32),
-        }
+    def score_prob_stats(variables, batch):
+        logits = view_forward(model, view, variables, batch)
+        with jax.named_scope("score_head"):
+            logits32 = logits.astype(jnp.float32)
+            probs = jax.nn.softmax(logits32, axis=-1)
+            logp = jax.nn.log_softmax(logits32, axis=-1)
+            top2, top2_idx = jax.lax.top_k(probs, 2)
+            return {
+                "confidence": top2[:, 0],
+                "margin": top2[:, 0] - top2[:, 1],
+                # -sum p log p via log_softmax; a prob that underflowed
+                # to exactly 0 would make 0 * -inf = NaN, so those
+                # entries are pinned to the limit value 0.
+                "entropy": -jnp.sum(
+                    jnp.where(probs > 0, probs * logp, 0.0), axis=-1),
+                "pred": top2_idx[:, 0].astype(jnp.int32),
+            }
 
-    return step
+    return score_prob_stats
 
 
 def make_embed_step(model, view: ViewSpec, with_probs: bool = False
@@ -135,20 +137,22 @@ def make_embed_step(model, view: ViewSpec, with_probs: bool = False
     optional softmax margin for MarginClusteringSampler
     (margin_clustering_sampler.py:23-45)."""
 
-    @jax.jit
     def step(variables, batch):
-        x = apply_view(batch["image"], view, train=False)
-        logits, embedding = model.apply(variables, x, train=False,
-                                        return_features=True)
+        logits, embedding = view_forward(model, view, variables, batch,
+                                         return_features=True)
         out = {"embedding": embedding}
         if with_probs:
-            probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-            top2, _ = jax.lax.top_k(probs, 2)
-            out["margin"] = top2[:, 0] - top2[:, 1]
-            out["pred"] = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("score_head"):
+                probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+                top2, _ = jax.lax.top_k(probs, 2)
+                out["margin"] = top2[:, 0] - top2[:, 1]
+                out["pred"] = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return out
 
-    return step
+    # The program's name in a device trace (jit_<name>); collect_pool
+    # names the resident runner after it (run_<name>).
+    step.__name__ = "score_embed_margin" if with_probs else "score_embed"
+    return jax.jit(step)
 
 
 def make_badge_step(model, view: ViewSpec, pool_512: bool = False
@@ -171,26 +175,26 @@ def make_badge_step(model, view: ViewSpec, pool_512: bool = False
     """
     from .kcenter import adaptive_avg_pool_matrix
 
-    @jax.jit
     def step(variables, batch):
-        x = apply_view(batch["image"], view, train=False)
-        logits, embedding = model.apply(variables, x, train=False,
-                                        return_features=True)
-        logits = logits.astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        pred = jnp.argmax(logits, axis=-1)
-        a = probs - jax.nn.one_hot(pred, logits.shape[-1],
-                                   dtype=jnp.float32)
-        e = embedding
-        if pool_512:
-            c, d = a.shape[1], e.shape[1]
-            pool_h = min(16, c)
-            pool_w = int(512 / pool_h)
-            a = a @ jnp.asarray(adaptive_avg_pool_matrix(c, pool_h))
-            e = e @ jnp.asarray(adaptive_avg_pool_matrix(d, pool_w))
-        return {"grad_a": a, "grad_e": e}
+        logits, embedding = view_forward(model, view, variables, batch,
+                                         return_features=True)
+        with jax.named_scope("score_head"):
+            logits = logits.astype(jnp.float32)
+            probs = jax.nn.softmax(logits, axis=-1)
+            pred = jnp.argmax(logits, axis=-1)
+            a = probs - jax.nn.one_hot(pred, logits.shape[-1],
+                                       dtype=jnp.float32)
+            e = embedding
+            if pool_512:
+                c, d = a.shape[1], e.shape[1]
+                pool_h = min(16, c)
+                pool_w = int(512 / pool_h)
+                a = a @ jnp.asarray(adaptive_avg_pool_matrix(c, pool_h))
+                e = e @ jnp.asarray(adaptive_avg_pool_matrix(d, pool_w))
+            return {"grad_a": a, "grad_e": e}
 
-    return step
+    step.__name__ = "score_badge_pool" if pool_512 else "score_badge"
+    return jax.jit(step)
 
 
 @jax.jit
@@ -280,15 +284,16 @@ def make_mase_step(model, view: ViewSpec) -> Callable:
     cache: Dict[str, Any] = {}
 
     @jax.jit
-    def jitted_step(variables, batch, pair_norms):
-        x = apply_view(batch["image"], view, train=False)
-        _, embedding = model.apply(variables, x, train=False,
-                                   return_features=True)
-        kernel = variables["params"]["linear"]["kernel"]
-        bias = variables["params"]["linear"]["bias"]
-        out = boundary_radii(embedding, kernel, bias, pair_norms=pair_norms)
-        out["min_margin"] = jnp.min(out["radii"], axis=-1)
-        return out
+    def score_mase(variables, batch, pair_norms):
+        _, embedding = view_forward(model, view, variables, batch,
+                                    return_features=True)
+        with jax.named_scope("score_head"):
+            kernel = variables["params"]["linear"]["kernel"]
+            bias = variables["params"]["linear"]["bias"]
+            out = boundary_radii(embedding, kernel, bias,
+                                 pair_norms=pair_norms)
+            out["min_margin"] = jnp.min(out["radii"], axis=-1)
+            return out
 
     def step(variables, batch):
         kernel = variables["params"]["linear"]["kernel"]
@@ -299,14 +304,15 @@ def make_mase_step(model, view: ViewSpec) -> Callable:
             # pools are in-memory/CIFAR-scale, where the C-step map is
             # trivial; the C=1000 disk datasets always take the host path
             # below.
-            return jitted_step(variables, batch, None)
+            return score_mase(variables, batch, None)
         # Identity (not equality) check; holding the reference keeps the
         # id from being reused by a different array.
         if cache.get("kernel") is not kernel:
             cache["kernel"] = kernel
             cache["norms"] = head_pair_norms(kernel)
-        return jitted_step(variables, batch, cache["norms"])
+        return score_mase(variables, batch, cache["norms"])
 
+    step.__name__ = "score_mase"
     return step
 
 
@@ -439,31 +445,6 @@ def collect_pool(
     if n == 0:
         raise ValueError("collect_pool called with empty idxs; guard the "
                          "exhausted-pool case in the sampler")
-    # Telemetry: chunk-granular spans + heartbeat ticks over the pool
-    # scan (experiment → round → phase → collect_pool chunk in the
-    # trace).  A chunk is the streaming path's flush unit (FETCH_EVERY
-    # batches); both objects are inert no-ops unless a run installed
-    # telemetry.
-    from ..telemetry import runtime as tele_runtime
-    from ..telemetry import spans as tele_spans
-    tracer = tele_spans.get_tracer()
-    tele = tele_runtime.get_run()
-    t_pool0 = time.perf_counter()
-    # Bulk-fetch cadence of the streaming path AND the chunk-span/tick
-    # granularity of both paths (single-process: keep per-batch outputs
-    # ON DEVICE and fetch every FETCH_EVERY batches — a per-batch
-    # np.asarray blocks the host on that batch's compute and serializes
-    # the whole pipeline — how much it costs on the v5e is not measured;
-    # deferred fetches let async dispatch overlap decode, h2d, and
-    # compute, bounding extra HBM to ~FETCH_EVERY batches of outputs).
-    FETCH_EVERY = 32
-
-    def chunk_span(t0: float, first_batch: int, n_batches: int,
-                   rows: int) -> None:
-        tracer.complete(
-            "collect_pool_chunk", t0, time.perf_counter(),
-            args={"batches": n_batches, "first_batch": first_batch,
-                  "rows": rows})
     # Device-resident fast path for in-memory pools: upload once per
     # experiment (the caller owns ``resident_cache``), then every batch of
     # every round's every sampler is an on-device gather — zero image
@@ -476,47 +457,97 @@ def collect_pool(
     # runner follows the ENTRY's actual layout either way.
     shard_ways = (mesh.devices.size
                   if pool_sharding == "row" and mesh is not None else 1)
-    if (resident_cache is not None
-            and resident_lib.eligible(dataset, resident_max_bytes,
-                                      cache=resident_cache,
-                                      shard_ways=shard_ways)):
-        images_dev, _ = resident_lib.pool_arrays(resident_cache, dataset,
-                                                 mesh,
-                                                 sharding=pool_sharding)
-        run = resident_lib.get_runner(
-            resident_cache, step_fn, mesh,
-            sharded=mesh_lib.is_row_sharded(images_dev))
-        multi = mesh_lib.is_multiprocess(mesh)
-        chunks: Dict[str, list] = {}
-        t_chunk, chunk_first = t_pool0, 0
-        for i, b in enumerate(batch_index_lists(idxs, batch_size)):
-            ids, mask = padded_batch_layout(b, batch_size)
-            with dispatch_lock:
-                small = mesh_lib.replicate((ids.astype(np.int32), mask),
-                                           mesh)
-                out = run(variables, images_dev, *small)
-                dispatch_lock.drain(out)
-            if keys is not None:
-                out = {k: out[k] for k in keys}
-            for k, v in out.items():
-                # Keep DEVICE arrays: a per-batch np.asarray would block on
-                # each batch and stall async dispatch (the host path hides
-                # that sync behind its threaded decode; here there is no
-                # host work to overlap).  One fetch at the end.
-                chunks.setdefault(k, []).append(v)
-            if (i + 1) % FETCH_EVERY == 0:
-                tele.tick(step=i + 1)
-                chunk_span(t_chunk, chunk_first, i + 1 - chunk_first,
-                           min((i + 1) * batch_size, n))
-                t_chunk, chunk_first = time.perf_counter(), i + 1
-        if i + 1 > chunk_first:
-            chunk_span(t_chunk, chunk_first, i + 1 - chunk_first, n)
-        tracer.complete("collect_pool", t_pool0, time.perf_counter(),
-                        args={"rows": n, "path": "resident"})
-        if multi:
-            return _finalize(chunks, True, mesh, n)
-        return {k: np.asarray(jnp.concatenate(v, axis=0))[:n]
-                for k, v in chunks.items()}
+    resident = (resident_cache is not None
+                and resident_lib.eligible(dataset, resident_max_bytes,
+                                          cache=resident_cache,
+                                          shard_ways=shard_ways))
+    # ONE span over the whole pass on either path (it also runs on the
+    # spec-scorer thread, under whatever span is open there), closed
+    # AFTER the final fetch: its end is where the host has the scores,
+    # not where the last batch was enqueued.  rows_run counts the padded
+    # last batch too — what the device executes.
+    with tele_spans.get_tracer().span("collect_pool", args={
+            "rows": n, "path": "resident" if resident else "stream"}) as sp:
+        if resident:
+            out, batches = _collect_resident(
+                dataset, idxs, batch_size, step_fn, variables, mesh, keys,
+                resident_cache, pool_sharding, dispatch_lock)
+        else:
+            out, batches = _collect_stream(
+                dataset, idxs, batch_size, step_fn, variables, mesh,
+                num_workers, prefetch, keys, host_s2d, dispatch_lock)
+        sp.args.update(batches=batches, rows_run=batches * batch_size)
+    return out
+
+
+# Bulk-fetch cadence of the streaming path AND the heartbeat-tick
+# granularity of both paths (single-process: keep per-batch outputs ON
+# DEVICE and fetch every FETCH_EVERY batches — a per-batch np.asarray
+# blocks the host on that batch's compute and serializes the whole
+# pipeline — how much it costs on the v5e is not measured; deferred
+# fetches let async dispatch overlap decode, h2d, and compute, bounding
+# extra HBM to ~FETCH_EVERY batches of outputs).
+FETCH_EVERY = 32
+
+
+def _runner_name(step_fn: Callable) -> str:
+    """``run_score_<kind>``: the resident runner's program name, after
+    the step it wraps (the factories above name theirs ``score_<kind>``)."""
+    kind = getattr(step_fn, "__name__", "step")
+    return "run_score_" + kind.removeprefix("score_")
+
+
+def _collect_resident(dataset, idxs, batch_size, step_fn, variables, mesh,
+                      keys, resident_cache, pool_sharding, dispatch_lock
+                      ) -> Tuple[Dict[str, np.ndarray], int]:
+    """collect_pool over the pinned pool: one jitted gather+step per
+    batch, outputs kept on the device, ONE fetch at the end."""
+    n = len(idxs)
+    tele = tele_runtime.get_run()
+    images_dev, _ = resident_lib.pool_arrays(resident_cache, dataset, mesh,
+                                             sharding=pool_sharding)
+    run = resident_lib.get_runner(
+        resident_cache, step_fn, mesh, _runner_name(step_fn),
+        sharded=mesh_lib.is_row_sharded(images_dev))
+    chunks: Dict[str, list] = {}
+    for i, b in enumerate(batch_index_lists(idxs, batch_size)):
+        ids, mask = padded_batch_layout(b, batch_size)
+        with dispatch_lock:
+            small = mesh_lib.replicate((ids.astype(np.int32), mask), mesh)
+            out = run(variables, images_dev, *small)
+            dispatch_lock.drain(out)
+        if keys is not None:
+            out = {k: out[k] for k in keys}
+        for k, v in out.items():
+            # Keep DEVICE arrays: a per-batch np.asarray would block on
+            # each batch and stall async dispatch (the host path hides
+            # that sync behind its threaded decode; here there is no
+            # host work to overlap).  One fetch at the end.
+            chunks.setdefault(k, []).append(v)
+        if (i + 1) % FETCH_EVERY == 0:
+            tele.tick(step=i + 1)
+    if mesh_lib.is_multiprocess(mesh):
+        return _finalize(chunks, True, mesh, n), i + 1
+    return {k: np.asarray(jnp.concatenate(v, axis=0))[:n]
+            for k, v in chunks.items()}, i + 1
+
+
+def _collect_stream(dataset, idxs, batch_size, step_fn, variables, mesh,
+                    num_workers, prefetch, keys, host_s2d, dispatch_lock
+                    ) -> Tuple[Dict[str, np.ndarray], int]:
+    """collect_pool over a pool that is not pinned: the double-buffered
+    host feed, a bulk fetch (and a ``collect_pool_chunk`` span that ends
+    at it) every FETCH_EVERY batches."""
+    n = len(idxs)
+    tracer = tele_spans.get_tracer()
+    tele = tele_runtime.get_run()
+
+    def chunk_span(t0: float, first_batch: int, n_batches: int,
+                   rows: int) -> None:
+        tracer.complete(
+            "collect_pool_chunk", t0, time.perf_counter(),
+            args={"batches": n_batches, "first_batch": first_batch,
+                  "rows": rows})
     # On a multi-host mesh each process gathers/decodes only its own rows
     # of every global batch; score rows come back in GLOBAL batch order
     # (mesh_lib.fetch all-gathers sharded outputs), so the global row
@@ -586,6 +617,4 @@ def collect_pool(
         flush()
     if i + 1 > chunk_first:
         chunk_span(t_chunk, chunk_first, i + 1 - chunk_first, n)
-    tracer.complete("collect_pool", t_pool0, time.perf_counter(),
-                    args={"rows": n, "path": "stream"})
-    return _finalize(chunks, multi, mesh, n)
+    return _finalize(chunks, multi, mesh, n), i + 1

@@ -13,6 +13,7 @@ from typing import Tuple
 
 import numpy as np
 
+from ..telemetry import spans as tele_spans
 from .base import Strategy, register_strategy
 
 
@@ -40,7 +41,8 @@ class _ScoreAscendingSampler(Strategy):
         scores = self.collect_scores(idxs, "prob_stats",
                                      keys=(self.score_key,))[self.score_key]
         budget = int(min(len(idxs), budget))
-        order = np.argsort(scores, kind="stable")[:budget]
+        with tele_spans.get_tracer().span("query/select"):
+            order = np.argsort(scores, kind="stable")[:budget]
         return idxs[order], budget
 
 

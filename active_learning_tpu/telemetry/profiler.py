@@ -172,14 +172,15 @@ def capture_window(out_dir: str, label: str = "capture"):
         finish_capture(handle)
 
 
-@contextlib.contextmanager
 def trace_annotation(name: str):
-    """Name the enclosed host span in device profiler traces; free when
-    no trace is active.  ``utils.tracing.annotate`` delegates here — one
-    device-naming convention, one module touching jax.profiler."""
+    """The context manager that names the enclosed host interval in the
+    profiler's own trace (its host plane, on the device planes' clock);
+    a flag test when no trace is open, whoever would have opened it.
+    ``SpanTracer.span`` is the one caller (the run installs this
+    function as the tracer's ``annotate`` hook, runtime.start_run) — one
+    name per span, one module touching jax.profiler."""
     import jax.profiler
-    with jax.profiler.TraceAnnotation(name):
-        yield
+    return jax.profiler.TraceAnnotation(name)
 
 
 def arm_hlo_dump(dump_dir: str) -> Optional[str]:

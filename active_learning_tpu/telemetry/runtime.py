@@ -28,6 +28,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional
 
 from . import heartbeat as hb_lib
+from . import profiler as profiler_lib
 from . import prom as prom_lib
 from . import spans as spans_lib
 
@@ -36,7 +37,7 @@ from . import spans as spans_lib
 # driver thread and read by the watchdog/status paths — always under
 # the run's _lock.
 _GUARDED_BY = {"_gauges": "_lock", "_jits": "_lock",
-               "_jit_total_last": "_lock"}
+               "_jit_sizes_last": "_lock"}
 
 
 def percentile(values: List[float], q: float) -> Optional[float]:
@@ -85,7 +86,10 @@ class RunTelemetry:
         self._lock = threading.Lock()
         self._gauges: Dict[str, float] = {}
         self._jits: Dict[str, Any] = {}
-        self._jit_total_last = 0
+        self._jit_sizes_last: Dict[str, int] = {}
+        # Registered programs whose cache grew in the last
+        # jit_cache_delta() window — the names behind a nonzero delta.
+        self.jit_grown: List[str] = []
         self.finished = False
 
     # -- progress ----------------------------------------------------------
@@ -144,11 +148,16 @@ class RunTelemetry:
         return sum(self.jit_cache_sizes().values())
 
     def jit_cache_delta(self) -> int:
-        """Compiles since the last call — the per-round miss delta."""
-        total = self.jit_cache_total()
+        """Compiles since the last call — the per-round miss delta.
+        ``jit_grown`` then names the registered programs that compiled,
+        so a recompile in a window says which step it was."""
+        sizes = self.jit_cache_sizes()
         with self._lock:
-            delta = total - self._jit_total_last
-            self._jit_total_last = total
+            last = self._jit_sizes_last
+            self.jit_grown = sorted(n for n, size in sizes.items()
+                                    if size > last.get(n, 0))
+            delta = sum(sizes.values()) - sum(last.values())
+            self._jit_sizes_last = sizes
         return delta
 
     # -- lifecycle ---------------------------------------------------------
@@ -224,7 +233,12 @@ def start_run(cfg, log_dir: str, process_index: int = 0,
         static_fields={"process_index": process_index,
                        "process_count": process_count,
                        "status": "running"})
-    tracer = spans_lib.SpanTracer(enabled=cfg.export_trace)
+    # Recording is opt-in (export_trace); the device annotation is not:
+    # with the recorder off a span is still a name in any profiler trace
+    # that happens to be open (--profile_rounds, a benchmark's own).
+    tracer = spans_lib.SpanTracer(
+        enabled=cfg.export_trace,
+        annotate=profiler_lib.trace_annotation)
     trace_path = (os.path.join(log_dir, f"trace{suffix}.json")
                   if cfg.export_trace else None)
     watchdog = None

@@ -3,13 +3,19 @@
 The framework's only run-time timing signal used to be the driver's
 round-granularity ``phase_timer`` wall-clocks — nothing between "a round
 took 219 s" and a full XLA profiler capture.  This tracer fills that gap
-with nested HOST spans (experiment → round → phase → epoch →
-collect_pool chunk) recorded at perf_counter resolution and exported as
-Chrome trace-event JSON (``trace.json``), loadable in Perfetto or
-``chrome://tracing`` with zero extra tooling.  Device-side naming stays
-with ``jax.profiler.TraceAnnotation`` (utils/tracing.annotate) — the
-two nest: every phase span still wraps its annotation, so an XProf
-capture and the host trace describe the same intervals.
+with ONE span tree per round (experiment → round → phase → the work
+inside the phase: ``collect_pool``, ``reinit/*``, ``fit/*``, ``epoch``,
+``ckpt/*``, ``test/evaluate`` — DESIGN.md §7 has the table) recorded at
+perf_counter resolution and exported as Chrome trace-event JSON
+(``trace.json``), loadable in Perfetto or ``chrome://tracing`` with zero
+extra tooling.  Every span carries an ``id``, the ``parent`` id (the top
+of the opening thread's stack) and the ``round`` it belongs to, so self
+time is computed from the record (``self_seconds``), never guessed from
+containment.  While an XLA profiler trace is open, every ``span()`` is
+also a ``jax.profiler.TraceAnnotation`` of the same name (through the
+``annotate`` hook the run installs — telemetry/profiler.trace_annotation,
+the one gated route), so the profiler's host plane holds the program's
+spans on the device's own clock.
 
 Design constraints, each load-bearing:
 
@@ -24,17 +30,19 @@ Design constraints, each load-bearing:
     the buffer is capped (oldest runs are multi-hour — an unbounded
     event list is a slow leak) with an explicit drop counter.
   * **No jax dependency.**  Importable from the status verb and tests
-    without touching a backend.
+    without touching a backend; the device annotation is a callable the
+    run hands in (telemetry/runtime.start_run), never an import here.
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import json
 import os
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Callable, Dict, Iterator, List, Optional
 
 # Lock discipline, statically enforced (scripts/al_lint.py
 # lock-discipline): the event buffer, its drop counter, and the
@@ -45,14 +53,30 @@ _GUARDED_BY = {"events": "_lock", "dropped": "_lock",
                "_thread_names": "_lock"}
 
 
+# Span ids are process-wide (one counter, not one per tracer): a span
+# recorded by the spec-scorer thread and one recorded by the main thread
+# can never collide, and ids stay unique across a run that swaps tracers.
+_SPAN_IDS = itertools.count(1)
+
+
 class Span:
-    """One completed (or in-flight) host span."""
+    """One completed (or in-flight) host span.  ``parent`` is the id of
+    the span that was open on the SAME thread when this one opened
+    (None at a thread's root); ``round`` is taken from ``args`` when
+    given there, else inherited from the parent."""
 
-    __slots__ = ("name", "args", "t0", "t1", "tid")
+    __slots__ = ("name", "args", "t0", "t1", "tid", "id", "parent",
+                 "round")
 
-    def __init__(self, name: str, args: Optional[Dict[str, Any]] = None):
+    def __init__(self, name: str, args: Optional[Dict[str, Any]] = None,
+                 parent: Optional["Span"] = None):
         self.name = name
         self.args = args
+        self.id = next(_SPAN_IDS)
+        self.parent = parent.id if parent is not None else None
+        rd = args.get("round") if args else None
+        self.round = (rd if rd is not None
+                      else parent.round if parent is not None else None)
         self.t0 = time.perf_counter()
         self.t1: Optional[float] = None
         self.tid = threading.get_ident()
@@ -66,8 +90,12 @@ class Span:
 class SpanTracer:
     """Records nested host spans; exports one Chrome trace per run."""
 
-    def __init__(self, enabled: bool = True, max_events: int = 200_000):
+    def __init__(self, enabled: bool = True, max_events: int = 200_000,
+                 annotate: Optional[Callable[[str], Any]] = None):
         self.enabled = bool(enabled)
+        # name -> context manager that names the enclosed interval in an
+        # open XLA profiler trace (a flag test when none is open).
+        self.annotate = annotate
         self.max_events = int(max_events)
         self.events: List[Dict[str, Any]] = []
         self.dropped = 0
@@ -99,12 +127,18 @@ class SpanTracer:
              ) -> Iterator[Span]:
         """Open a nested span.  Always measures; records only when
         enabled.  The yielded Span's ``duration_s`` is valid after the
-        block exits (phase_timer reads it for the metrics sink)."""
-        sp = Span(name, args)
+        block exits (phase_timer reads it for the metrics sink).  A site
+        that learns a count only inside the block (steps run, bytes
+        written) adds it to ``sp.args`` before the block exits."""
         stack = self._stack()
+        sp = Span(name, args, parent=stack[-1] if stack else None)
         stack.append(sp)
         try:
-            yield sp
+            if self.annotate is None:
+                yield sp
+            else:
+                with self.annotate(name):
+                    yield sp
         finally:
             sp.t1 = time.perf_counter()
             stack.pop()
@@ -114,11 +148,13 @@ class SpanTracer:
     def complete(self, name: str, t0: float, t1: float,
                  args: Optional[Dict[str, Any]] = None) -> None:
         """Record a span retroactively from perf_counter stamps — for
-        loop bodies (collect_pool chunks) where a ``with`` per chunk
-        would contort the control flow."""
+        loop bodies only (the stream path's collect_pool chunks), where
+        a ``with`` per chunk would contort the control flow.  Its parent
+        is whatever span is open on the calling thread at the time of
+        the call; a retroactive span cannot be a device annotation."""
         if not self.enabled:
             return
-        sp = Span(name, args)
+        sp = Span(name, args, parent=self.current())
         sp.t0, sp.t1 = t0, t1
         self._record(sp)
 
@@ -198,8 +234,8 @@ class SpanTracer:
             "dur": (sp.t1 - sp.t0) * 1e6,
             "pid": os.getpid(), "tid": sp.tid % 2**31,
         }
-        if sp.args:
-            event["args"] = dict(sp.args)
+        event["args"] = {**(sp.args or {}), "id": sp.id,
+                         "parent": sp.parent, "round": sp.round}
         with self._lock:
             if len(self.events) >= self.max_events:
                 self.dropped += 1
@@ -223,6 +259,10 @@ class SpanTracer:
             "displayTimeUnit": "ms",
             "otherData": {
                 "wall_origin": self._wall_origin,
+                # ts is microseconds after this perf_counter stamp: a
+                # reader that holds another perf_counter reading (the
+                # benchmark's trace anchor) places spans on its clock.
+                "perf_origin": self._origin,
                 "dropped_events": dropped,
                 **(metadata or {}),
             },
@@ -234,6 +274,31 @@ class SpanTracer:
             json.dump(out, fh)
         os.replace(tmp, path)
         return path
+
+
+def self_seconds(events: List[Dict[str, Any]]) -> Dict[int, float]:
+    """Self time per span id, from exported events: a span's duration
+    minus the union of its children's intervals on the same thread
+    (clipped to the span).  Children are the events whose
+    ``args.parent`` is the span's id; a child on ANOTHER thread ran
+    beside its parent, not inside it, and takes nothing away."""
+    spans = [e for e in events
+             if e.get("ph") == "X" and "id" in (e.get("args") or {})]
+    children: Dict[Any, List[Dict[str, Any]]] = {}
+    for e in spans:
+        children.setdefault((e["args"]["parent"], e["tid"]), []).append(e)
+    out: Dict[int, float] = {}
+    for e in spans:
+        t0, t1 = e["ts"], e["ts"] + e["dur"]
+        covered, edge = 0.0, t0
+        for c in sorted(children.get((e["args"]["id"], e["tid"]), ()),
+                        key=lambda c: c["ts"]):
+            a, b = max(c["ts"], edge), min(c["ts"] + c["dur"], t1)
+            if b > a:
+                covered += b - a
+                edge = b
+        out[e["args"]["id"]] = (e["dur"] - covered) / 1e6
+    return out
 
 
 # The process-wide tracer: disabled (timing-only) until a run installs a

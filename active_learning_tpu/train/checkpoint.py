@@ -37,6 +37,13 @@ from .. import faults
 MODEL_FORMAT_VERSION = 2
 
 
+def tree_bytes(tree: Any) -> int:
+    """Bytes the arrays of a pytree hold, from their shapes (no fetch) —
+    the ``bytes`` counter of the ``ckpt/*`` spans."""
+    return int(sum(getattr(leaf, "nbytes", 0)
+                   for leaf in jax.tree.leaves(tree)))
+
+
 def save_variables(path: str, variables: Dict[str, Any]) -> None:
     """Atomic write (tmp + rename): a reader never sees a half-written
     checkpoint — mid-round resume (experiment/resume.py) and non-writer

@@ -67,16 +67,28 @@ def batch_metric_counts(logits: jnp.ndarray, labels: jnp.ndarray,
 _STEP_BUILDERS = ("make_eval_step",)
 
 
+def view_forward(model, view: ViewSpec, variables, batch, **apply_kw):
+    """The front every forward-only step shares (evaluation and the
+    scoring steps of strategies/scoring.py): the eval view, then the
+    forward pass, each under its named scope (``view``, ``forward``) so a
+    device trace attributes the step's operations to them.  Scopes are
+    metadata; the compiled program is the same without them."""
+    with jax.named_scope("view"):
+        x = apply_view(batch["image"], view, train=False)
+    with jax.named_scope("forward"):
+        return model.apply(variables, x, train=False, **apply_kw)
+
+
 def make_eval_step(model, view: ViewSpec, num_classes: int):
     """Jitted: uint8 batch -> metric counts.  The batch arrives sharded over
     the mesh's data axis; XLA reduces the counts across devices."""
 
     @jax.jit
     def eval_step(variables, batch):
-        x = apply_view(batch["image"], view, train=False)
-        logits = model.apply(variables, x, train=False)
-        return batch_metric_counts(logits, batch["label"], batch["mask"],
-                                   num_classes)
+        logits = view_forward(model, view, variables, batch)
+        with jax.named_scope("score_head"):
+            return batch_metric_counts(logits, batch["label"],
+                                       batch["mask"], num_classes)
 
     return eval_step
 

@@ -398,19 +398,25 @@ class Trainer:
         @functools.partial(jax.jit, static_argnames=("view",),
                            donate_argnums=(0,))
         def train_step(state, batch, key, lr, class_weights, view):
-            x = apply_view(batch["image"], view, key=key, train=True)
-            weights = class_weights[batch["label"]] * batch["mask"]
-            (loss, new_stats), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(state.params, state.batch_stats, x,
-                                       batch["label"], weights)
+            # The named scopes (view / forward_backward / optimizer) are
+            # metadata on the operations: a device trace attributes the
+            # step's time to them, the compiled program is unchanged.
+            with jax.named_scope("view"):
+                x = apply_view(batch["image"], view, key=key, train=True)
+            with jax.named_scope("forward_backward"):
+                weights = class_weights[batch["label"]] * batch["mask"]
+                (loss, new_stats), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(state.params, state.batch_stats,
+                                           x, batch["label"], weights)
             # Telemetry rider: the global gradient norm, computed where
             # the grads already exist (~|params| FLOPs vs the backward
             # pass's billions) and fetched in the SAME deferred bulk
             # materialization as the loss — zero extra device syncs.
             # Params/opt updates are untouched, so path equality
             # (tests/test_trainer_parallel.py) is unaffected.
-            gnorm = optax.global_norm(grads)
-            params, new_opt_state = apply_optimizer(grads, state, lr)
+            with jax.named_scope("optimizer"):
+                gnorm = optax.global_norm(grads)
+                params, new_opt_state = apply_optimizer(grads, state, lr)
             return state.replace(params=params, batch_stats=new_stats,
                                  opt_state=new_opt_state,
                                  step=state.step + 1), loss, gnorm
@@ -480,18 +486,22 @@ class Trainer:
             # a fold_in'd key (the f32 path draws one batch-wide key;
             # int8 is bounded-delta, not bit-exact, by contract).
             aug_key = jax.random.fold_in(key, jax.lax.axis_index(axis))
-            x = apply_view(batch["image"], view, key=aug_key, train=True)
-            weights = class_weights[batch["label"]] * batch["mask"]
-            (loss_local, new_stats), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(state.params, state.batch_stats, x,
-                                       batch["label"], weights)
+            with jax.named_scope("view"):
+                x = apply_view(batch["image"], view, key=aug_key,
+                               train=True)
+            with jax.named_scope("forward_backward"):
+                weights = class_weights[batch["label"]] * batch["mask"]
+                (loss_local, new_stats), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(state.params, state.batch_stats,
+                                           x, batch["label"], weights)
             if sync_form == "reduce_scatter":
                 grads = mesh_lib.int8_reduce_scatter(grads, ndev, axis)
             else:
                 grads = mesh_lib.int8_allreduce(grads, axis)
             loss = jax.lax.psum(loss_local, axis)
-            gnorm = optax.global_norm(grads)
-            params, new_opt_state = apply_optimizer(grads, state, lr)
+            with jax.named_scope("optimizer"):
+                gnorm = optax.global_norm(grads)
+                params, new_opt_state = apply_optimizer(grads, state, lr)
             return state.replace(params=params, batch_stats=new_stats,
                                  opt_state=new_opt_state,
                                  step=state.step + 1), loss, gnorm
@@ -560,13 +570,8 @@ class Trainer:
                            donate_argnums=(0, 5))
         def resident_batch_step(state, images, labels, ids, mask, key,
                                 lr, class_weights, view, sharded=False):
-            if sharded:
-                img, lab = resident_lib.sharded_pool_gather(
-                    images, ids, mesh, labels=labels)
-            else:
-                img = jax.lax.with_sharding_constraint(
-                    images[ids], mesh_lib.batch_sharding(mesh))
-                lab = labels[ids]
+            img, lab = resident_lib.pool_gather(
+                images, ids, mesh, labels=labels, sharded=sharded)
             batch = {"image": img, "label": lab, "mask": mask}
             new_key, sub = jax.random.split(key)
             new_state, loss, gnorm = train_step(state, batch, sub, lr,
@@ -596,24 +601,16 @@ class Trainer:
                            donate_argnums=(0,))
         def epoch_scan(state, images, labels, idx_mat, mask_mat, valid,
                        key, lr, class_weights, view, sharded=False):
-            batch_sharding = mesh_lib.batch_sharding(mesh)
-
             def body(carry, inp):
                 state, key = carry
                 idxs, mask, v = inp
                 new_key, sub = jax.random.split(key)
-                if sharded:
-                    # Row-sharded pool: batch rows assembled from their
-                    # owning shards (resident.sharded_pool_gather) into
-                    # the SAME batch sharding the constraint below
-                    # commits — bit-identical batches, shard_map
-                    # composes inside the scan body.
-                    img, lab = resident_lib.sharded_pool_gather(
-                        images, idxs, mesh, labels=labels)
-                else:
-                    img = jax.lax.with_sharding_constraint(
-                        images[idxs], batch_sharding)
-                    lab = labels[idxs]
+                # Row-sharded pool: batch rows assembled from their
+                # owning shards into the SAME batch sharding the
+                # replicated layout's constraint commits — bit-identical
+                # batches, shard_map composes inside the scan body.
+                img, lab = resident_lib.pool_gather(
+                    images, idxs, mesh, labels=labels, sharded=sharded)
                 batch = {"image": img, "label": lab, "mask": mask}
                 new_state, loss, gnorm = train_step(state, batch, sub, lr,
                                                     class_weights, view=view)
@@ -1005,7 +1002,8 @@ class Trainer:
                 self.resident_pool, dataset, self.mesh,
                 sharding=self.pool_sharding)
             run = resident_lib.get_runner(
-                self.resident_pool, eval_step, self.mesh, with_labels=True,
+                self.resident_pool, eval_step, self.mesh, "run_eval",
+                with_labels=True,
                 sharded=mesh_lib.is_row_sharded(images_dev))
             totals = None
             for b in batch_index_lists(np.asarray(idxs), bs):
@@ -1040,6 +1038,29 @@ class Trainer:
         return accumulate_metrics(counts())
 
     # -- the fit loop ----------------------------------------------------
+
+    def _publish_best(self, weight_paths: Dict[str, str], variables,
+                      round_idx: int, epoch: int) -> None:
+        """``ckpt/publish_best``: the device->host fetch AND the atomic
+        write + monotonic (round, epoch) tag, as one span that ends at
+        the rename."""
+        with tele_spans.get_tracer().span(
+                "ckpt/publish_best",
+                args={"bytes": ckpt_lib.tree_bytes(variables)}):
+            _CKPT_RETRY.call(ckpt_lib.publish_best,
+                             weight_paths["best_ckpt"],
+                             jax.tree.map(np.asarray, variables),
+                             round_idx=round_idx, epoch=epoch)
+
+    def _save_current(self, weight_paths: Dict[str, str], variables
+                      ) -> None:
+        """``ckpt/save_current``: fetch + write, ends at the rename."""
+        with tele_spans.get_tracer().span(
+                "ckpt/save_current",
+                args={"bytes": ckpt_lib.tree_bytes(variables)}):
+            _CKPT_RETRY.call(ckpt_lib.save_variables,
+                             weight_paths["current_ckpt"],
+                             jax.tree.map(np.asarray, variables))
 
     def fit(
         self,
@@ -1080,320 +1101,334 @@ class Trainer:
         subscriber may keep using it.  A failing callback is logged and
         ignored: speculation must never take a fit down."""
         use_es = es_patience != 0 and len(eval_idxs) > 0
-        from ..data.cache import CachedEvalRows, DecodedPoolCache
-        if (use_es and self.cfg.cache_eval and hasattr(al_set, "paths")
-                and not al_set.train_transform
-                and not isinstance(al_set, DecodedPoolCache)):
-            # Disk-backed eval rows decode identically every epoch (the
-            # val view is deterministic) — decode each once per round.
-            # Skipped when the experiment-lifetime memmap cache already
-            # wraps the pool: rows then stream from the page cache and a
-            # second RAM copy buys nothing.
-            al_set = CachedEvalRows(al_set,
-                                    max_bytes=self.cfg.cache_eval_bytes)
-        labels = train_set.targets[labeled_idxs]
-        class_weights = jnp.asarray(self.class_weights(labels))
-        if (self.grad_allreduce == "int8"
-                and getattr(self, "_int8_axis_fallback", False)
-                and self.train_bn
-                and jax.tree.leaves(state.batch_stats)):
-            # The int8 step could not thread the mesh axis into this
-            # model (no axis_name field) AND the model carries mutable
-            # batch statistics that would train PER-SHARD inside the
-            # shard_map body — divergent, silently-wrong BN.  Refuse
-            # loudly; grad_allreduce=f32 (or an axis_name-capable
-            # model) is the fix.
-            raise ValueError(
-                "grad_allreduce=int8 with a train-mode-BatchNorm model "
-                "that has no axis_name field: cross-device statistics "
-                "cannot be synced inside the quantized step — use "
-                "--grad_allreduce f32 or a model exposing axis_name")
-        state = self.reinit_optimizer(state)
-        bs = self.padded_batch_size(self.cfg.loader_tr.batch_size)
-
-        # The train feed, resolved ONCE for the whole fit (DESIGN.md §2a:
-        # resident-gather > prefetched-host > serial-host).  On the
-        # resident legs each epoch is ONE jitted scan whose per-step
-        # on-device gather + augment reproduce the host stream bit for
-        # bit (tests/test_trainer_parallel.py); "resident" draws from the
-        # SAME pinned pool scoring/evaluation use (zero host image
-        # copies), "resident_copy" from a private labeled-subset upload.
-        feed = self.resolve_train_feed(train_set, labeled_idxs, batch_hook)
-        use_scan = self._ensure_exec_form(feed)
-        self.last_feed = {"source": feed, "feed_stall_frac": None,
-                          "host_wait_ms_p50": None,
-                          "form": ("scan" if use_scan else
-                                   "step" if feed == "resident" else
-                                   "loop")}
-        feed_map = None
-        dr_sharded = False
-        if feed == "resident":
-            # Local epoch-matrix positions -> GLOBAL pool rows.  int32:
-            # resident pools are bounded by HBM, far under 2^31 rows.
-            feed_map = np.asarray(labeled_idxs, dtype=np.int32)
-            dr_images, dr_labels = self._resident_feed_arrays(train_set)
-            # Execution follows the entry's ACTUAL layout (a pool pinned
-            # replicated before a config change stays replicated): the
-            # flag is static on the jitted forms, fixed per experiment.
-            dr_sharded = mesh_lib.is_row_sharded(dr_images)
-        elif feed == "resident_copy":
-            # The legacy private labeled-subset copy stays replicated
-            # (it is bucket-padded per round; sharding it would buy
-            # little and cost a layout axis on the step bucketing).
-            dr_images, dr_labels = self._device_resident_arrays(
-                train_set, labeled_idxs, bs)
-            if getattr(train_set, "paged_backend", False):
-                # The disk tier's HBM leg: the hot copy joins the shared
-                # budget accounting (pinned_bytes/enforce_budget) under
-                # one per-trainer slot — re-pinned each fit, so the
-                # previous round's copy is replaced, never accumulated.
-                from ..parallel import resident as resident_lib
-                resident_lib.pin_hot(self.resident_pool,
-                                     f"hot_rows@{id(self):x}",
-                                     dr_images, dr_labels)
-        best_perf, best_epoch, es_count = 0.0, 0, 0
-        best_variables = None  # device tree after an improvement this fit
-        best_dirty = False  # True = best_variables newer than best_ckpt
-        history: List[Dict[str, float]] = []
-        key = jax.random.PRNGKey(int(rng.integers(0, 2 ** 31 - 1)))
-
-        # Mid-round resume: if a fit-state checkpoint for THIS round exists
-        # (written periodically below, deleted when the round completes), a
-        # crashed/preempted fit continues from its last completed epoch
-        # bit-for-bit instead of restarting the round — epoch-granularity
-        # recovery the reference lacks (its rd_{n}.pth is written every
-        # epoch and never read back, strategy.py:440).  VAAL's co-trained
-        # VAE/discriminator state is not covered: with a batch_hook the
-        # resumed fit restarts from epoch 1.
-        start_epoch = 1
-        if weight_paths and batch_hook is None and not resume_fit_state:
-            # This fit starts from scratch by the caller's decision (a
-            # fresh, non-resumed experiment run).  A fit state on disk here
-            # is from an OLDER dead run of the same experiment directory —
-            # consuming it would silently splice two runs together.
-            if os.path.exists(weight_paths["fit_state"] + ".json"):
-                self.logger.warning(
-                    "Discarding a stale mid-round fit state from a "
-                    "previous run (start this run with --resume_training "
-                    "to consume it)")
-            ckpt_lib.delete_fit_state(weight_paths["fit_state"])
-        if weight_paths and batch_hook is None and resume_fit_state:
-            saved = ckpt_lib.load_fit_state(weight_paths["fit_state"],
-                                            round_idx)
-            if saved is not None:
-                try:
-                    opt_state = serialization.from_state_dict(
-                        jax.tree.map(np.asarray, state.opt_state),
-                        saved["opt_state"])
-                except Exception:  # noqa: BLE001 - layout drift
-                    # The saved optimizer state has a different pytree
-                    # layout than this Trainer's (the fused path's
-                    # {"trace": ...} vs the optax chain's tuple state —
-                    # a --fused_optimizer change, or a pre-fused-era
-                    # checkpoint resumed under the new default).  The
-                    # fit state is all-or-nothing (its rng chain and
-                    # epoch counter assume the whole restore): discard
-                    # it and restart the round from scratch rather than
-                    # crash the resume.
-                    self.logger.warning(
-                        "mid-round fit state holds an incompatible "
-                        "optimizer-state layout (the optimizer path "
-                        "changed between runs); discarding it — round "
-                        f"{round_idx} restarts from its first epoch")
-                    ckpt_lib.delete_fit_state(weight_paths["fit_state"])
-                    saved = None
-            if saved is not None:
-                host = jax.tree.map(np.asarray, state.variables)
-                variables = serialization.from_state_dict(
-                    host, saved["variables"])
-                state = TrainState(
-                    params=mesh_lib.replicate(variables["params"],
-                                              self.mesh),
-                    batch_stats=mesh_lib.replicate(
-                        variables.get("batch_stats", {}), self.mesh),
-                    opt_state=mesh_lib.replicate(opt_state, self.mesh),
-                    step=jnp.asarray(saved["step"], jnp.int32))
-                best_perf = float(saved["best_perf"])
-                best_epoch = int(saved["best_epoch"])
-                es_count = int(saved["es_count"])
-                key = jnp.asarray(np.asarray(saved["key"], dtype=np.uint32))
-                rng.bit_generator.state = saved["rng_state"]
-                start_epoch = int(saved["epoch"]) + 1
-                if best_epoch > 0:
-                    # The COORDINATOR's view of best_ckpt decides for every
-                    # process: this branch resets early-stopping control
-                    # state (es_count), and a per-process filesystem check
-                    # (NFS attribute-cache lag on a pod) could send
-                    # processes down different epoch counts — mismatched
-                    # collectives hang the job.
-                    have_best = os.path.exists(weight_paths["best_ckpt"])
-                    if mesh_lib.is_multiprocess(self.mesh):
-                        from jax.experimental import multihost_utils
-                        have_best = bool(multihost_utils.broadcast_one_to_all(
-                            np.uint8(have_best)))
-                    if have_best:
-                        best_variables = ckpt_lib.load_variables(
-                            weight_paths["best_ckpt"], like=host)
-                    else:
-                        # The weights best_perf refers to are gone; keeping
-                        # the stale score would make the no-improvement
-                        # fallback report it over final-epoch weights.
-                        self.logger.warning(
-                            f"fit-state references best epoch {best_epoch} "
-                            "but best_ckpt is missing; restarting "
-                            "best-model tracking")
-                        best_perf, best_epoch, es_count = 0.0, 0, 0
-                self.logger.info(
-                    f"Resuming round {round_idx} training from epoch "
-                    f"{start_epoch} (mid-round fit state)")
-
-        # Per-step/per-epoch telemetry (DESIGN.md §7).  ``collect`` False
-        # (no run installed, or telemetry off) must add NO per-step work:
-        # every perf_counter call and list append below is gated on it.
-        rt = tele_runtime.get_run()
         tracer = tele_spans.get_tracer()
-        collect = rt.train_metrics
-        n_real = len(labeled_idxs)
+        with tracer.span("fit/prepare"):
+            from ..data.cache import CachedEvalRows, DecodedPoolCache
+            if (use_es and self.cfg.cache_eval and hasattr(al_set, "paths")
+                    and not al_set.train_transform
+                    and not isinstance(al_set, DecodedPoolCache)):
+                # Disk-backed eval rows decode identically every epoch (the
+                # val view is deterministic) — decode each once per round.
+                # Skipped when the experiment-lifetime memmap cache already
+                # wraps the pool: rows then stream from the page cache and a
+                # second RAM copy buys nothing.
+                al_set = CachedEvalRows(al_set,
+                                        max_bytes=self.cfg.cache_eval_bytes)
+            labels = train_set.targets[labeled_idxs]
+            class_weights = jnp.asarray(self.class_weights(labels))
+            if (self.grad_allreduce == "int8"
+                    and getattr(self, "_int8_axis_fallback", False)
+                    and self.train_bn
+                    and jax.tree.leaves(state.batch_stats)):
+                # The int8 step could not thread the mesh axis into this
+                # model (no axis_name field) AND the model carries mutable
+                # batch statistics that would train PER-SHARD inside the
+                # shard_map body — divergent, silently-wrong BN.  Refuse
+                # loudly; grad_allreduce=f32 (or an axis_name-capable
+                # model) is the fix.
+                raise ValueError(
+                    "grad_allreduce=int8 with a train-mode-BatchNorm model "
+                    "that has no axis_name field: cross-device statistics "
+                    "cannot be synced inside the quantized step — use "
+                    "--grad_allreduce f32 or a model exposing axis_name")
+            state = self.reinit_optimizer(state)
+            bs = self.padded_batch_size(self.cfg.loader_tr.batch_size)
+
+            # The train feed, resolved ONCE for the whole fit (DESIGN.md §2a:
+            # resident-gather > prefetched-host > serial-host).  On the
+            # resident legs each epoch is ONE jitted scan whose per-step
+            # on-device gather + augment reproduce the host stream bit for
+            # bit (tests/test_trainer_parallel.py); "resident" draws from the
+            # SAME pinned pool scoring/evaluation use (zero host image
+            # copies), "resident_copy" from a private labeled-subset upload.
+            feed = self.resolve_train_feed(train_set, labeled_idxs, batch_hook)
+            use_scan = self._ensure_exec_form(feed)
+            self.last_feed = {"source": feed, "feed_stall_frac": None,
+                              "host_wait_ms_p50": None,
+                              "form": ("scan" if use_scan else
+                                       "step" if feed == "resident" else
+                                       "loop")}
+            feed_map = None
+            dr_sharded = False
+            if feed == "resident":
+                # Local epoch-matrix positions -> GLOBAL pool rows.  int32:
+                # resident pools are bounded by HBM, far under 2^31 rows.
+                feed_map = np.asarray(labeled_idxs, dtype=np.int32)
+                dr_images, dr_labels = self._resident_feed_arrays(train_set)
+                # Execution follows the entry's ACTUAL layout (a pool pinned
+                # replicated before a config change stays replicated): the
+                # flag is static on the jitted forms, fixed per experiment.
+                dr_sharded = mesh_lib.is_row_sharded(dr_images)
+            elif feed == "resident_copy":
+                # The legacy private labeled-subset copy stays replicated
+                # (it is bucket-padded per round; sharding it would buy
+                # little and cost a layout axis on the step bucketing).
+                dr_images, dr_labels = self._device_resident_arrays(
+                    train_set, labeled_idxs, bs)
+                if getattr(train_set, "paged_backend", False):
+                    # The disk tier's HBM leg: the hot copy joins the shared
+                    # budget accounting (pinned_bytes/enforce_budget) under
+                    # one per-trainer slot — re-pinned each fit, so the
+                    # previous round's copy is replaced, never accumulated.
+                    from ..parallel import resident as resident_lib
+                    resident_lib.pin_hot(self.resident_pool,
+                                         f"hot_rows@{id(self):x}",
+                                         dr_images, dr_labels)
+            best_perf, best_epoch, es_count = 0.0, 0, 0
+            best_variables = None  # device tree after an improvement this fit
+            best_dirty = False  # True = best_variables newer than best_ckpt
+            history: List[Dict[str, float]] = []
+            key = jax.random.PRNGKey(int(rng.integers(0, 2 ** 31 - 1)))
+
+            # Mid-round resume: if a fit-state checkpoint for THIS round exists
+            # (written periodically below, deleted when the round completes), a
+            # crashed/preempted fit continues from its last completed epoch
+            # bit-for-bit instead of restarting the round — epoch-granularity
+            # recovery the reference lacks (its rd_{n}.pth is written every
+            # epoch and never read back, strategy.py:440).  VAAL's co-trained
+            # VAE/discriminator state is not covered: with a batch_hook the
+            # resumed fit restarts from epoch 1.
+            start_epoch = 1
+            if weight_paths and batch_hook is None and not resume_fit_state:
+                # This fit starts from scratch by the caller's decision (a
+                # fresh, non-resumed experiment run).  A fit state on disk here
+                # is from an OLDER dead run of the same experiment
+                # directory — consuming it would silently splice two runs
+                # together.
+                if os.path.exists(weight_paths["fit_state"] + ".json"):
+                    self.logger.warning(
+                        "Discarding a stale mid-round fit state from a "
+                        "previous run (start this run with --resume_training "
+                        "to consume it)")
+                ckpt_lib.delete_fit_state(weight_paths["fit_state"])
+            if weight_paths and batch_hook is None and resume_fit_state:
+                saved = ckpt_lib.load_fit_state(weight_paths["fit_state"],
+                                                round_idx)
+                if saved is not None:
+                    try:
+                        opt_state = serialization.from_state_dict(
+                            jax.tree.map(np.asarray, state.opt_state),
+                            saved["opt_state"])
+                    except Exception:  # noqa: BLE001 - layout drift
+                        # The saved optimizer state has a different pytree
+                        # layout than this Trainer's (the fused path's
+                        # {"trace": ...} vs the optax chain's tuple state —
+                        # a --fused_optimizer change, or a pre-fused-era
+                        # checkpoint resumed under the new default).  The
+                        # fit state is all-or-nothing (its rng chain and
+                        # epoch counter assume the whole restore): discard
+                        # it and restart the round from scratch rather than
+                        # crash the resume.
+                        self.logger.warning(
+                            "mid-round fit state holds an incompatible "
+                            "optimizer-state layout (the optimizer path "
+                            "changed between runs); discarding it — round "
+                            f"{round_idx} restarts from its first epoch")
+                        ckpt_lib.delete_fit_state(weight_paths["fit_state"])
+                        saved = None
+                if saved is not None:
+                    host = jax.tree.map(np.asarray, state.variables)
+                    variables = serialization.from_state_dict(
+                        host, saved["variables"])
+                    state = TrainState(
+                        params=mesh_lib.replicate(variables["params"],
+                                                  self.mesh),
+                        batch_stats=mesh_lib.replicate(
+                            variables.get("batch_stats", {}), self.mesh),
+                        opt_state=mesh_lib.replicate(opt_state, self.mesh),
+                        step=jnp.asarray(saved["step"], jnp.int32))
+                    best_perf = float(saved["best_perf"])
+                    best_epoch = int(saved["best_epoch"])
+                    es_count = int(saved["es_count"])
+                    key = jnp.asarray(
+                        np.asarray(saved["key"], dtype=np.uint32))
+                    rng.bit_generator.state = saved["rng_state"]
+                    start_epoch = int(saved["epoch"]) + 1
+                    if best_epoch > 0:
+                        # The COORDINATOR's view of best_ckpt decides for every
+                        # process: this branch resets early-stopping control
+                        # state (es_count), and a per-process filesystem check
+                        # (NFS attribute-cache lag on a pod) could send
+                        # processes down different epoch counts — mismatched
+                        # collectives hang the job.
+                        have_best = os.path.exists(weight_paths["best_ckpt"])
+                        if mesh_lib.is_multiprocess(self.mesh):
+                            from jax.experimental import multihost_utils
+                            have_best = bool(
+                                multihost_utils.broadcast_one_to_all(
+                                    np.uint8(have_best)))
+                        if have_best:
+                            best_variables = ckpt_lib.load_variables(
+                                weight_paths["best_ckpt"], like=host)
+                        else:
+                            # The weights best_perf refers to are gone; keeping
+                            # the stale score would make the no-improvement
+                            # fallback report it over final-epoch weights.
+                            self.logger.warning(
+                                "fit-state references best epoch "
+                                f"{best_epoch} but best_ckpt is missing; "
+                                "restarting best-model tracking")
+                            best_perf, best_epoch, es_count = 0.0, 0, 0
+                    self.logger.info(
+                        f"Resuming round {round_idx} training from epoch "
+                        f"{start_epoch} (mid-round fit state)")
+
+            # Per-step/per-epoch telemetry (DESIGN.md §7).  ``collect`` False
+            # (no run installed, or telemetry off) must add NO per-step work:
+            # every perf_counter call and list append below is gated on it.
+            rt = tele_runtime.get_run()
+            collect = rt.train_metrics
+            n_real = len(labeled_idxs)
 
         epochs_run = 0
         for epoch in range(start_epoch, n_epoch + 1):
             epochs_run = epoch
-            t_epoch0 = time.perf_counter() if collect else 0.0
-            step_times: List[float] = []
-            if hasattr(train_set, "set_epoch"):
-                # Advance disk datasets' per-(seed, epoch, index) crop RNG
-                # (data/imagenet.py); fold the round in so AL rounds don't
-                # replay the same augmentation sequence.
-                train_set.set_epoch(round_idx * (n_epoch + 1) + epoch)
-            lr = jnp.float32(self.lr_at(epoch - 1))
-            # train_loss stays a DEVICE scalar until the end of the fit:
-            # fetching it here would block the host on the epoch's compute
-            # before validation could even be dispatched — one avoidable
-            # host sync per epoch (its cost on the v5e: not measured).  The
-            # history is materialized to floats right before returning;
-            # mid-fit history entries hold live device arrays, so history
-            # must never be added to the fit-state payload as-is.
-            host_waits: List[float] = []
-            if use_scan:
-                idx_mat, mask_mat, valid, steps_real = \
-                    self._epoch_index_matrix(len(labeled_idxs), bs, rng)
-                if feed_map is not None:
-                    # Resident-gather: the SAME shuffled layout the host
-                    # path commits, re-expressed as global pool rows —
-                    # index math only, never an image byte.
-                    idx_mat = feed_map[idx_mat]
-                with self.dispatch_lock:
-                    state, key, losses, gnorms = self._epoch_scan(
-                        state, dr_images, dr_labels, jnp.asarray(idx_mat),
-                        jnp.asarray(mask_mat), jnp.asarray(valid), key, lr,
-                        class_weights, view=train_set.view,
-                        sharded=dr_sharded)
-                    self.dispatch_lock.drain(losses)
-                epoch_loss = jnp.sum(losses) / steps_real
-                epoch_gnorm = jnp.sum(gnorms) / steps_real
-                steps_run = steps_real
-            elif feed == "resident":
-                # Per-batch execution form: the SAME shuffled global
-                # layout (batch_index_lists consumes the rng exactly
-                # like the scan's _epoch_index_matrix and the host
-                # path), each batch one jitted on-device gather + step —
-                # the only h2d per step is the [batch] index vector.
-                losses, gnorms = [], []
-                t_step = time.perf_counter() if collect else 0.0
-                for b in batch_index_lists(labeled_idxs, bs,
-                                           shuffle=True, rng=rng):
-                    ids, mask = padded_batch_layout(b, bs)
+            # The epoch span times the DISPATCH (on the scan path one
+            # asynchronous call: it ends at the enqueue, and the device
+            # time is waited for in fit/validate below or, without
+            # validation, in the next fetch).  Recorded whenever the
+            # recorder is on; steps_run is the bucketed count.
+            with tracer.span("epoch", args={
+                    "round": round_idx, "epoch": epoch,
+                    "rows": n_real}) as epoch_sp:
+                step_times: List[float] = []
+                if hasattr(train_set, "set_epoch"):
+                    # Advance disk datasets' per-(seed, epoch, index) crop RNG
+                    # (data/imagenet.py); fold the round in so AL rounds don't
+                    # replay the same augmentation sequence.
+                    train_set.set_epoch(round_idx * (n_epoch + 1) + epoch)
+                lr = jnp.float32(self.lr_at(epoch - 1))
+                # train_loss stays a DEVICE scalar until the end of the fit:
+                # fetching it here would block the host on the epoch's compute
+                # before validation could even be dispatched — one avoidable
+                # host sync per epoch (its cost on the v5e: not measured).  The
+                # history is materialized to floats right before returning;
+                # mid-fit history entries hold live device arrays, so history
+                # must never be added to the fit-state payload as-is.
+                host_waits: List[float] = []
+                if use_scan:
+                    idx_mat, mask_mat, valid, steps_real = \
+                        self._epoch_index_matrix(len(labeled_idxs), bs, rng)
+                    if feed_map is not None:
+                        # Resident-gather: the SAME shuffled layout the host
+                        # path commits, re-expressed as global pool rows —
+                        # index math only, never an image byte.
+                        idx_mat = feed_map[idx_mat]
                     with self.dispatch_lock:
-                        small = mesh_lib.replicate(
-                            (ids.astype(np.int32), mask), self.mesh)
-                        state, key, loss, gnorm = \
-                            self._resident_batch_step(  # al-lint: donated-ok positions 3-4 are the *small (ids, mask) splat; the donated key at 5 is rebound by this statement's own targets
-                                state, dr_images, dr_labels, *small, key,
-                                lr, class_weights, view=train_set.view,
-                                sharded=dr_sharded)
-                        self.dispatch_lock.drain(loss)
-                    losses.append(loss)
-                    gnorms.append(gnorm)
-                    if collect:
-                        now = time.perf_counter()
-                        step_times.append(now - t_step)
-                        t_step = now
-                        rt.tick(epoch=epoch, step=len(losses))
-                epoch_loss = (jnp.mean(jnp.stack(losses))
-                              if losses else 0.0)
-                epoch_gnorm = (jnp.mean(jnp.stack(gnorms))
-                               if gnorms else 0.0)
-                steps_run = len(losses)
-            else:
-                losses, gnorms = [], []
-                workers = self._feed_workers()
-                # host_prefetch: worker-threaded gather/decode behind the
-                # double-buffered device prefetch — the loop below then
-                # receives already-sharded device batches and host_wait
-                # measures pure feed stall.  host_serial (always under a
-                # batch_hook): the classic gather->shard->step loop.
-                put = ((lambda b: mesh_lib.shard_batch(b, self.mesh))
-                       if feed == "host_prefetch" else None)
-                # Host-side s2d only without a batch_hook: VAAL's hook
-                # feeds the same sharded batch to its 3-channel VAE.
-                feed_iter = iter(train_feed_batches(
-                    train_set, labeled_idxs, bs, rng=rng, shuffle=True,
-                    num_workers=workers,
-                    prefetch=self.cfg.loader_tr.prefetch,
-                    local=mesh_lib.process_local_rows(self.mesh, bs),
-                    s2d=self._host_s2d and batch_hook is None,
-                    put=put, depth=self.cfg.loader_tr.prefetch))
-                t_step = time.perf_counter() if collect else 0.0
-                while True:
-                    t_wait = time.perf_counter() if collect else 0.0
-                    item = next(feed_iter, None)
-                    if item is None:
-                        break
-                    if collect:
-                        # Time blocked on the feed (gather/decode on the
-                        # serial leg, queue wait on the prefetched one):
-                        # the numerator of feed_stall_frac.
-                        host_waits.append(time.perf_counter() - t_wait)
-                    with self.dispatch_lock:
-                        sharded = (item if put is not None
-                                   else mesh_lib.shard_batch(item,
-                                                             self.mesh))
-                        state, key, loss, gnorm = self._chained_train_step(
-                            state, sharded, key, lr, class_weights,
-                            view=train_set.view)
-                        self.dispatch_lock.drain(loss)
-                    losses.append(loss)
-                    gnorms.append(gnorm)
-                    if batch_hook is not None:
-                        # Receives the already-sharded device batch — no
-                        # second host->device transfer on the hot path.
-                        batch_hook(epoch, sharded)
-                    if collect:
-                        # Loop-cadence deltas (gather + dispatch; the
-                        # donated-buffer backpressure makes steady-state
-                        # cadence track real step time) — host-side, no
-                        # sync.
-                        now = time.perf_counter()
-                        step_times.append(now - t_step)
-                        t_step = now
-                        rt.tick(epoch=epoch, step=len(losses))
-                epoch_loss = (jnp.mean(jnp.stack(losses))
-                              if losses else 0.0)
-                epoch_gnorm = (jnp.mean(jnp.stack(gnorms))
-                               if gnorms else 0.0)
-                steps_run = len(losses)
-            record = {"epoch": epoch, "lr": float(lr),
-                      "train_loss": epoch_loss, "grad_norm": epoch_gnorm}
-            if collect:
-                t_train_end = time.perf_counter()
-                tracer.complete("epoch", t_epoch0, t_train_end,
-                                args={"round": round_idx, "epoch": epoch,
-                                      "steps": steps_run})
+                        state, key, losses, gnorms = self._epoch_scan(
+                            state, dr_images, dr_labels, jnp.asarray(idx_mat),
+                            jnp.asarray(mask_mat), jnp.asarray(valid), key, lr,
+                            class_weights, view=train_set.view,
+                            sharded=dr_sharded)
+                        self.dispatch_lock.drain(losses)
+                    epoch_loss = jnp.sum(losses) / steps_real
+                    epoch_gnorm = jnp.sum(gnorms) / steps_real
+                    steps_run = steps_real
+                elif feed == "resident":
+                    # Per-batch execution form: the SAME shuffled global
+                    # layout (batch_index_lists consumes the rng exactly
+                    # like the scan's _epoch_index_matrix and the host
+                    # path), each batch one jitted on-device gather + step —
+                    # the only h2d per step is the [batch] index vector.
+                    losses, gnorms = [], []
+                    t_step = time.perf_counter() if collect else 0.0
+                    for b in batch_index_lists(labeled_idxs, bs,
+                                               shuffle=True, rng=rng):
+                        ids, mask = padded_batch_layout(b, bs)
+                        with self.dispatch_lock:
+                            small = mesh_lib.replicate(
+                                (ids.astype(np.int32), mask), self.mesh)
+                            state, key, loss, gnorm = \
+                                self._resident_batch_step(  # al-lint: donated-ok positions 3-4 are the *small (ids, mask) splat; the donated key at 5 is rebound by this statement's own targets
+                                    state, dr_images, dr_labels, *small, key,
+                                    lr, class_weights, view=train_set.view,
+                                    sharded=dr_sharded)
+                            self.dispatch_lock.drain(loss)
+                        losses.append(loss)
+                        gnorms.append(gnorm)
+                        if collect:
+                            now = time.perf_counter()
+                            step_times.append(now - t_step)
+                            t_step = now
+                            rt.tick(epoch=epoch, step=len(losses))
+                    epoch_loss = (jnp.mean(jnp.stack(losses))
+                                  if losses else 0.0)
+                    epoch_gnorm = (jnp.mean(jnp.stack(gnorms))
+                                   if gnorms else 0.0)
+                    steps_run = len(losses)
+                else:
+                    losses, gnorms = [], []
+                    workers = self._feed_workers()
+                    # host_prefetch: worker-threaded gather/decode behind the
+                    # double-buffered device prefetch — the loop below then
+                    # receives already-sharded device batches and host_wait
+                    # measures pure feed stall.  host_serial (always under a
+                    # batch_hook): the classic gather->shard->step loop.
+                    put = ((lambda b: mesh_lib.shard_batch(b, self.mesh))
+                           if feed == "host_prefetch" else None)
+                    # Host-side s2d only without a batch_hook: VAAL's hook
+                    # feeds the same sharded batch to its 3-channel VAE.
+                    feed_iter = iter(train_feed_batches(
+                        train_set, labeled_idxs, bs, rng=rng, shuffle=True,
+                        num_workers=workers,
+                        prefetch=self.cfg.loader_tr.prefetch,
+                        local=mesh_lib.process_local_rows(self.mesh, bs),
+                        s2d=self._host_s2d and batch_hook is None,
+                        put=put, depth=self.cfg.loader_tr.prefetch))
+                    t_step = time.perf_counter() if collect else 0.0
+                    while True:
+                        t_wait = time.perf_counter() if collect else 0.0
+                        item = next(feed_iter, None)
+                        if item is None:
+                            break
+                        if collect:
+                            # Time blocked on the feed (gather/decode on the
+                            # serial leg, queue wait on the prefetched one):
+                            # the numerator of feed_stall_frac.
+                            host_waits.append(time.perf_counter() - t_wait)
+                        with self.dispatch_lock:
+                            sharded = (item if put is not None
+                                       else mesh_lib.shard_batch(item,
+                                                                 self.mesh))
+                            state, key, loss, gnorm = self._chained_train_step(
+                                state, sharded, key, lr, class_weights,
+                                view=train_set.view)
+                            self.dispatch_lock.drain(loss)
+                        losses.append(loss)
+                        gnorms.append(gnorm)
+                        if batch_hook is not None:
+                            # Receives the already-sharded device batch — no
+                            # second host->device transfer on the hot path.
+                            batch_hook(epoch, sharded)
+                        if collect:
+                            # Loop-cadence deltas (gather + dispatch; the
+                            # donated-buffer backpressure makes steady-state
+                            # cadence track real step time) — host-side, no
+                            # sync.
+                            now = time.perf_counter()
+                            step_times.append(now - t_step)
+                            t_step = now
+                            rt.tick(epoch=epoch, step=len(losses))
+                    epoch_loss = (jnp.mean(jnp.stack(losses))
+                                  if losses else 0.0)
+                    epoch_gnorm = (jnp.mean(jnp.stack(gnorms))
+                                   if gnorms else 0.0)
+                    steps_run = len(losses)
+                record = {"epoch": epoch, "lr": float(lr),
+                          "train_loss": epoch_loss, "grad_norm": epoch_gnorm}
+                # steps_run: what the device executes — the scan's
+                # bucket-padded step count, the real count elsewhere.
+                epoch_sp.args.update(
+                    steps_real=steps_run,
+                    steps_run=len(valid) if use_scan else steps_run)
 
             if use_es:
-                perf = self.evaluate(state, al_set, eval_idxs)
-                eval_acc = float(perf["accuracy"])
-                eval_top5 = float(perf["top_5_accuracy"])
+                # Ends at the fetch of the counts — on the scan path this
+                # is where the epoch's device time is waited for.
+                with tracer.span("fit/validate"):
+                    perf = self.evaluate(state, al_set, eval_idxs)
+                    eval_acc = float(perf["accuracy"])
+                    eval_top5 = float(perf["top_5_accuracy"])
                 record.update(val_accuracy=eval_acc, val_top5=eval_top5)
                 self.logger.info(
                     f"\tValidation performance on round {round_idx} at "
@@ -1443,28 +1478,22 @@ class Trainer:
                         # publish_best = atomic write + monotonic
                         # (round, best_epoch) tag for the concurrent
                         # readers (serve hot-reload, speculative scorer).
-                        _CKPT_RETRY.call(
-                            ckpt_lib.publish_best,
-                            weight_paths["best_ckpt"],
-                            jax.tree.map(np.asarray, best_variables),
-                            round_idx=round_idx, epoch=best_epoch)
+                        self._publish_best(weight_paths, best_variables,
+                                           round_idx, best_epoch)
                         best_dirty = False
-                    _CKPT_RETRY.call(
-                        ckpt_lib.save_variables,
-                        weight_paths["current_ckpt"],
-                        jax.tree.map(np.asarray, state.variables))
+                    self._save_current(weight_paths, state.variables)
             if collect:
                 # AFTER validation on purpose: on the epoch-scan path the
                 # eval-accuracy fetch above is the sync that makes the
                 # epoch wall real (see _emit_epoch_telemetry).
                 self._emit_epoch_telemetry(
                     metric_cb, round_idx, epoch, n_epoch, n_real,
-                    t_train_end - t_epoch0,
-                    time.perf_counter() - t_epoch0, use_es,
+                    epoch_sp.duration_s,
+                    time.perf_counter() - epoch_sp.t0, use_es,
                     steps_run, step_times)
                 self._emit_feed_telemetry(
                     metric_cb, round_idx * (n_epoch + 1) + epoch,
-                    host_waits, t_train_end - t_epoch0)
+                    host_waits, epoch_sp.duration_s)
                 rt.tick(epoch=epoch, feed=feed)
             history.append(record)
             if use_es and es_count > es_patience:
@@ -1484,18 +1513,20 @@ class Trainer:
                     # best_epoch; without this publish the resumed fit
                     # would find best_ckpt missing and restart best-model
                     # tracking — diverging from the uninterrupted run.
-                    _CKPT_RETRY.call(
-                        ckpt_lib.publish_best, weight_paths["best_ckpt"],
-                        jax.tree.map(np.asarray, best_variables),
-                        round_idx=round_idx, epoch=best_epoch)
+                    self._publish_best(weight_paths, best_variables,
+                                       round_idx, best_epoch)
                     best_dirty = False
-                _CKPT_RETRY.call(
-                    ckpt_lib.save_fit_state,
-                    weight_paths["fit_state"], variables=state.variables,
-                    opt_state=state.opt_state, step=state.step, epoch=epoch,
-                    round_idx=round_idx, best_perf=best_perf,
-                    best_epoch=best_epoch, es_count=es_count, key=key,
-                    rng=rng)
+                with tracer.span("ckpt/save_fit_state", args={
+                        "bytes": ckpt_lib.tree_bytes(
+                            (state.variables, state.opt_state))}):
+                    _CKPT_RETRY.call(
+                        ckpt_lib.save_fit_state,
+                        weight_paths["fit_state"],
+                        variables=state.variables,
+                        opt_state=state.opt_state, step=state.step,
+                        epoch=epoch, round_idx=round_idx,
+                        best_perf=best_perf, best_epoch=best_epoch,
+                        es_count=es_count, key=key, rng=rng)
             if preempted:
                 # Preemption (SIGTERM/SIGINT recorded by the driver's
                 # handler): the epoch boundary is the safe point — the
@@ -1507,44 +1538,48 @@ class Trainer:
 
         if best_variables is None:
             best_epoch = epochs_run
-            best_variables = jax.tree.map(np.asarray, state.variables)
+            best_variables = state.variables
             best_dirty = True
         if best_dirty and weight_paths and mesh_lib.is_coordinator():
-            _CKPT_RETRY.call(ckpt_lib.publish_best,
-                             weight_paths["best_ckpt"],
-                             jax.tree.map(np.asarray, best_variables),
-                             round_idx=round_idx, epoch=best_epoch)
+            self._publish_best(weight_paths, best_variables, round_idx,
+                               best_epoch)
         if weight_paths and mesh_lib.is_coordinator():
-            _CKPT_RETRY.call(ckpt_lib.save_variables,
-                             weight_paths["current_ckpt"],
-                             jax.tree.map(np.asarray, state.variables))
-            # The round completed: a later restart must re-run it from
-            # scratch (the experiment-level resume owns cross-round state).
-            ckpt_lib.delete_fit_state(weight_paths["fit_state"])
-        if mesh_lib.is_multiprocess(self.mesh):
-            # Non-writer processes must not race ahead to read best_ckpt
-            # (strategy.load_best_ckpt) before process 0 finishes writing.
-            from jax.experimental import multihost_utils
-            multihost_utils.sync_global_devices("fit_ckpts_written")
-        self.logger.info(
-            f"Sanity Check: Best ckpt occurs on epoch {best_epoch}")
-        ema_loss = ema_gnorm = None
-        for rec in history:
-            # Deferred train-loss fetch (see the epoch loop): one bulk
-            # materialization here instead of one host sync per epoch.
-            # The loss/grad-norm EMAs piggyback on this SAME fetch — the
-            # telemetry rider costs no additional device sync.
-            rec["train_loss"] = float(rec["train_loss"])
-            rec["grad_norm"] = float(rec.get("grad_norm", 0.0))
-            if collect and metric_cb is not None:
-                a = self.TELEMETRY_EMA_ALPHA
-                ema_loss = (rec["train_loss"] if ema_loss is None
-                            else a * rec["train_loss"] + (1 - a) * ema_loss)
-                ema_gnorm = (rec["grad_norm"] if ema_gnorm is None
-                             else a * rec["grad_norm"] + (1 - a) * ema_gnorm)
-                tele_step = round_idx * (n_epoch + 1) + rec["epoch"]
-                metric_cb("train_loss_ema", round(ema_loss, 6), tele_step)
-                metric_cb("grad_norm_ema", round(ema_gnorm, 6), tele_step)
+            self._save_current(weight_paths, state.variables)
+        with tracer.span("fit/finish"):
+            if weight_paths and mesh_lib.is_coordinator():
+                # The round completed: a later restart must re-run it from
+                # scratch (the experiment-level resume owns cross-round
+                # state).
+                ckpt_lib.delete_fit_state(weight_paths["fit_state"])
+            if mesh_lib.is_multiprocess(self.mesh):
+                # Non-writer processes must not race ahead to read
+                # best_ckpt (strategy.load_best_ckpt) before process 0
+                # finishes writing.
+                from jax.experimental import multihost_utils
+                multihost_utils.sync_global_devices("fit_ckpts_written")
+            self.logger.info(
+                f"Sanity Check: Best ckpt occurs on epoch {best_epoch}")
+            ema_loss = ema_gnorm = None
+            for rec in history:
+                # Deferred train-loss fetch (see the epoch loop): one bulk
+                # materialization here instead of one host sync per epoch.
+                # The loss/grad-norm EMAs piggyback on this SAME fetch —
+                # the telemetry rider costs no additional device sync.
+                rec["train_loss"] = float(rec["train_loss"])
+                rec["grad_norm"] = float(rec.get("grad_norm", 0.0))
+                if collect and metric_cb is not None:
+                    a = self.TELEMETRY_EMA_ALPHA
+                    ema_loss = (rec["train_loss"] if ema_loss is None
+                                else a * rec["train_loss"]
+                                + (1 - a) * ema_loss)
+                    ema_gnorm = (rec["grad_norm"] if ema_gnorm is None
+                                 else a * rec["grad_norm"]
+                                 + (1 - a) * ema_gnorm)
+                    tele_step = round_idx * (n_epoch + 1) + rec["epoch"]
+                    metric_cb("train_loss_ema", round(ema_loss, 6),
+                              tele_step)
+                    metric_cb("grad_norm_ema", round(ema_gnorm, 6),
+                              tele_step)
         return FitResult(state=state, best_epoch=best_epoch,
                          best_perf=best_perf, epochs_run=epochs_run,
                          history=history)

@@ -200,12 +200,15 @@ def overlay_torch_state(variables: Dict[str, Any],
     return unflatten_dict(flat)
 
 
-def apply_pretrained(variables: Dict[str, Any],
-                     cfg: PretrainedConfig) -> Dict[str, Any]:
+def apply_pretrained(variables: Dict[str, Any], cfg: PretrainedConfig,
+                     state: Optional[Dict[str, np.ndarray]] = None
+                     ) -> Dict[str, Any]:
     """Full pipeline: load -> surgery -> overlay.  Called from
     Strategy.init_network_weights after the random re-init
-    (strategy.py:185-196)."""
-    state = load_torch_state_dict(cfg.path)
+    (strategy.py:185-196), which reads the file itself (``state``) so the
+    read and the overlay are two sibling spans there."""
+    if state is None:
+        state = load_torch_state_dict(cfg.path)
     state = surgery(state, required_key=cfg.required_key,
                     skip_key=cfg.skip_key, replace_map=cfg.replace_map)
     return overlay_torch_state(variables, state)

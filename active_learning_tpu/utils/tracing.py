@@ -6,10 +6,12 @@ Here the same per-phase timers are HOST SPANS (telemetry/spans.py): one
 measurement feeds the ``rd_{name}`` metric, the log line, the Chrome
 trace event, and the heartbeat tick, so the trace can never silently
 fork from the metrics (scripts/trace_lint.py asserts this routing).
-Each phase additionally wraps a device trace annotation so XLA profiler
-captures (telemetry/profiler.py — the device-truth layer, which owns
-EVERY jax.profiler touch per trace_lint check 10) show query/train/test
-spans on the device timeline too.
+The device annotation is the span's too: ``SpanTracer.span`` opens one
+``TraceAnnotation`` of the span's own name through the gated route
+(telemetry/profiler.trace_annotation, which owns EVERY jax.profiler
+touch per trace_lint check 10), so ``phase_timer`` opens none of its own
+and XLA profiler captures show every program span — phases and what
+runs inside them — on the device timeline under one name each.
 """
 
 from __future__ import annotations
@@ -17,29 +19,18 @@ from __future__ import annotations
 import contextlib
 from typing import Iterator
 
-from ..telemetry import profiler as _tele_profiler
 from ..telemetry import runtime as _tele_runtime
 from ..telemetry import spans as _tele_spans
 from .logging import get_logger
 
 
 @contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Name the enclosed host span in device profiler traces; free when no
-    trace is active.  Delegates to the device-truth layer's gated
-    annotation (telemetry/profiler.trace_annotation) — one module owns
-    jax.profiler."""
-    with _tele_profiler.trace_annotation(name):
-        yield
-
-
-@contextlib.contextmanager
 def phase_timer(name: str, round_idx: int, sink=None,
                 logger=None) -> Iterator[None]:
     """Wall-clock a phase, log it, and emit ``rd_{name}`` to the metrics
-    sink — the reference's per-phase prints (main_al.py:160-178) with the
-    profiler annotation added.  The timing IS the host span's: metric,
-    log, trace event, and heartbeat all read one measurement.  Yields
+    sink — the reference's per-phase prints (main_al.py:160-178).  The
+    timing IS the host span's: metric, log, trace event, device
+    annotation and heartbeat all read one measurement.  Yields
     the span so callers can read the same ``duration_s`` afterwards (the
     driver's overlap_frac accounting sums phase walls from it — still
     one measurement, never a second clock)."""
@@ -47,8 +38,7 @@ def phase_timer(name: str, round_idx: int, sink=None,
     _tele_runtime.get_run().tick(force=True, phase=name, round=round_idx)
     with _tele_spans.get_tracer().span(
             name, args={"round": round_idx}) as sp:
-        with annotate(f"{name}/rd{round_idx}"):
-            yield sp
+        yield sp
     seconds = sp.duration_s
     logger.info(f"Rd {round_idx} {name} is {seconds:.3f}s")
     if sink is not None:

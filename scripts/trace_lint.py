@@ -12,7 +12,7 @@ subprocess run, monkeypatched ``_py_files``) works unchanged:
   1  phase_timer derives its seconds from ONE tracer span
   2  nobody else defines a phase_timer
   3  call sites import phase_timer from utils.tracing
-  4  jax.profiler.TraceAnnotation stays behind tracing.annotate
+  4  jax.profiler.TraceAnnotation is opened by SpanTracer.span only
   5  the resident train feed never materializes images on host
   6  the row-sharded selection backend never un-shards the pool
   7  the speculative-scoring coordinator never syncs the train stream

@@ -183,7 +183,8 @@ def test_224px_scoring_step_at_the_256_row_floor(one_chip):
     model = get_network("imagenet", "SSLResNet50", dtype="bfloat16")
     step = scoring.make_prob_stats_step(
         model, ViewSpec(IMAGENET_NORM, augment=False))
-    run = resident_lib.get_runner({}, step, one_chip)
+    run = resident_lib.get_runner({}, step, one_chip,
+                                  scoring._runner_name(step))
     rep = mesh_lib.replicated_sharding(one_chip)
     variables = jax.eval_shape(
         lambda rng: model.init(rng, jnp.zeros((1, 224, 224, 3), jnp.float32),

@@ -394,12 +394,17 @@ class TestEndToEndSmoke:
         trace = json.load(open(os.path.join(smoke_run, "trace.json")))
         events = trace["traceEvents"]
         names = {e["name"] for e in events}
-        # The span hierarchy of DESIGN §7: experiment → round → phase →
-        # epoch → collect_pool chunk.
-        for expected in ("experiment", "round", "train_time", "test_time",
-                         "query_time", "epoch", "collect_pool",
-                         "collect_pool_chunk"):
+        # The span tree of DESIGN §7: experiment → round → phase → the
+        # work inside the phase.  This run scores from the pinned pool:
+        # collect_pool_chunk lives on the stream path only, where a
+        # chunk ends at a real fetch (tests/test_span_tree.py asserts it
+        # there).
+        for expected in ("experiment", "round", "round_epilogue",
+                         "train_time", "test_time", "query_time", "epoch",
+                         "collect_pool", "fit/validate",
+                         "ckpt/publish_best", "test/evaluate"):
             assert expected in names, f"missing span {expected!r}"
+        assert "collect_pool_chunk" not in names
         spans = {e["name"]: e for e in events}
         exp = spans["experiment"]
         for e in events:
