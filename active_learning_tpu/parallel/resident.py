@@ -28,9 +28,23 @@ never pool-sized) and the result lands batch-sharded, exactly where the
 replicated path's sharding constraint put it — so consumers are
 bit-identical across layouts.
 
+Pinned form (DESIGN.md §2b): image rows are NOT pinned as
+``[N, H, W, C]``.  The TPU gives that shape a compact layout with the
+row index minor-most, and every program that gathers rows from it first
+re-lays the WHOLE pool out rows-major (4.93 GB in and out per scoring
+dispatch at 32,768 x 224 px: 2.5 s of a 5.7 s round, PERF.md §6 PR 26).
+``to_pinned`` reshapes host rows into a form whose device layout keeps
+dimension 0 major, so a gather reads whole rows where they lie;
+``from_pinned`` brings a gathered batch back to ``[B, H, W, C]`` (same
+bytes, a reshape of 256 rows instead of a copy of the pool).  These two
+functions are the only code that knows the form; dimension 0 is the row
+index in every form, so sharding, ``rows_per_device`` and the in-place
+row update read it unchanged.
+
 Layout of a cache dict:
   cache["images"][(id(images), n)] = (dataset, images_dev, labels_dev)
-  cache["steps"][(id(step_fn), with_labels, sharded)] = jitted runner
+      # images_dev in the pinned form: to_pinned(dataset.images[:n])
+  cache["steps"][(id(step_fn), with_labels, sharded, row_shape)] = jitted runner
   cache["lru"] = [key, ...]  # least-recently-used first (eviction order)
 
 Virtual-CPU-mesh caveat: the N replicas' on-device gathers execute
@@ -53,6 +67,7 @@ from jax.sharding import PartitionSpec as P
 
 from . import mesh as mesh_lib
 from .. import faults
+from ..telemetry import profiler as profiler_lib
 from ..utils.logging import get_logger
 
 # Device transfer is the classic transient-failure surface (HBM pressure
@@ -305,10 +320,50 @@ def cached(cache: Optional[Dict], dataset: Any) -> bool:
         return (id(images), len(dataset)) in cache.get("images", {})
 
 
+# One (8, 128) tile of the TPU's compact layouts, in elements.  A
+# trailing ``[k, 128]`` keeps the row index major-most exactly when k
+# fills whole tiles (k % 8 == 0); otherwise the device folds the ROW
+# index into the tile and a gather re-lays the pool again (compiled
+# for the v5e: u8[32768,1176,128] pins as {2,1,0}, u8[32768,294,128]
+# as {2,0,1} with a pool-sized copy in front of the gather).
+_LANES = 128
+_TILE = 8 * _LANES
+
+
+def pinned_shape(shape: Tuple[int, ...]) -> Tuple[int, ...]:
+    """``[n, *row_shape]`` -> the shape of its pinned form ``[n, ...]``.
+    Rows whose elements fill whole (8, 128) tiles pin as
+    ``[n, elems/128, 128]``, where the device keeps each row contiguous;
+    any other row pins flat as ``[n, elems]`` (it fits no better form:
+    the device may still fold n into the tile there, which costs what
+    ``[n, H, W, C]`` always cost).  The form follows the row alone —
+    never a flag, a config field or a model."""
+    elems = int(np.prod(shape[1:], dtype=np.int64))
+    if elems and elems % _TILE == 0:
+        return (int(shape[0]), elems // _LANES, _LANES)
+    return (int(shape[0]), elems)
+
+
+def to_pinned(rows: Any) -> Any:
+    """Host rows ``[n, *row_shape]`` -> the pinned form
+    (``pinned_shape``): a reshape — a view of contiguous rows, no bytes
+    move and no value changes."""
+    return rows.reshape(pinned_shape(rows.shape))
+
+
+def from_pinned(batch: Any, row_shape: Tuple[int, ...]) -> Any:
+    """A batch gathered from a pinned array (or a host copy of pinned
+    rows) -> ``[B, *row_shape]``: the inverse reshape of ``to_pinned``,
+    bit for bit the rows the host held."""
+    return batch.reshape(batch.shape[0], *row_shape)
+
+
 def pool_arrays(cache: Dict, dataset: Any, mesh,
                 sharding: str = "replicated") -> Tuple[Any, Any]:
     """(images_dev, labels_dev) for the dataset, uploaded once per
     (underlying array, length) — views sharing storage share the upload.
+    ``images_dev`` is in the pinned form (``to_pinned``): read rows back
+    through ``pool_gather`` / ``from_pinned``, never by its shape.
     ``sharding`` "row": rows split over the mesh's data axis
     (mesh_lib.shard_rows — zero-padded to divide evenly; the full array
     never lands on any single device), "replicated": one copy per chip.
@@ -333,13 +388,15 @@ def pool_arrays(cache: Dict, dataset: Any, mesh,
                     # exactly what the row path avoids.
                     return (
                         dataset,
-                        mesh_lib.shard_rows(dataset.images[:n], mesh),
+                        mesh_lib.shard_rows(
+                            to_pinned(dataset.images[:n]), mesh),
                         mesh_lib.shard_rows(
                             dataset.targets[:n].astype(np.int32), mesh))
                 return (
                     dataset,
                     mesh_lib.replicate(
-                        np.ascontiguousarray(dataset.images[:n]), mesh),
+                        to_pinned(np.ascontiguousarray(dataset.images[:n])),
+                        mesh),
                     mesh_lib.replicate(
                         dataset.targets[:n].astype(np.int32), mesh))
 
@@ -392,22 +449,29 @@ def sharded_pool_gather(images, ids, mesh, labels=None):
         out_specs=(img_spec, P(axis)), check_vma=False)(images, labels, ids)
 
 
-def pool_gather(images, ids, mesh, labels=None, sharded: bool = False):
-    """One batch of pool rows (and labels) for a replicated [batch] index
-    vector, batch-sharded — the ONE spelling of the per-batch gather in
-    the scoring/eval runners, the resident batch step and the epoch
-    scan, under the named scope ``pool_gather`` so a device trace says
-    which operations are the gather (scopes are metadata: the compiled
-    program is the same with and without them).  ``sharded`` follows the
-    pool entry's actual layout: shard-local pick + owner psum
-    (sharded_pool_gather) against a full-array index + sharding
-    constraint; both land the batch in the same batch sharding."""
+def pool_gather(images, ids, mesh, row_shape: Tuple[int, ...], labels=None,
+                sharded: bool = False):
+    """One batch of pool rows ``[batch, *row_shape]`` (and labels) for a
+    replicated [batch] index vector, batch-sharded — the ONE spelling of
+    the per-batch gather in the scoring/eval runners, the resident batch
+    step and the epoch scan, under the named scope ``pool_gather`` so a
+    device trace says which operations are the gather (scopes are
+    metadata: the compiled program is the same with and without them).
+    ``images`` is a pinned array (``to_pinned``); the gathered rows come
+    back through ``from_pinned`` here, so every consumer downstream sees
+    the rows as the host held them.  ``sharded`` follows the pool
+    entry's actual layout: shard-local pick + owner psum
+    (sharded_pool_gather) against a full-array index; both land the
+    batch in the same batch sharding."""
     with jax.named_scope("pool_gather"):
         if sharded:
-            return sharded_pool_gather(images, ids, mesh, labels=labels)
+            out = sharded_pool_gather(images, ids, mesh, labels=labels)
+            img, lab = out if labels is not None else (out, None)
+        else:
+            img, lab = images[ids], None if labels is None else labels[ids]
         img = jax.lax.with_sharding_constraint(
-            images[ids], mesh_lib.batch_sharding(mesh))
-        return img if labels is None else (img, labels[ids])
+            from_pinned(img, row_shape), mesh_lib.batch_sharding(mesh))
+        return img if labels is None else (img, lab)
 
 
 # The incremental row update's FIXED window width (rows): every drain,
@@ -527,7 +591,8 @@ def update_rows(cache: Optional[Dict], dataset: Any, mesh,
         try:
             for lo0 in range(int(row_lo), int(row_hi), block_rows):
                 lo = min(lo0, n - block_rows)
-                block = np.ascontiguousarray(images[lo:lo + block_rows])
+                block = to_pinned(
+                    np.ascontiguousarray(images[lo:lo + block_rows]))
                 new_images = run(new_images, block, jnp.int32(lo))
         except Exception:
             # The old buffer may be donated-and-gone: drop the entry so
@@ -703,7 +768,8 @@ def enforce_budget(cache: Optional[Dict], max_bytes: int) -> list:
 
 
 def get_runner(cache: Dict, step_fn: Callable, mesh, name: str,
-               with_labels: bool = False, sharded: bool = False) -> Callable:
+               row_shape: Tuple[int, ...], with_labels: bool = False,
+               sharded: bool = False) -> Callable:
     """Jitted gather+step over a resident pool: rows are picked out on
     device and constrained to the batch sharding, so each batch costs one
     tiny [batch]-int32 transfer instead of the image rows.  ``name`` is
@@ -711,13 +777,16 @@ def get_runner(cache: Dict, step_fn: Callable, mesh, name: str,
     scoring.collect_pool, ``run_eval`` from Trainer.evaluate): it is set
     on the function that is jitted, so the device trace shows
     ``jit_<name>`` and the scoring and evaluation runners are told apart
-    by name.  ``sharded`` (caller reads it off the entry via
+    by name.  ``row_shape`` is the dataset's ``image_shape``: the pinned
+    array no longer says it (``to_pinned``), and the step sees
+    ``[batch, *row_shape]``.  ``sharded`` (caller reads it off the entry via
     mesh_lib.is_row_sharded): the gather goes through
     sharded_pool_gather — shard-local row pick + owner psum instead of a
     full-array index — landing the batch in the SAME batch sharding, so
     the step partitions identically and scores are bit-identical across
     pool layouts."""
-    key = (id(step_fn), with_labels, bool(sharded))
+    row_shape = tuple(int(d) for d in row_shape)
+    key = (id(step_fn), with_labels, bool(sharded), row_shape)
     with _CACHE_LOCK:
         steps = cache.setdefault("steps", {})
         if key in steps:
@@ -726,14 +795,14 @@ def get_runner(cache: Dict, step_fn: Callable, mesh, name: str,
     if with_labels:
 
         def run(variables, images, labels, ids, mask):
-            img, lab = pool_gather(images, ids, mesh, labels=labels,
-                                   sharded=sharded)
+            img, lab = pool_gather(images, ids, mesh, row_shape,
+                                   labels=labels, sharded=sharded)
             batch = {"image": img, "label": lab, "mask": mask}
             return step_fn(variables, batch)
     else:
 
         def run(variables, images, ids, mask):
-            img = pool_gather(images, ids, mesh, sharded=sharded)
+            img = pool_gather(images, ids, mesh, row_shape, sharded=sharded)
             batch = {"image": img, "mask": mask}
             return step_fn(variables, batch)
 
@@ -745,3 +814,33 @@ def get_runner(cache: Dict, step_fn: Callable, mesh, name: str,
     # objects for one (step_fn, layout) would each compile separately.
     with _CACHE_LOCK:
         return steps.setdefault(key, run)
+
+
+def assert_pool_read_in_place(run: Callable, args: Tuple,
+                              pool_arg: int) -> Dict[str, int]:
+    """Lower and compile ``run`` (a ``get_runner`` program) for ``args``
+    — arrays or ``ShapeDtypeStruct``s; ``args[pool_arg]`` is the pinned
+    pool — and raise ``AssertionError`` unless the program reads the
+    pool where it lies: its temporaries stay under a quarter of the
+    pool's per-device bytes, and no instruction other than a parameter
+    produces a pool-sized array (the re-layout ``copy`` this form exists
+    to avoid, or a reshape / cast of the whole pool crept into
+    ``pool_gather``).  Compiling for the device IS the check: the
+    operand's layout is the compiler's choice for the device at hand, so
+    the same call guards the form on the chip (chip_smoke.py), for a
+    described chip (tests/test_chip_compile.py) and, for a pool-sized
+    reshape only, on the CPU.  Returns the figures it judged."""
+    pool = args[pool_arg]
+    shard = pool.sharding.shard_shape(pool.shape)
+    pool_bytes = int(np.prod(shard, dtype=np.int64)) * pool.dtype.itemsize
+    compiled = run.lower(*args).compile()
+    temp = int(compiled.memory_analysis().temp_size_in_bytes)
+    sized = [f"{name} = {opcode}(...)" for name, opcode, nbytes
+             in profiler_lib.hlo_text_instructions(compiled.as_text())
+             if nbytes >= pool_bytes and opcode != "parameter"]
+    if sized or temp >= pool_bytes // 4:
+        raise AssertionError(
+            f"the program does not read its {pool_bytes}-byte pool "
+            f"operand in place: {temp} bytes of temporaries, pool-sized "
+            f"instructions {sized}")
+    return {"pool_bytes": pool_bytes, "temp_bytes": temp}

@@ -508,7 +508,7 @@ def _collect_resident(dataset, idxs, batch_size, step_fn, variables, mesh,
                                              sharding=pool_sharding)
     run = resident_lib.get_runner(
         resident_cache, step_fn, mesh, _runner_name(step_fn),
-        sharded=mesh_lib.is_row_sharded(images_dev))
+        dataset.image_shape, sharded=mesh_lib.is_row_sharded(images_dev))
     chunks: Dict[str, list] = {}
     for i, b in enumerate(batch_index_lists(idxs, batch_size)):
         ids, mask = padded_batch_layout(b, batch_size)

@@ -422,6 +422,20 @@ def _shape_bytes(shape_text: str) -> int:
     return total
 
 
+_INST_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.-]+)\s*=\s*(.+?)\s+([\w-]+)\(", re.M)
+
+
+def hlo_text_instructions(text: str) -> List[Tuple[str, str, int]]:
+    """[(name, opcode, result bytes)] of every instruction in ONE
+    optimized-HLO module's text (fused computations included) — logical
+    bytes of the result arrays (``_shape_bytes``), so a copy into a
+    padded layout still counts as its operand's size.  What
+    ``resident.assert_pool_read_in_place`` reads a compiled runner by."""
+    return [(name, op, _shape_bytes(shape_text))
+            for name, shape_text, op in _INST_RE.findall(text)]
+
+
 def _collective_inst_re() -> "re.Pattern":
     pattern = "|".join(re.escape(op) for op in COLLECTIVE_OPS)
     # The async lowering emits '-start'/'-done' pairs; the -start

@@ -172,6 +172,8 @@ class Trainer:
         # The resident feed's per-batch execution form (CPU meshes; see
         # _build_resident_batch_step) — also lazy.
         self._resident_batch_step: Optional[Callable] = None
+        # The row shape both forms were built for (_ensure_exec_form).
+        self._exec_row_shape: Optional[Tuple[int, ...]] = None
         # The generalized jit-compile counter (telemetry/runtime.py): a
         # no-op unless a run installed telemetry, so unit-test Trainers
         # never accumulate in a process-global registry.
@@ -545,7 +547,7 @@ class Trainer:
                 self.model, view, self.num_classes)
         return self._eval_steps[view]
 
-    def _build_resident_batch_step(self):
+    def _build_resident_batch_step(self, row_shape: Tuple[int, ...]):
         """The resident-gather feed's PER-BATCH execution form: one
         jitted dispatch = on-device gather from the pinned pool + the
         chained PRNG split + the train step.  Key consumption and batch
@@ -571,7 +573,8 @@ class Trainer:
         def resident_batch_step(state, images, labels, ids, mask, key,
                                 lr, class_weights, view, sharded=False):
             img, lab = resident_lib.pool_gather(
-                images, ids, mesh, labels=labels, sharded=sharded)
+                images, ids, mesh, row_shape, labels=labels,
+                sharded=sharded)
             batch = {"image": img, "label": lab, "mask": mask}
             new_key, sub = jax.random.split(key)
             new_state, loss, gnorm = train_step(state, batch, sub, lr,
@@ -580,8 +583,10 @@ class Trainer:
 
         return resident_batch_step
 
-    def _build_epoch_scan(self):
-        """One jitted call = one full epoch over device-resident data.
+    def _build_epoch_scan(self, row_shape: Tuple[int, ...]):
+        """One jitted call = one full epoch over device-resident data
+        (``images`` in the pinned form of rows of ``row_shape``:
+        resident.to_pinned).
 
         The host-batched path dispatches one jitted step per batch — fine
         when gather/decode is the bottleneck (disk datasets), pure dispatch
@@ -610,7 +615,8 @@ class Trainer:
                 # replicated layout's constraint commits — bit-identical
                 # batches, shard_map composes inside the scan body.
                 img, lab = resident_lib.pool_gather(
-                    images, idxs, mesh, labels=labels, sharded=sharded)
+                    images, idxs, mesh, row_shape, labels=labels,
+                    sharded=sharded)
                 batch = {"image": img, "label": lab, "mask": mask}
                 new_state, loss, gnorm = train_step(state, batch, sub, lr,
                                                     class_weights, view=view)
@@ -759,7 +765,8 @@ class Trainer:
                 return "resident_copy"
         return host
 
-    def _ensure_exec_form(self, feed: str) -> bool:
+    def _ensure_exec_form(self, feed: str,
+                          row_shape: Tuple[int, ...]) -> bool:
         """ONE rule for which jitted execution form a resident-feed fit
         uses — shared by fit and the select-time prefetch
         (prepare_next_fit), so the prefetch can never warm a form the
@@ -769,18 +776,26 @@ class Trainer:
         jitted gather+step dispatch per batch on CPU meshes — XLA:CPU
         runs conv bodies inside lax.scan several times slower than
         directly-dispatched ops (_build_resident_batch_step), and the
-        per-batch form also skips the step-bucket padding entirely."""
+        per-batch form also skips the step-bucket padding entirely.
+        Both forms read rows of ``row_shape`` out of a pinned array
+        (resident.pool_gather); a fit over rows of another shape gets
+        programs of its own."""
+        row_shape = tuple(int(d) for d in row_shape)
+        if row_shape != self._exec_row_shape:
+            self._epoch_scan = self._resident_batch_step = None
+            self._exec_row_shape = row_shape
         scan_form = (self.mesh.devices.flat[0].platform != "cpu"
                      or self.cfg.device_resident is True)
         use_scan = (feed == "resident_copy"
                     or (feed == "resident" and scan_form))
         if use_scan and self._epoch_scan is None:
-            self._epoch_scan = self._build_epoch_scan()
+            self._epoch_scan = self._build_epoch_scan(row_shape)
             tele_runtime.get_run().register_jit(
                 f"epoch_scan@{id(self):x}", self._epoch_scan)
         if (feed == "resident" and not use_scan
                 and self._resident_batch_step is None):
-            self._resident_batch_step = self._build_resident_batch_step()
+            self._resident_batch_step = self._build_resident_batch_step(
+                row_shape)
             tele_runtime.get_run().register_jit(
                 f"resident_batch_step@{id(self):x}",
                 self._resident_batch_step)
@@ -815,7 +830,7 @@ class Trainer:
         if feed in ("resident", "resident_copy"):
             # The SAME form rule + lazy build the fit runs — shared so
             # the prefetch can never warm a form the fit won't use.
-            self._ensure_exec_form(feed)
+            self._ensure_exec_form(feed, train_set.image_shape)
         elif len(labeled_now):
             # Bounded warm-up of the host gather/decode path; the rows
             # land in the memmap/page cache and are dropped here.
@@ -850,7 +865,10 @@ class Trainer:
                                 labeled_idxs: np.ndarray, batch_size: int):
         """Upload the labeled subset once, padded up to the row bucket so
         consecutive rounds reuse the same compiled scan (replicated; the
-        per-step gather output is what gets data-sharded)."""
+        per-step gather output is what gets data-sharded).  The rows go
+        up in the pinned form, like the shared pool's: one gather serves
+        both feeds."""
+        from ..parallel import resident as resident_lib
         images = train_set.gather(labeled_idxs)
         labels = train_set.targets[labeled_idxs].astype(np.int32)
         padded = self.bucket_steps(
@@ -860,7 +878,8 @@ class Trainer:
             images = np.concatenate(
                 [images, np.zeros((pad, *images.shape[1:]), images.dtype)])
             labels = np.concatenate([labels, np.zeros(pad, labels.dtype)])
-        return (mesh_lib.replicate(jnp.asarray(images), self.mesh),
+        return (mesh_lib.replicate(
+                    jnp.asarray(resident_lib.to_pinned(images)), self.mesh),
                 mesh_lib.replicate(jnp.asarray(labels), self.mesh))
 
     @classmethod
@@ -1003,7 +1022,7 @@ class Trainer:
                 sharding=self.pool_sharding)
             run = resident_lib.get_runner(
                 self.resident_pool, eval_step, self.mesh, "run_eval",
-                with_labels=True,
+                dataset.image_shape, with_labels=True,
                 sharded=mesh_lib.is_row_sharded(images_dev))
             totals = None
             for b in batch_index_lists(np.asarray(idxs), bs):
@@ -1142,7 +1161,7 @@ class Trainer:
             # SAME pinned pool scoring/evaluation use (zero host image
             # copies), "resident_copy" from a private labeled-subset upload.
             feed = self.resolve_train_feed(train_set, labeled_idxs, batch_hook)
-            use_scan = self._ensure_exec_form(feed)
+            use_scan = self._ensure_exec_form(feed, train_set.image_shape)
             self.last_feed = {"source": feed, "feed_stall_frac": None,
                               "host_wait_ms_p50": None,
                               "form": ("scan" if use_scan else

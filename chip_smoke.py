@@ -2,7 +2,7 @@
 """Prove on the chip that the main path starts, compiles, fits and takes
 the accelerator branches — through the entry points a user types.
 
-    python chip_smoke.py             # one TPU chip: phases a-e, ~12 min cold
+    python chip_smoke.py             # one TPU chip: phases f, a-e, ~13 min cold
     python chip_smoke.py --chips 4   # four chips: the sharded pair only
 
 This process NEVER imports JAX: a process that has touched JAX holds the
@@ -14,6 +14,12 @@ recorded (``round_journal.json``, ``metrics.jsonl``, ``run_report.json``,
 
 Default run, in order:
 
+  f  the pinned form: the scoring and the evaluation runner compiled for
+     the benchmark's pool (32,768 rows of 224 px, 4.93 GB, as shapes) must
+     read it in place: no pool-sized instruction but the parameter, and
+     temporaries under a quarter of the pool
+     (``resident.assert_pool_read_in_place``).  First, because it is cheap
+     and the phases after it pin pools in that form.
   a  ImageNet shape at full width: a seeded ImageFolder tree of JPEGs,
      SSLResNet50 / 1000-way head / batch 128 / 224 px, three MarginSampler
      rounds.  Host-prefetch feed with the native decoder; later rounds score
@@ -266,6 +272,40 @@ def start_cifar_child(data_dir: str, n_train: int, n_test: int,
                  os.path.join(WORK, "make_cifar.out"), env)
 
 
+_FORM_CHILD = """
+import json, sys
+import jax, jax.numpy as jnp
+from active_learning_tpu.data.core import IMAGENET_NORM, ViewSpec
+from active_learning_tpu.models.factory import get_network
+from active_learning_tpu.parallel import mesh as mesh_lib, resident
+from active_learning_tpu.strategies import scoring
+from active_learning_tpu.train.evaluation import make_eval_step
+name, rows, px, batch = sys.argv[1], *(int(v) for v in sys.argv[2:5])
+mesh = mesh_lib.make_mesh()
+rep = mesh_lib.replicated_sharding(mesh)
+spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+row = (px, px, 3)
+model = get_network("imagenet", name, dtype="auto")
+variables = jax.tree.map(
+    lambda s: spec(s.shape, s.dtype),
+    jax.eval_shape(lambda k: model.init(k, jnp.zeros((1, *row), jnp.float32),
+                                        train=False), jax.random.PRNGKey(0)))
+view = ViewSpec(IMAGENET_NORM, augment=False)
+images = spec(resident.pinned_shape((rows, *row)), jnp.uint8)
+small = (spec((batch,), jnp.int32), spec((batch,), jnp.float32))
+step = scoring.make_prob_stats_step(model, view)
+out = {"pinned_shape": list(images.shape)}
+out["score"] = resident.assert_pool_read_in_place(
+    resident.get_runner({}, step, mesh, scoring._runner_name(step), row),
+    (variables, images, *small), pool_arg=1)
+out["eval"] = resident.assert_pool_read_in_place(
+    resident.get_runner({}, make_eval_step(model, view, model.num_classes),
+                        mesh, "run_eval", row, with_labels=True),
+    (variables, images, spec((rows,), jnp.int32), *small), pool_arg=1)
+print("pool_form " + json.dumps(out), flush=True)
+"""
+
+
 def finish_cifar_child(proc: subprocess.Popen) -> None:
     try:
         rc = proc.wait(timeout=300)
@@ -434,6 +474,27 @@ def phase_dirs(name: str) -> dict:
 
 
 # -- the phases -------------------------------------------------------------------
+
+def phase_f(ctx) -> dict:
+    """Nothing runs and nothing is pinned: the programs are compiled for
+    shapes, on the device at hand, and the check raises in the child."""
+    size = ctx["size"]
+    run("f", [PY, "-c", _FORM_CHILD, size["form_model"],
+              str(size["form_rows"]), str(size["form_px"]),
+              str(size["form_batch"])],
+        ctx["env"], ctx["limits"]["f"])
+    m = re.search(r"^pool_form (\{.*\})$", tail(os.path.join(WORK, "f.out"),
+                                               4000), re.M)
+    check(m is not None, "the form child printed its figures")
+    got = json.loads(m.group(1))
+    pool = size["form_rows"] * size["form_px"] ** 2 * 3
+    asserted = [check(
+        got[k]["pool_bytes"] == pool,
+        f"the {k} runner reads its {pool}-byte pinned pool in place "
+        f"({got[k]['temp_bytes']} bytes of temporaries, no pool-sized "
+        "instruction but the parameter)") for k in ("score", "eval")]
+    return {"asserted": asserted, "path": got}
+
 
 def phase_a(ctx) -> dict:
     size = ctx["size"]
@@ -794,16 +855,20 @@ _PROBE = ("import jax, json; d = jax.devices(); print(json.dumps({"
 SIZES = {
     "real": {"pool": 4096, "test": 512, "classes": 128, "budget": 512,
              "big_model": "SSLResNet50", "embed_dim": 2048,
+             "form_model": "SSLResNet18", "form_rows": 32768, "form_px": 224,
+             "form_batch": 256,
              "cifar_pool": 50000, "cifar_test": 10000, "cifar_budget": 1000},
     # CPU rehearsal: same control flow, toy rows (never a result).
     "rehearse": {"pool": 96, "test": 32, "classes": 8, "budget": 16,
                  "big_model": "SSLResNet18", "embed_dim": 512,
+                 "form_model": "SSLResNet18", "form_rows": 200000,
+                 "form_px": 32, "form_batch": 8,
                  "cifar_pool": 2000, "cifar_test": 400, "cifar_budget": 64},
 }
-LIMITS = {"a": 420.0, "b": 240.0, "c": 300.0, "d": 360.0, "e": 240.0,
-          "four": 600.0}
-PHASES = {"a": phase_a, "b": phase_b, "c": phase_c, "d": phase_d,
-          "e": phase_e}
+LIMITS = {"f": 240.0, "a": 420.0, "b": 240.0, "c": 300.0, "d": 360.0,
+          "e": 240.0, "four": 600.0}
+PHASES = {"f": phase_f, "a": phase_a, "b": phase_b, "c": phase_c,
+          "d": phase_d, "e": phase_e}
 
 
 def main() -> int:
@@ -848,7 +913,7 @@ def main() -> int:
                "chips": args.chips, "limits": LIMITS,
                "tree": os.path.join(WORK, "imagenet"),
                "cifar": os.path.join(WORK, "cifar10")}
-        plan = ["four"] if args.chips == 4 else list("abcde")
+        plan = ["four"] if args.chips == 4 else list("fabcde")
         # Phase b's (and the pair's) archive is written beside phase a.
         ctx["cifar_child"] = start_cifar_child(
             ctx["cifar"], size["cifar_pool"], size["cifar_test"],

@@ -40,6 +40,7 @@ from active_learning_tpu.parallel import mesh as mesh_lib
 from active_learning_tpu.parallel import resident as resident_lib
 from active_learning_tpu.pool import bucket_size
 from active_learning_tpu.strategies import kcenter, scoring
+from active_learning_tpu.train.evaluation import make_eval_step
 from active_learning_tpu.train.trainer import Trainer, TrainState, num_batches
 
 HBM_BYTES = 16 * 10**9  # one v5e chip
@@ -111,6 +112,11 @@ def _trainer(dataset, model_name, mesh, pool_sharding="auto"):
     return Trainer(model, cfg, mesh, num_classes=model.num_classes)
 
 
+def _pinned_spec(rows, row_shape, sharding):
+    return _spec(resident_lib.pinned_shape((rows, *row_shape)), jnp.uint8,
+                 sharding)
+
+
 def _device_bytes(compiled) -> int:
     m = compiled.memory_analysis()
     return int(m.argument_size_in_bytes + m.output_size_in_bytes
@@ -147,9 +153,9 @@ def _epoch_scan_compiled(mesh, sharded: bool):
     pool = mesh_lib.row_sharding(mesh) if sharded else rep
     bs = trainer.padded_batch_size(128)
     steps = trainer.bucket_steps(num_batches(1000, bs))  # a 1000-row round
-    return trainer._build_epoch_scan().lower(
+    return trainer._build_epoch_scan((32, 32, 3)).lower(
         _state_spec(trainer, (32, 32, 3)),
-        _spec((POOL_ROWS, 32, 32, 3), jnp.uint8, pool),
+        _pinned_spec(POOL_ROWS, (32, 32, 3), pool),
         _spec((POOL_ROWS,), jnp.int32, pool),
         _spec((steps, bs), jnp.int32, rep),
         _spec((steps, bs), jnp.float32, rep),
@@ -174,9 +180,17 @@ def test_resnet18_resident_epoch_scan_four_devices_row_sharded(four_chips):
     # owner sum) cross the mesh inside the one program.
     assert "all-reduce" in text
     # Each device holds a QUARTER of the pool rows, not the pool.
-    quarter = f"u8[{POOL_ROWS // 4},32,32,3]"
-    assert quarter in text and f"u8[{POOL_ROWS},32,32,3]" not in text
+    quarter = f"u8[{POOL_ROWS // 4},24,128]"
+    assert quarter in text and f"u8[{POOL_ROWS},24,128]" not in text
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def _variables_spec(model, mesh):
+    rep = mesh_lib.replicated_sharding(mesh)
+    variables = jax.eval_shape(
+        lambda rng: model.init(rng, jnp.zeros((1, 224, 224, 3), jnp.float32),
+                               train=False), jax.random.PRNGKey(0))
+    return jax.tree.map(lambda s: _spec(s.shape, s.dtype, rep), variables)
 
 
 def test_224px_scoring_step_at_the_256_row_floor(one_chip):
@@ -184,19 +198,48 @@ def test_224px_scoring_step_at_the_256_row_floor(one_chip):
     step = scoring.make_prob_stats_step(
         model, ViewSpec(IMAGENET_NORM, augment=False))
     run = resident_lib.get_runner({}, step, one_chip,
-                                  scoring._runner_name(step))
+                                  scoring._runner_name(step), (224, 224, 3))
     rep = mesh_lib.replicated_sharding(one_chip)
-    variables = jax.eval_shape(
-        lambda rng: model.init(rng, jnp.zeros((1, 224, 224, 3), jnp.float32),
-                               train=False), jax.random.PRNGKey(0))
-    variables = jax.tree.map(lambda s: _spec(s.shape, s.dtype, rep),
-                             variables)
     compiled = run.lower(
-        variables, _spec((4096, 224, 224, 3), jnp.uint8, rep),
+        _variables_spec(model, one_chip),
+        _pinned_spec(4096, (224, 224, 3), rep),
         _spec((256,), jnp.int32, rep), _spec((256,), jnp.float32, rep)
     ).compile()
     assert "bf16[" in compiled.as_text()
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("runner,chips", [("score", 1), ("eval", 1),
+                                          ("score", 4)])
+def test_224px_runners_read_the_pinned_pool_in_place(runner, chips, one_chip,
+                                                     four_chips):
+    """The benchmark's pool (32,768 rows of 224 px a chip, 4.93 GB) under
+    the v5e's own layout choice: no program re-lays it out or copies it.
+    With the rows pinned as ``[N, 224, 224, 3]`` this fails on
+    ``copy.7 = u8[32768,224,224,3]{2,1,3,0} copy(...{0,2,3,1})`` and 5.64
+    GB of temporaries (PERF.md section 6, PR 26)."""
+    mesh = one_chip if chips == 1 else four_chips
+    rep = mesh_lib.replicated_sharding(mesh)
+    pool = rep if chips == 1 else mesh_lib.row_sharding(mesh)
+    rows, batch = 32_768 * chips, 256 * chips
+    model = get_network("imagenet", "SSLResNet18", dtype="bfloat16")
+    view = ViewSpec(IMAGENET_NORM, augment=False)
+    small = (_spec((batch,), jnp.int32, rep), _spec((batch,), jnp.float32, rep))
+    images = _pinned_spec(rows, (224, 224, 3), pool)
+    if runner == "score":
+        step = scoring.make_prob_stats_step(model, view)
+        run = resident_lib.get_runner({}, step, mesh,
+                                      scoring._runner_name(step),
+                                      (224, 224, 3), sharded=chips > 1)
+        args = (_variables_spec(model, mesh), images, *small)
+    else:
+        run = resident_lib.get_runner(
+            {}, make_eval_step(model, view, model.num_classes), mesh,
+            "run_eval", (224, 224, 3), with_labels=True)
+        args = (_variables_spec(model, mesh), images,
+                _spec((rows,), jnp.int32, pool), *small)
+    got = resident_lib.assert_pool_read_in_place(run, args, pool_arg=1)
+    assert got["pool_bytes"] == 32_768 * 224 * 224 * 3
 
 
 def test_batched_kcenter_scan_50000_by_2048(one_chip):

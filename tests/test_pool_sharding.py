@@ -280,8 +280,9 @@ class TestResidentBytesAndBudget:
             lambda im, lb, i: resident_lib.sharded_pool_gather(
                 im, i, mesh, labels=lb))(images_dev, labels_dev,
                                          jax.numpy.asarray(ids))
-        np.testing.assert_array_equal(np.asarray(img),
-                                      al_set.images[ids])
+        np.testing.assert_array_equal(
+            resident_lib.from_pinned(np.asarray(img), al_set.image_shape),
+            al_set.images[ids])
         np.testing.assert_array_equal(
             np.asarray(lab), al_set.targets[ids].astype(np.int32))
 

@@ -340,7 +340,8 @@ class TestDeviceNames:
                 ("badge", scoring.make_badge_step(model, al_set.view)),
                 ("mase", scoring.make_mase_step(model, al_set.view))):
             run = resident_lib.get_runner(cache, step, mesh,
-                                          scoring._runner_name(step))
+                                          scoring._runner_name(step),
+                                          al_set.image_shape)
             text = run.lower(variables, images, ids,
                              mask).compile().as_text()
             assert f"jit_run_score_{kind}" in text
@@ -348,7 +349,7 @@ class TestDeviceNames:
                 assert f"/{scope}/" in text, (kind, scope)
         run = resident_lib.get_runner(
             cache, make_eval_step(model, al_set.view, 4), mesh, "run_eval",
-            with_labels=True)
+            al_set.image_shape, with_labels=True)
         text = run.lower(variables, images, labels, ids,
                          mask).compile().as_text()
         assert "jit_run_eval" in text
@@ -365,8 +366,8 @@ class TestDeviceNames:
                           num_classes=4)
         state = trainer.init_state(jax.random.PRNGKey(0),
                                    train_set.gather(np.arange(2)))
-        scan = trainer._build_epoch_scan()
-        images = jnp.asarray(train_set.images)
+        scan = trainer._build_epoch_scan(train_set.image_shape)
+        images = jnp.asarray(resident_lib.to_pinned(train_set.images))
         labels = jnp.asarray(train_set.targets)
         idx = jnp.zeros((2, 8), jnp.int32)
         text = scan.lower(

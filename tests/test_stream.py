@@ -850,7 +850,8 @@ class TestIncrementalResidentUpdate:
         assert resident_lib.update_rows(cache, ds, mesh, 80, 96)
         key = (id(ds.images), 96)
         _, images_dev, labels_dev = cache["images"][key]
-        got = np.asarray(images_dev)[:96]
+        got = resident_lib.from_pinned(np.asarray(images_dev)[:96],
+                                       ds.image_shape)
         np.testing.assert_array_equal(got, ds.images[:96])
         np.testing.assert_array_equal(
             np.asarray(labels_dev)[:96],
@@ -935,7 +936,9 @@ class TestIncrementalResidentUpdate:
         assert resident_lib.update_rows(cache, ds, mesh, 0, 96)
         assert {k: v._cache_size() for k, v in runners.items()} == sizes
         np.testing.assert_array_equal(
-            np.asarray(cache["images"][(id(ds.images), 96)][1])[:96],
+            resident_lib.from_pinned(
+                np.asarray(cache["images"][(id(ds.images), 96)][1])[:96],
+                ds.image_shape),
             ds.images[:96])
 
     def test_prewarm_is_noop_once_warm(self, monkeypatch):
@@ -969,7 +972,9 @@ class TestIncrementalResidentUpdate:
         # The untouched entry still serves reads.
         key = (id(ds.images), 96)
         np.testing.assert_array_equal(
-            np.asarray(cache["images"][key][1])[:96], ds.images[:96])
+            resident_lib.from_pinned(
+                np.asarray(cache["images"][key][1])[:96], ds.image_shape),
+            ds.images[:96])
 
     def test_failed_donating_update_drops_entry(self, monkeypatch):
         """A failure inside the donating image dispatch may have
