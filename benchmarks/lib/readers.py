@@ -1,12 +1,17 @@
 """The per-layer metric readers.  A metric's data file names one of these
 and its parameters; a reader that finds nothing to read returns None and the
-metric is left out of the line."""
+metric is left out of the line.  A reader may live in any file of ``lib/``:
+the metric's data file names it (``"module": "spans"``; default this file),
+``reader_for`` imports it, and importing it registers its readers here."""
 
 from __future__ import annotations
 
 from typing import Callable, Dict, Optional
 
-from . import flops as flops_lib
+import importlib
+import re
+
+from . import peaks as peaks_lib
 from . import trace as trace_lib
 
 READERS: Dict[str, Callable] = {}
@@ -17,6 +22,20 @@ def reader(name: str):
         READERS[name] = fn
         return fn
     return deco
+
+
+def reader_for(spec: Dict) -> Callable:
+    """The reader a metric's data file names, its module imported first."""
+    module = spec.get("module", "readers")
+    if not re.fullmatch(r"[A-Za-z_][A-Za-z0-9_]*", module):
+        raise ValueError(f"metric {spec.get('name')!r}: module {module!r} "
+                         f"is not the name of a file of lib/")
+    importlib.import_module(f"{__package__}.{module}")
+    try:
+        return READERS[spec["reader"]]
+    except KeyError:
+        raise KeyError(f"metric {spec.get('name')!r}: lib/{module}.py "
+                       f"registers no reader {spec['reader']!r}") from None
 
 
 @reader("sink_phase")
@@ -79,7 +98,7 @@ def trace_program(ctx: Dict, match, kinds, phase=None) -> Optional[float]:
             work[f] += ctx["work"].get(k, {}).get(f, 0.0)
     if seconds <= 0 or work["flops"] <= 0:
         return None
-    least, bound = flops_lib.least_seconds(work, ctx["peaks"])
+    least, bound = peaks_lib.least_seconds(work, ctx["peaks"])
     ctx.setdefault("bounds", {})[",".join(match)] = bound
     return 100.0 * least / seconds
 

@@ -1,19 +1,19 @@
 """The plain reference and the comparison that decides ``correct``.
 
-A straightforward ``jax.numpy`` ResNet (float32, matmul precision
-``highest``, BatchNorm on its stored statistics as the program runs it under
-a pretrained checkpoint), its loss and gradients, plain SGD with momentum,
-greedy k-center, and the comparison of what the timed path produced with
-what this gives on the same rows.  It imports nothing of the program and
-takes no tensor the program made: its parameters come from
-``data.make_weights`` and the rows from ``data.make_data``.  From the
-program it takes only decisions: which rows each step drew, the step's
-augmentation key, the epoch the program kept, what it picked.
+The configuration's family (``families/__init__.py``) gives the plain
+forward (float32, matmul precision ``highest``) and the train view as
+arguments; here are its loss and gradients, plain SGD with momentum, greedy
+k-center, and the comparison of what the timed path produced with what this
+gives on the same rows.  It imports nothing of the program and takes no
+tensor the program made: its parameters come from the family's
+``make_weights`` and the rows from its ``make_data``.  From the program it
+takes only decisions: which rows each step drew, the step's augmentation
+key, the epoch the program kept, what it picked.
 
-``quant="fp8"`` is the control: the same reference with every convolution's
-and matmul's inputs rounded to float8 (e4m3, per-tensor scale), the step
-below the bfloat16 the configurations state.  ``fault`` plants the faults a
-cell can have in the reference put in the program's place.
+``quant="fp8"`` is the control: the same reference with the inputs of every
+contraction rounded to float8 (e4m3, per-tensor scale), the step below the
+bfloat16 the configurations state.  ``fault`` plants the faults a cell can
+have in the reference put in the program's place.
 """
 
 from __future__ import annotations
@@ -23,14 +23,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from . import data as data_lib
 
-IMAGENET_MEAN = (0.485, 0.456, 0.406)
-IMAGENET_STD = (0.229, 0.224, 0.225)
-BN_EPS = 1e-5
-
-
-def _q(x, quant: Optional[str]):
+def q(x, quant: Optional[str]):
     """Round to the control's precision, straight-through for gradients."""
     if quant is None:
         return x
@@ -43,84 +37,21 @@ def _q(x, quant: Optional[str]):
     return x + jax.lax.stop_gradient(rounded * scale - x)
 
 
-def _conv(x, w, stride: int, pad: int, quant):
-    import jax
-    return jax.lax.conv_general_dilated(
-        _q(x, quant), _q(w, quant), (stride, stride),
-        [(pad, pad), (pad, pad)],
-        dimension_numbers=("NHWC", "OIHW", "NHWC"),
-        precision=jax.lax.Precision.HIGHEST)
-
-
-def _bn(x, p: Dict, name: str):
-    import jax
-    mul = p[f"{name}.weight"] * jax.lax.rsqrt(
-        p[f"{name}.running_var"] + BN_EPS)
-    return x * mul + (p[f"{name}.bias"] - p[f"{name}.running_mean"] * mul)
-
-
-def embed(p: Dict, x_u8, config: Dict, quant=None):
-    """uint8 rows [B,H,W,C] -> float32 embedding [B,D]."""
-    import jax
-    import jax.numpy as jnp
-    mean = jnp.asarray(IMAGENET_MEAN, jnp.float32) * 255.0
-    std = jnp.asarray(IMAGENET_STD, jnp.float32) * 255.0
-    x = (x_u8.astype(jnp.float32) - mean) / std
-    x = _conv(x, p["encoder.conv1.weight"], 2, 3, quant)
-    x = jax.nn.relu(_bn(x, p, "encoder.bn1"))
-    x = jax.lax.reduce_window(
-        x, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1),
-        [(0, 0), (1, 1), (1, 1), (0, 0)])
-    bottleneck = config["block"] == "bottleneck"
-    for prefix, convs, stride, down in data_lib.block_keys(config):
-        res = x
-        if bottleneck:
-            y = _conv(x, p[f"{prefix}.conv1.weight"], 1, 0, quant)
-            y = jax.nn.relu(_bn(y, p, f"{prefix}.bn1"))
-            y = _conv(y, p[f"{prefix}.conv2.weight"], stride, 1, quant)
-            y = jax.nn.relu(_bn(y, p, f"{prefix}.bn2"))
-            y = _conv(y, p[f"{prefix}.conv3.weight"], 1, 0, quant)
-            y = _bn(y, p, f"{prefix}.bn3")
-        else:
-            y = _conv(x, p[f"{prefix}.conv1.weight"], stride, 1, quant)
-            y = jax.nn.relu(_bn(y, p, f"{prefix}.bn1"))
-            y = _conv(y, p[f"{prefix}.conv2.weight"], 1, 1, quant)
-            y = _bn(y, p, f"{prefix}.bn2")
-        if down:
-            res = _conv(x, p[f"{prefix}.downsample.0.weight"], stride, 0,
-                        quant)
-            res = _bn(res, p, f"{prefix}.downsample.1")
-        x = jax.nn.relu(res + y)
-    return jnp.mean(x, axis=(1, 2))
-
-
-def head(p: Dict, emb, quant=None):
-    import jax
-    import jax.numpy as jnp
-    return jnp.matmul(_q(emb, quant), _q(p["linear.weight"], quant).T,
-                      precision=jax.lax.Precision.HIGHEST) + p["linear.bias"]
-
-
-def _flip(x_u8, flips):
-    import jax.numpy as jnp
-    return jnp.where(flips[:, None, None, None], x_u8[:, :, ::-1, :], x_u8)
-
-
 @functools.lru_cache(maxsize=None)
-def _forward_fn(config_key: str, quant: Optional[str]):
+def _forward_fn(family, config_key: str, quant: Optional[str]):
     import json
     import jax
     config = json.loads(config_key)
 
-    def forward(p, x_u8):
-        emb = embed(p, x_u8, config, quant)
-        return head(p, emb, quant), emb
+    def forward(p, rows):
+        emb = family.embed(p, rows, config, quant)
+        return family.head(p, emb, quant), emb
 
     return jax.jit(forward)
 
 
 @functools.lru_cache(maxsize=None)
-def _step_fn(config_key: str, quant: Optional[str], frozen: bool,
+def _step_fn(family, config_key: str, quant: Optional[str], frozen: bool,
              mu: float, wd: float, update: bool):
     """One SGD step over a batch given in micro-batches."""
     import json
@@ -128,26 +59,31 @@ def _step_fn(config_key: str, quant: Optional[str], frozen: bool,
     import jax.numpy as jnp
     config = json.loads(config_key)
 
-    def loss_sum(trained, fixed, x_u8, labels, weights, flips):
+    def loss_sum(trained, fixed, rows, labels, weights):
         p = {**fixed, **trained}
-        emb = embed(p, _flip(x_u8, flips), config, quant)
+        emb = family.embed(p, rows, config, quant)
         if frozen:
             emb = jax.lax.stop_gradient(emb)
-        logp = jax.nn.log_softmax(head(p, emb, quant))
+        logp = jax.nn.log_softmax(family.head(p, emb, quant))
         ce = -jnp.take_along_axis(logp, labels[:, None], axis=1)[:, 0]
         return jnp.sum(ce * weights)
 
-    def step(trained, momentum, fixed, xs, labels, weights, flips, lr):
-        """``xs`` [micro-batches, rows, H, W, C]: the batch's gradient is the
-        sum of its micro-batches' (the rows are independent under stored
-        BatchNorm statistics), over the batch's weight."""
+    def step(trained, momentum, fixed, xs, labels, weights, step_key,
+             augment, lr):
+        """``xs`` [micro-batches, rows, ...]: the batch's gradient is the
+        sum of its micro-batches' (the forward treats its rows one by one),
+        over the batch's weight.  The train view is taken of the whole
+        batch, as the program's step draws it from the step's key."""
+        xs = family.train_view(xs.reshape((-1,) + xs.shape[2:]), step_key,
+                               augment).reshape(xs.shape)
+
         def body(carry, inp):
             total, grads = carry
             val, g = jax.value_and_grad(loss_sum)(trained, fixed, *inp)
             return (total + val, jax.tree.map(jnp.add, grads, g)), None
         zero = jax.tree.map(jnp.zeros_like, trained)
         (total, grads), _ = jax.lax.scan(
-            body, (jnp.float32(0.0), zero), (xs, labels, weights, flips))
+            body, (jnp.float32(0.0), zero), (xs, labels, weights))
         denom = jnp.maximum(jnp.sum(weights), 1e-12)
         grads = jax.tree.map(lambda g: g / denom, grads)
         gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
@@ -166,32 +102,28 @@ def _step_fn(config_key: str, quant: Optional[str], frozen: bool,
 
 def _config_key(config: Dict) -> str:
     import json
-    keep = ("image_size", "in_channels", "num_filters", "block",
-            "stage_sizes", "num_classes")
-    return json.dumps({k: config[k] for k in keep}, sort_keys=True)
+    return json.dumps(config, sort_keys=True)
 
 
-def step_flips(epoch_key: np.ndarray, steps: int, batch: int) -> np.ndarray:
-    """The flip bits of each step of one epoch, from the epoch's key: the
-    key chain splits once per step (carry, step key), and the step's
-    augmentation draws its flips from the second half of the step key's own
-    split (the first half is the crop's, unused at 224 px)."""
+def step_keys(epoch_key: np.ndarray, steps: int) -> np.ndarray:
+    """The key of each step of one epoch, [steps, 2] uint32: the program's
+    key chain splits once per step (carry, step key).  What a step draws
+    from its key is the family's ``train_view``."""
     import jax
     key = jax.numpy.asarray(np.asarray(epoch_key, dtype=np.uint32))
-    out = np.zeros((steps, batch), dtype=bool)
+    out = np.zeros((steps, 2), dtype=np.uint32)
     for s in range(steps):
         key, sub = jax.random.split(key)
-        _, key_flip = jax.random.split(sub)
-        out[s] = np.asarray(jax.random.bernoulli(key_flip, 0.5, (batch,)))
+        out[s] = np.asarray(sub)
     return out
 
 
-def forward_rows(p: Dict, images: np.ndarray, idxs: np.ndarray, config: Dict,
-                 quant=None, block: int = 128) -> Tuple[np.ndarray,
-                                                         np.ndarray]:
+def forward_rows(family, p: Dict, images: np.ndarray, idxs: np.ndarray,
+                 config: Dict, quant=None, block: int = 128
+                 ) -> Tuple[np.ndarray, np.ndarray]:
     """(logits, embeddings) of ``images[idxs]``, in blocks of rows."""
     import jax.numpy as jnp
-    fwd = _forward_fn(_config_key(config), quant)
+    fwd = _forward_fn(family, _config_key(config), quant)
     logits, embs = [], []
     idxs = np.asarray(idxs)
     for s in range(0, len(idxs), block):
@@ -205,7 +137,7 @@ def forward_rows(p: Dict, images: np.ndarray, idxs: np.ndarray, config: Dict,
     return np.concatenate(logits), np.concatenate(embs)
 
 
-def follow_fit(weights: Dict[str, np.ndarray], images: np.ndarray,
+def follow_fit(family, weights: Dict[str, np.ndarray], images: np.ndarray,
                labels: np.ndarray, fit: Dict, config: Dict, hyper: Dict,
                frozen: bool, quant=None, fault: Optional[str] = None,
                micro: int = 32) -> Dict[str, Any]:
@@ -216,11 +148,10 @@ def follow_fit(weights: Dict[str, np.ndarray], images: np.ndarray,
     key and learning rate.  Returns the per-step losses and gradient norms of
     the first epoch and the parameters after the epoch the program kept."""
     import jax.numpy as jnp
-    step = _step_fn(_config_key(config), quant, frozen,
+    step = _step_fn(family, _config_key(config), quant, frozen,
                     float(hyper["momentum"]), float(hyper["weight_decay"]),
                     fault != "state_unchanged")
-    train_keys = [k for k in data_lib.trainable_keys(weights)
-                  if not frozen or k.startswith("linear.")]
+    train_keys = family.trainable_keys(weights, head_only=frozen)
     trained = {k: jnp.array(weights[k]) for k in train_keys}
     fixed = {k: jnp.asarray(v) for k, v in weights.items()
              if k not in trained}
@@ -232,9 +163,8 @@ def follow_fit(weights: Dict[str, np.ndarray], images: np.ndarray,
         batch = idx.shape[1]
         m = min(micro, batch)
         assert batch % m == 0, "micro-batch must divide the batch"
-        flips = (step_flips(ep["key"], len(idx), batch)
-                 if ep.get("augment", True)
-                 else np.zeros(idx.shape, dtype=bool))
+        keys = step_keys(ep["key"], len(idx))
+        augment = bool(ep.get("augment", True))
         for s in range(len(idx)):
             rows, w = idx[s], mask[s].astype(np.float32)
             if fault == "half_batch":
@@ -247,7 +177,7 @@ def follow_fit(weights: Dict[str, np.ndarray], images: np.ndarray,
                 jnp.asarray(images[rows].reshape(shape + images.shape[1:])),
                 jnp.asarray(labels[rows].astype(np.int32).reshape(shape)),
                 jnp.asarray(w.reshape(shape)),
-                jnp.asarray(flips[s].reshape(shape)),
+                jnp.asarray(keys[s]), jnp.asarray(augment),
                 jnp.float32(ep["lr"]))
             if e == 0 and s < 3:
                 losses.append(loss)
@@ -343,16 +273,17 @@ def leaf_change_gap(cand: Dict[str, np.ndarray], ref: Dict[str, np.ndarray],
                         / np.maximum(np.maximum(ref_n, floor), 1e-30)))
 
 
-def reference_outputs(weights, images, labels, test_images, test_labels,
-                      record: Dict, config: Dict, hyper: Dict, frozen: bool,
-                      quant=None, fault=None, micro: int = 32) -> Dict:
+def reference_outputs(family, weights, images, labels, test_images,
+                      test_labels, record: Dict, config: Dict, hyper: Dict,
+                      frozen: bool, quant=None, fault=None,
+                      micro: int = 32) -> Dict:
     """Everything the comparison reads, computed by the reference (or by the
     control / a planted fault) on the rows the program's record names."""
-    fit = follow_fit(weights, images, labels, record["fit"], config, hyper,
-                     frozen, quant=quant, fault=fault, micro=micro)
+    fit = follow_fit(family, weights, images, labels, record["fit"], config,
+                     hyper, frozen, quant=quant, fault=fault, micro=micro)
     import jax.numpy as jnp
     params = {k: jnp.asarray(v) for k, v in fit["params"].items()}
-    t_logits, _ = forward_rows(params, test_images,
+    t_logits, _ = forward_rows(family, params, test_images,
                                np.arange(len(test_images)), config, quant)
     out = {"losses": fit["losses"], "gnorms": fit["gnorms"],
            "params": fit["params"], "trained": fit["trained"],
@@ -362,8 +293,8 @@ def reference_outputs(weights, images, labels, test_images, test_labels,
     # Scores are taken with the parameters the program scored with: the
     # kept epoch's under fine-tuning, the seed's encoder when it is frozen
     # (the embedding never sees the head).
-    s_logits, s_emb = forward_rows(seed_p if frozen else params, images,
-                                   sample, config, quant)
+    s_logits, s_emb = forward_rows(family, seed_p if frozen else params,
+                                   images, sample, config, quant)
     if record["score"]["kind"] == "margin":
         out["scores"] = margins(s_logits)
     else:

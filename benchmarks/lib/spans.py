@@ -19,12 +19,16 @@ from __future__ import annotations
 
 import bisect
 import fnmatch
+import glob
 import json
+import os
 import re
+import statistics
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 from . import readers as readers_lib
 from . import trace as trace_lib
+from . import window as window_lib
 
 UNATTRIBUTED = "unattributed"
 HOST_PLANE = re.compile(r"^/host:")
@@ -363,6 +367,104 @@ def clock_disagreement(pairs: Sequence[Tuple[int, int, int, int]]
         return None
     worst = max(max(abs(c - a), abs(d - b)) for a, b, c, d in pairs)
     return {"spans_compared": len(pairs), "largest_s": worst / 1e9}
+
+
+# -- the record laid over the traced round ------------------------------------
+
+def load_span_record(trace_dir: str) -> Optional[Dict]:
+    """The program's span record (``trace.json`` under the run's log
+    directory, beside ``trace_dir``); None where its recorder was off."""
+    logs = os.path.join(os.path.dirname(trace_dir), "logs")
+    files = sorted(glob.glob(os.path.join(logs, "**", "trace*.json"),
+                             recursive=True))
+    return load_record(files[-1]) if files else None
+
+
+def read_spans(ctl, red: Dict, planes: List[Dict],
+               scopes: Sequence[str]) -> Dict:
+    """Lay the program's span record over the traced round's reduction
+    ``red`` (in place): the record itself, every idle instant of the device
+    given to the deepest span open on the round's thread (``idle_gaps`` then
+    shows that attribution), and device seconds per named scope from the
+    operations' ``op_name``.  ``ctl`` holds the runner's ``trace_dir``, its
+    ``trace_anchor`` and ``trace_span`` (``perf_counter`` seconds) and its
+    ``pauses``.  Returns what the runner prints beside the metrics: the host
+    self time of the traced round, the two clocks' disagreement and the
+    tree's checks; nothing where the recorder was off."""
+    record = load_span_record(ctl.trace_dir)
+    if record is None:
+        return {}
+    spans = record["spans"]
+    anchor = trace_lib.first_event_ns(planes, "bench_anchor")
+    by_anchor = anchor - int(ctl.trace_anchor * 1e9)    # perf_counter -> trace
+    extras = load_trace_extras(
+        trace_lib.find_xplane(ctl.trace_dir), {s["name"] for s in spans})
+    pairs = paired(spans, extras["host"], lambda t: int(t * 1e9) + by_anchor)
+    # Where the host plane has the annotations, tie the record to the
+    # trace's clock by them (the median shift of the paired span starts),
+    # not by the runner's anchor program.
+    offset = by_anchor + (int(statistics.median(
+        c - a for a, _, c, _ in pairs)) if pairs else 0)
+    t0 = int(ctl.trace_span[0] * 1e9) + by_anchor
+    t1 = int(ctl.trace_span[1] * 1e9) + by_anchor
+    on_trace = [{**s, "t0": int(s["t0"] * 1e9) + offset,
+                 "t1": int(s["t1"] * 1e9) + offset} for s in spans]
+    rounds = [s for s in on_trace if s["name"] == "round"]
+    if not rounds:
+        return {}
+    timeline = deepest_timeline(on_trace, rounds[0]["tid"])
+    first = planes[0]["lines"]
+    ops = first.get(trace_lib.OP_LINE) or first.get(trace_lib.MODULE_LINE, ())
+    idle = {k: v / 1e9 for k, v in idle_by_span(
+        device_gaps(ops, t0, t1), timeline).items()}
+    red["spans_record"] = record
+    red["idle_by_span"] = idle
+    red["idle_gaps"] = sorted(idle.items(), key=lambda kv: -kv[1])[:10]
+    out: Dict = {"clock": clock_disagreement(pairs)}
+    if extras["hlo"]:
+        named = name_ops(first.get(trace_lib.MODULE_LINE, ()),
+                         first.get(trace_lib.OP_LINE, ()), extras["hlo"])
+        red["scope_s"] = scope_seconds_of(named, t0, t1, scopes)
+        out["ops_named"] = [sum(1 for n in named if n[0]), len(named),
+                            len(extras["hlo"])]
+
+    # The traced round: the one whose ``round`` span shares most of the
+    # window (its end and the trace's stop lie a dispatch apart).
+    traced = max(rounds, key=lambda s: min(s["t1"], t1) - max(s["t0"], t0))
+    selfs = self_seconds(spans)
+    by_id = {s["id"]: s for s in spans}
+    total_idle = sum(idle.values())
+    # A phase's span is named as its sink metric, less the ``rd_``.
+    not_deeper = {m[len("rd_"):] for m in window_lib.PHASE_METRICS} | {
+        "round", "experiment", UNATTRIBUTED}
+    shallow = sum(v for k, v in idle.items() if k in not_deeper)
+    host_self: Dict[str, float] = {}
+    for s in spans:
+        if s["round"] != traced["round"]:
+            continue
+        own = selfs[s["id"]]
+        if s["name"] == "round_epilogue":
+            # The runner stops the trace at a boundary, inside an epilogue:
+            # that pause is the runner's, not the program's.
+            own -= sum(max(0.0, min(p1, s["t1"]) - max(p0, s["t0"]))
+                       for p0, p1 in ctl.pauses)
+        host_self[s["name"]] = host_self.get(s["name"], 0.0) + own
+    out.update({
+        "traced_round": traced["round"],
+        "round_span_s": (traced["t1"] - traced["t0"]) / 1e9,
+        "subtree_self_sum_s": sum(
+            selfs[s["id"]] for s in spans
+            if s["round"] == traced["round"]
+            and s["tid"] == by_id[traced["id"]]["tid"]
+            and (s["id"] == traced["id"]
+                 or has_ancestor(s, by_id, "round"))),
+        "idle_total_s": total_idle,
+        "idle_deeper_than_phase_share": (
+            1.0 - shallow / total_idle if total_idle else None),
+        "idle_unattributed_share": (
+            idle.get(UNATTRIBUTED, 0.0) / total_idle if total_idle else None),
+        "host_self": sorted(host_self.items(), key=lambda kv: -kv[1])[:10]})
+    return out
 
 
 # -- the readers --------------------------------------------------------------

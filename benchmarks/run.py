@@ -7,7 +7,10 @@ Drives warm active-learning rounds through the program's own
 measures ``round_s`` over a window that opens and closes at round boundaries,
 then checks what the timed rounds produced against the plain reference
 (``lib/reference.py``).  Everything that belongs to one cell, configuration
-or per-layer metric is a data file found by its name in ``BENCHMARK.json``.
+or per-layer metric is a data file found by its name in ``BENCHMARK.json``;
+everything that belongs to one kind of model and row is the module the
+configuration names as its ``family`` (``families/__init__.py``), and a
+metric's reader is looked up in the file of ``lib/`` its data file names.
 """
 
 from __future__ import annotations
@@ -17,6 +20,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -30,8 +34,32 @@ sys.path.insert(0, HERE)
 sys.path.insert(1, ROOT)
 
 WARM_ROUNDS = 2          # round 0 fits cold, round 1 runs the first query
-EVAL_ROWS = 256          # the program's scoring/evaluation batch at 224 px
 REHEARSAL_EXIT = 3
+# The program's named scopes: device time is summed per scope.
+SCOPES = ("pool_gather", "view", "forward", "score_head",
+          "forward_backward", "optimizer")
+
+
+def pin_allocator() -> bool:
+    """Fix glibc's two thresholds for the whole run, so that the host's work
+    on the model's bytes costs every process the same.
+
+    Left to itself glibc maps a block of 128 KiB or more afresh and hands it
+    back when freed, until the free of such a block raises the threshold to
+    its size: whether and when that happens depends on the order of a
+    process's frees, and a page touched for the first time costs about 5 us
+    on the machines the cells run on.  That is the two levels ``round_s``
+    ran at (``PERF.md`` section 2).  With the thresholds set, blocks of up to
+    32 MiB (glibc's cap) are recycled from a heap that is never trimmed, in
+    every process from its start."""
+    import ctypes
+    try:
+        libc = ctypes.CDLL("libc.so.6")
+    except OSError:          # not glibc: nothing to pin
+        return False
+    m_trim_threshold, m_mmap_threshold = -1, -3      # <malloc.h>
+    return bool(libc.mallopt(m_mmap_threshold, 32 << 20)
+                and libc.mallopt(m_trim_threshold, 1 << 30))
 
 
 def log(msg: str) -> None:
@@ -45,7 +73,8 @@ def load_json(path: str):
 
 
 def load_cell(args) -> dict:
-    """The cell's manifest entry and its data files."""
+    """The cell's manifest entry, its data files and its family."""
+    import families
     manifest = load_json(os.path.join(ROOT, "BENCHMARK.json"))
     base = manifest["paths"][0]
     if args.workload_file:
@@ -73,7 +102,8 @@ def load_cell(args) -> dict:
     scale = dict(config["scale"])
     scale.update(workload.get("scale", {}))
     return {"entry": entry, "workload": workload, "config": config,
-            "scale": scale, "metrics": metrics}
+            "scale": scale, "metrics": metrics,
+            "family": families.load(config["family"], ROOT)}
 
 
 # -- what the hooks record ---------------------------------------------------
@@ -108,7 +138,8 @@ def install_hooks(strategy, rec: Record, break_how: str = "") -> None:
     def collect_scores(idxs, kind, keys=None):
         out = orig_collect(idxs, kind, keys=keys)
         rec.scores[strategy.round] = {
-            "idxs": np.asarray(idxs).copy(), "kind": kind, "out": out}
+            "idxs": np.asarray(idxs).copy(), "kind": kind, "out": out,
+            "batch": int(strategy._score_batch_size())}
         return out
     strategy.collect_scores = collect_scores
 
@@ -166,6 +197,8 @@ def install_hooks(strategy, rec: Record, break_how: str = "") -> None:
         count = float(perf["count"])
         rec.evals.append({
             "round": strategy.round, "rows": int(len(idxs)),
+            "batch": int(trainer.padded_batch_size(
+                trainer.eval_batch_size(dataset))),
             "count": int(round(count)),
             "test": dataset is strategy.test_set,
             "top1": int(round(float(perf["accuracy"]) * count)),
@@ -259,11 +292,10 @@ class Controller:
 # -- set-up ------------------------------------------------------------------
 
 def build_configs(cell: dict, seed: int, work_dir: str, ckpt_file: str,
-                  rehearse: bool = False):
+                  rehearse: bool = False, trace: bool = False):
     from active_learning_tpu.config import (
         ExperimentConfig, LoaderConfig, OptimizerConfig, PretrainedConfig,
         SchedulerConfig, TrainConfig)
-    import dataclasses
     wl, cfgf, scale = cell["workload"], cell["config"], cell["scale"]
     tr = wl["train"]
     batch = int(cfgf["train_batch"])
@@ -282,8 +314,8 @@ def build_configs(cell: dict, seed: int, work_dir: str, ckpt_file: str,
         exp_name=cell["entry"]["name"].replace(".", "_"),
         exp_hash="bench", log_dir=os.path.join(work_dir, "logs"),
         ckpt_path=os.path.join(work_dir, "ckpt"),
-        dataset="imagenet", arg_pool="benchmark",
-        strategy=wl["strategy"], model=cfgf["model"],
+        arg_pool="benchmark", strategy=wl["strategy"],
+        **cell["family"].experiment(cfgf),
         freeze_feature=bool(wl["freeze_feature"]),
         rounds=int(wl.get("rounds", 100000)),
         round_budget=int(scale["round_budget"]),
@@ -294,26 +326,24 @@ def build_configs(cell: dict, seed: int, work_dir: str, ckpt_file: str,
         compilation_cache_dir=(
             "" if rehearse else os.path.join(ROOT, ".jax_cache")),
         **wl.get("experiment", {}))
+    if trace:
+        # The program's span recorder, through its documented switch: the
+        # span, counter and scope metrics read its record.  A ``--trace 0``
+        # run, which gives the end-to-end metrics, leaves it off.
+        cfg = dataclasses.replace(cfg, telemetry=dataclasses.replace(
+            cfg.telemetry, export_trace=True))
     return cfg, train_cfg
 
 
 def make_inputs(cell: dict, seed: int):
-    from lib import data as data_lib
-    from active_learning_tpu.data.core import (
-        ArrayDataset, IMAGENET_NORM, ViewSpec)
-    cfgf, scale = cell["config"], cell["scale"]
-    images, labels, t_images, t_labels = data_lib.make_data(
+    """(host arrays, the program's datasets over them, weights), all from the
+    seed through the configuration's family."""
+    family, cfgf, scale = cell["family"], cell["config"], cell["scale"]
+    rows, labels, t_rows, t_labels = family.make_data(
         seed, cfgf, int(scale["pool_rows"]), int(scale["test_rows"]))
-    nc = int(cfgf["num_classes"])
-    # The 224 px train view: flip on the device (the random-resized crop
-    # belongs to decode time, which a warm in-memory pool has behind it).
-    train_set = ArrayDataset(images, labels, nc,
-                             ViewSpec(IMAGENET_NORM, augment=True, pad=0))
-    val_view = ViewSpec(IMAGENET_NORM, augment=False)
-    al_set = train_set.with_view(val_view)
-    test_set = ArrayDataset(t_images, t_labels, nc, val_view)
-    weights = data_lib.make_weights(seed, cfgf)
-    return (train_set, test_set, al_set), weights
+    data = family.datasets(cfgf, (rows, labels), (t_rows, t_labels))
+    weights = family.make_weights(seed, cfgf)
+    return (rows, labels, t_rows, t_labels), data, weights
 
 
 # -- after the window --------------------------------------------------------
@@ -321,9 +351,7 @@ def make_inputs(cell: dict, seed: int):
 def program_outputs(rec: Record, weights, cell: dict, seed: int, rd: int):
     """What the timed rounds ``rd - 1`` (fit, test) and ``rd`` (query)
     produced, as host arrays, and the decisions the reference follows."""
-    import jax
     import numpy as np
-    from lib import data as data_lib
     fit = rec.fits[rd - 1]
     if not fit["epochs"]:
         raise RuntimeError("the fit's epoch program was not seen: the "
@@ -338,17 +366,7 @@ def program_outputs(rec: Record, weights, cell: dict, seed: int, rd: int):
     first = fit["epochs"][0]
     losses = [float(v) for v in np.asarray(first["losses"])[:3]]
     gnorms = [float(v) for v in np.asarray(first["gnorms"])[:3]]
-    flat = {"/".join(str(getattr(k, "key", k)) for k in path): np.asarray(v)
-            for path, v in jax.tree_util.tree_flatten_with_path(
-                rec.params[rd - 1])[0]}
-    params = {}
-    for key in data_lib.trainable_keys(weights):
-        leaf = flat["/".join(data_lib.flax_path(key))]
-        if leaf.ndim == 4:
-            leaf = leaf.transpose(3, 2, 0, 1)      # HWIO -> OIHW
-        elif leaf.ndim == 2:
-            leaf = leaf.T
-        params[key] = leaf
+    params = cell["family"].program_params(rec.params[rd - 1], weights)
     test = [e for e in rec.evals if e["round"] == rd - 1 and e["test"]][-1]
     q = rec.queries[rd]
     sc = rec.scores[max(r for r in rec.scores if r <= rd)]
@@ -376,7 +394,7 @@ def program_outputs(rec: Record, weights, cell: dict, seed: int, rd: int):
 
 def required_work(rec: Record, cell: dict, rounds) -> dict:
     """Required FLOPs and bytes of the given rounds, by kind of work."""
-    from lib import flops as flops_lib
+    work = cell["family"].work
     cfgf = cell["config"]
     frozen = bool(cell["workload"]["freeze_feature"])
     batch = int(cfgf["train_batch"])
@@ -391,24 +409,23 @@ def required_work(rec: Record, cell: dict, rounds) -> dict:
         fit = rec.fits.get(rd)
         if fit:
             steps = -(-fit["labeled"] // batch) * fit["epochs_run"]
-            add("fit", flops_lib.work(
-                cfgf, "fit", fit["labeled"] * fit["epochs_run"], steps,
-                head_only=frozen))
+            add("fit", work(cfgf, "fit", fit["labeled"] * fit["epochs_run"],
+                            steps, head_only=frozen))
         sc = rec.scores.get(rd)
         if sc:
             n = len(sc["idxs"])
-            add("score", flops_lib.work(cfgf, "forward", n,
-                                        -(-n // EVAL_ROWS)))
+            add("score", work(cfgf, "forward", n, -(-n // sc["batch"])))
         for ev in rec.evals:
             if ev["round"] == rd:
-                add("test" if ev["test"] else "validate", flops_lib.work(
+                add("test" if ev["test"] else "validate", work(
                     cfgf, "forward", ev["rows"],
-                    -(-ev["rows"] // EVAL_ROWS)))
+                    -(-ev["rows"] // ev["batch"])))
     return total
 
 
 def read_trace(ctl: Controller, sink):
     """The traced round's reduction, on the host's clock."""
+    from lib import spans as spans_lib
     from lib import trace as trace_lib
     from lib import window as window_lib
     planes = trace_lib.load_xplane(trace_lib.find_xplane(ctl.trace_dir))
@@ -427,7 +444,11 @@ def read_trace(ctl: Controller, sink):
             spans.append((name, to_ns(t - float(value)), to_ns(t)))
     red = trace_lib.reduce_trace(planes, t0, t1, spans)
     red["spans"] = spans
-    return red, planes
+    # The program's span record over it: idle by deepest span, scopes.
+    extras = spans_lib.read_spans(ctl, red, planes, SCOPES)
+    if not extras:
+        log("no span record: the program's recorder was off")
+    return red, planes, extras
 
 
 def free_program(rec: Record) -> None:
@@ -466,6 +487,8 @@ def main(argv=None) -> int:
     ap.add_argument("--dump-trace", default=None,
                     help="write a trimmed record of the trace here")
     args = ap.parse_args(argv)
+    if not pin_allocator():
+        log("the allocator's thresholds were not set")
     cell = load_cell(args)
     # One fixed work directory per cell inside the checkout (checkpoints,
     # logs, the trace), emptied before and after the run; walks off the
@@ -504,19 +527,19 @@ def run(args, cell: dict, work_dir: str) -> int:
     from active_learning_tpu.experiment import driver
     from active_learning_tpu.faults import preempt as preempt_lib
     from lib import reference as ref_lib
-    from lib import data as data_lib
     from lib import readers as readers_lib
     from lib import window as window_lib
 
+    family = cell["family"]
     trace_dir = os.path.join(work_dir, "trace") if args.trace else None
 
-    data, weights = make_inputs(cell, args.seed)
-    ckpt_file = os.path.join(work_dir, "seed_weights.pth")
-    data_lib.save_torch_checkpoint(weights, ckpt_file)
+    arrays, data, weights = make_inputs(cell, args.seed)
+    ckpt_file = family.save_checkpoint(weights, work_dir)
     cfg, train_cfg = build_configs(cell, args.seed, work_dir, ckpt_file,
-                                   rehearse=args.rehearse)
-    log(f"inputs made: pool {data[0].images.shape}, device {kind} x "
-        f"{len(devices)}")
+                                   rehearse=args.rehearse,
+                                   trace=bool(args.trace))
+    log(f"inputs made: pool {arrays[0].dtype}{list(arrays[0].shape)}, "
+        f"device {kind} x {len(devices)}")
 
     rec = Record()
     anchor_fn = jax.jit(_anchor)
@@ -560,12 +583,13 @@ def run(args, cell: dict, work_dir: str) -> int:
     # pinned pool.  A run that fell back to a host feed is a failed run.
     expect = cell["workload"].get("expect_feed",
                                   {"source": "resident", "form": "scan"})
-    from active_learning_tpu.parallel import resident as resident_lib
-    pinned = resident_lib.rows_per_device(rec.strategy.trainer.resident_pool)
-    n_pool = int(cell["scale"]["pool_rows"])
-    if not any(n_pool in per.values() for per in pinned):
+    pinned = pinned_arrays(rec.strategy.trainer.resident_pool)
+    pool = pool_is_pinned(pinned, int(cell["scale"]["pool_rows"]))
+    if pool is None:
         log(f"the pool is not pinned on the device: {pinned}")
         return 1
+    log(f"the pool is pinned: {pool['rows']} rows, {pool['layout']} over "
+        f"{len(pool['shards'])} device(s)")
     for r in rounds:
         feed = rec.fits[r["round"]]["feed"]
         if any(feed.get(k) != v for k, v in expect.items()):
@@ -582,24 +606,36 @@ def run(args, cell: dict, work_dir: str) -> int:
                    - ctl.counters_open["misses"]),
                "persistent_cache_hits": float(
                    ctl.counters_close["hits"] - ctl.counters_open["hits"])},
-           "trace": None, "work": {}}
-    breakdown = None
+           "trace": None, "spans": None, "work": {}}
+    breakdown, span_extras = None, {}
     device = {"platform": platform, "kind": kind, "count": len(devices),
               "memory_peak_bytes": peak}
     if args.trace and not args.rehearse:
-        red, planes = read_trace(ctl, sink)
+        red, planes, span_extras = read_trace(ctl, sink)
         ctx["trace"] = red
+        ctx["spans"] = red.get("spans_record")
         ctx["work"] = required_work(rec, cell, [rounds[0]["round"]])
         device["busy_s"] = red["busy_s"]
         device["window_s"] = red["window_s"]
         breakdown = {"device_ops": [[n[:64], s] for n, s in red["top_ops"]],
                      "idle_gaps": [[n, s] for n, s in red["idle_gaps"]]}
+        if "host_self" in span_extras:
+            breakdown["host_self"] = [
+                [n, s] for n, s in span_extras.pop("host_self")]
+            # The two clocks and the tree's checks: for the reader of the
+            # log, no metric reads them.
+            log("[spans] " + json.dumps(span_extras))
         if args.dump_trace:
             _dump_trace(args.dump_trace, planes)
+    elif args.trace:
+        # Off the chip there is no device trace; the span record is read all
+        # the same, so the walk drives the span and counter readers.
+        from lib import spans as spans_lib
+        ctx["spans"] = spans_lib.load_span_record(trace_dir)
     metrics = {}
     if args.trace:
         for m in cell["metrics"]:
-            value = readers_lib.READERS[m["reader"]](ctx, **m["params"])
+            value = readers_lib.reader_for(m)(ctx, **m["params"])
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     else:
@@ -609,14 +645,13 @@ def run(args, cell: dict, work_dir: str) -> int:
     # The comparison, once the window has closed and the peak is read.
     out, record, select = program_outputs(rec, weights, cell, args.seed,
                                           last)
-    images, labels = data[0].images, data[0].targets
-    t_images, t_labels = data[1].images, data[1].targets
+    images, labels, t_images, t_labels = arrays
     free_program(rec)
     t_ref0 = time.perf_counter()
     frozen = bool(cell["workload"]["freeze_feature"])
     hyper = cell["workload"]["train"]
     micro = int(cell["workload"]["check"].get("micro_rows", 32))
-    common = (weights, images, labels, t_images, t_labels, record,
+    common = (family, weights, images, labels, t_images, t_labels, record,
               cell["config"], hyper, frozen)
     ref = ref_lib.reference_outputs(*common, micro=micro)
     numbers = ref_lib.compare(out, ref, weights, len(t_labels))
@@ -662,6 +697,45 @@ def run(args, cell: dict, work_dir: str) -> int:
         result["rehearsal"] = True
     print(json.dumps(result))
     return REHEARSAL_EXIT if args.rehearse else 0
+
+
+def pinned_arrays(cache) -> list:
+    """Every array the program pinned (``trainer.resident_pool``), read off
+    the array itself: its rows and, per device, the rows ``[start, stop)``
+    of the shard that device holds."""
+    out = []
+    for entry in ((cache or {}).get("images") or {}).values():
+        array = entry[1]
+        rows = int(array.shape[0])
+        out.append({"rows": rows, "shards": {
+            str(sh.device.id): [sh.index[0].start or 0,
+                                rows if sh.index[0].stop is None
+                                else sh.index[0].stop]
+            for sh in array.addressable_shards}})
+    return out
+
+
+def pool_is_pinned(pinned: list, n_pool: int):
+    """The pinned array (``pinned_arrays``) that is the whole pool, with its
+    ``layout``, or None.  The pool has ``n_pool`` rows itself (row sharding
+    may pad it to divide evenly: fewer pad rows than shards), and its
+    devices' distinct shards tile them: one shard on every device
+    (replicated) or one part each (row-sharded).  Another array whose
+    replicas happen to add up to ``n_pool`` is not the pool."""
+    for array in pinned:
+        parts = sorted({tuple(span) for span in array["shards"].values()})
+        if not parts or not n_pool <= array["rows"] < n_pool + len(parts):
+            continue
+        edge = 0
+        for start, stop in parts:
+            if start != edge:
+                break
+            edge = stop
+        else:
+            if edge == array["rows"]:
+                return {**array, "layout": ("replicated" if len(parts) == 1
+                                            else "row-sharded")}
+    return None
 
 
 def _anchor(x):

@@ -1,15 +1,17 @@
-"""The data generator, the window arithmetic and a toy walk of the runner."""
+"""The data generator, the window arithmetic and two toy walks of the runner:
+one chip, and four virtual devices with the pool sharded by rows."""
 
 import numpy as np
 import pytest
 
-from bench_testlib import finish_walk, load, start_walk
+from bench_testlib import (
+    assert_reads_the_parents_numbers, family, finish_walk, load, start_walk)
 
 TOY = load("tests/benchmark/toy/config.json")
 
 
 def test_data_is_a_function_of_the_seed_alone():
-    from lib import data
+    data = family()
     a = data.make_data(2 ** 31 + 7, TOY, 96, 16)
     b = data.make_data(2 ** 31 + 7, TOY, 96, 16)
     c = data.make_data(2 ** 31 + 8, TOY, 96, 16)
@@ -29,7 +31,7 @@ def test_data_is_a_function_of_the_seed_alone():
 
 
 def test_generator_makes_no_float_array_of_the_pool(monkeypatch):
-    from lib import data
+    data = family()
     seen = []
     real = data._fill_chunk
 
@@ -45,7 +47,7 @@ def test_generator_makes_no_float_array_of_the_pool(monkeypatch):
 
 
 def test_weights_are_a_function_of_the_seed_and_cover_the_model():
-    from lib import data, flops
+    data = flops = family()
     r50 = load("benchmarks/configs/sslresnet50_in224.json")
     shapes = {k: v.shape for k, v in data.make_weights(1, TOY).items()}
     again = data.make_weights(1, TOY)
@@ -132,6 +134,29 @@ def test_toy_walk_reaches_its_last_line_and_ends_as_a_rehearsal(walk):
     assert last["metrics"]["round_s"]["value"] > 0
     assert last["device"]["platform"] == "cpu"
     assert "check loss3:" in err and "check pick_regret:" in err
+    assert_reads_the_parents_numbers(last, "toy.margin_ft", 2 ** 31 + 11)
+
+
+@pytest.mark.slow
+def test_four_device_walk_passes_the_pinned_rows_check():
+    """``chips: 4`` and ``pool_sharding: row`` on four virtual devices: each
+    holds a quarter of the pool, the health check adds them up, and the walk
+    goes on to the trace-free result line.  Marked slow: a second process
+    that runs CPU collectives beside the suite's workers starves their
+    rendezvous, and a worker aborts (three whole runs of three, PR 27)."""
+    rc, last, err = finish_walk(start_walk("toy.margin_ft_x4", 7,
+                                           ["--trace", "0"], devices=4))
+    assert rc == 3 and last is not None, err[-2000:]
+    assert "the pool is not pinned" not in err
+    assert "per chip, row layout" in err
+    # The pool's own entry: 256 rows in four parts (the test set's 48 rows,
+    # replicated on four devices, add up to no pool).
+    assert ("the pool is pinned: 256 rows, row-sharded over 4 device(s)"
+            in err)
+    assert last["correct"] is True, last["check"]
+    assert last["device"]["count"] == 4
+    assert set(last["metrics"]) == {"round_s", "setup_s"}
+    assert list(last)[-2:] == ["check", "rehearsal"]
 
 
 def test_the_control_and_the_planted_faults_fail_the_toy_cell(walk):

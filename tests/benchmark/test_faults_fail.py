@@ -3,7 +3,8 @@ has to come out false, once for each fault a cell can have."""
 
 import pytest
 
-from bench_testlib import finish_walk, start_walk
+from bench_testlib import (
+    assert_reads_the_parents_numbers, finish_walk, start_walk)
 
 FAULTS = {"state_unchanged": ("toy.margin_ft", 21),
           "half_batch": ("toy.margin_ft", 22),
@@ -24,6 +25,8 @@ def test_fault_makes_the_run_incorrect(walks, fault):
     assert last["correct"] is False, last["check"]
     over = [k for k, (v, lim) in last["check"].items() if not v <= lim]
     assert over
+    # ... and it breaks what the parent commit's walk broke, digit for digit.
+    assert_reads_the_parents_numbers(last, *FAULTS[fault], fault)
 
 
 def test_sound_frozen_walk_is_correct_with_a_traced_line():
@@ -32,7 +35,13 @@ def test_sound_frozen_walk_is_correct_with_a_traced_line():
     assert rc == 3 and last["correct"] is True, err[-2000:]
     assert {"query_s", "fit_s", "test_s", "round_other_s",
             "window_compiles"} <= set(last["metrics"])
+    # ``--trace 1`` switches the program's span recorder on, and the span
+    # and counter readers read its record off the chip too.
+    assert {"ckpt_s", "fit_step_useful"} <= set(last["metrics"])
+    assert 0 < last["metrics"]["fit_step_useful"]["value"] <= 100
     # off the chip no device metric is ever printed
-    assert not {"round_mfu", "fit_roofline", "device_idle"} & set(
+    assert not {"round_mfu", "fit_roofline", "device_idle", "gather_s",
+                "idle_ckpt_s", "idle_reinit_s", "score_roofline"} & set(
         last["metrics"])
     assert "busy_s" not in last["device"]
+    assert_reads_the_parents_numbers(last, "toy.coreset_lin", 24)

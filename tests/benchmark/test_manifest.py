@@ -49,6 +49,23 @@ def test_config_entry_and_file(config):
     assert any(w["config"] == config for w in M["workloads"])
 
 
+CONFIG_FILES = sorted({c["file"] for c in M["configs"]} | {
+    "tests/benchmark/toy/config.json", "tests/benchmark/toy/config_rows.json"})
+
+
+@pytest.mark.parametrize("path", CONFIG_FILES)
+def test_config_names_a_family_that_keeps_the_contract(path):
+    """Every configuration file, the tests' own too, names its family by a
+    path under ``paths``; the file is there and exports what ``run.py``
+    calls."""
+    import families
+    rel = load(path)["family"]
+    assert any(rel.startswith(p + "/") for p in M["paths"])
+    assert os.path.isfile(os.path.join(ROOT, rel))
+    module = families.load(rel, ROOT)
+    assert all(callable(getattr(module, n)) for n in families.CONTRACT)
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_entry_and_files(cell):
     entry = next(w for w in M["workloads"] if w["name"] == cell)
@@ -80,13 +97,31 @@ def test_per_layer_metric(metric):
                                "program_counter", "host_clock")
     assert entry["moves"] in E2E and entry["moves"] != "setup_s"
     body = load(f"{M['paths'][0]}/metrics/{metric}.json")
-    assert body["reader"] in readers.READERS
+    # The reader is registered by the file of ``lib/`` the data file names.
+    module = body.get("module", "readers")
+    assert os.path.isfile(os.path.join(
+        ROOT, M["paths"][0], "lib", f"{module}.py"))
+    fn = readers.reader_for(body)
+    assert fn is readers.READERS[body["reader"]]
+    assert fn.__module__ == f"lib.{module}"
     assert body["layer"] == entry["layer"] and "\n" not in entry["layer"]
     assert body.get("workloads") == entry.get("workloads")
     for cell in entry.get("workloads", CELLS):
         assert cell in CELLS
     if metric.endswith("_roofline") or "mfu" in metric:
         assert entry["unit"] == "%" and entry["source"] == "device_trace"
+
+
+def test_a_reader_that_no_file_registers_is_refused():
+    from lib import readers
+    with pytest.raises(KeyError):
+        readers.reader_for({"name": "x", "reader": "no_such_reader"})
+    with pytest.raises(ValueError):
+        readers.reader_for({"name": "x", "module": "../run",
+                            "reader": "counter"})
+    with pytest.raises(ImportError):
+        readers.reader_for({"name": "x", "module": "no_such_file",
+                            "reader": "counter"})
 
 
 @pytest.mark.parametrize("metric", sorted(E2E))

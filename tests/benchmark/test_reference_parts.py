@@ -3,7 +3,7 @@
 import numpy as np
 import pytest
 
-import bench_testlib  # noqa: F401  (puts the benchmark on sys.path)
+from bench_testlib import family
 
 
 def test_margin_pick_regret_is_zero_only_for_the_smallest_scores():
@@ -90,24 +90,41 @@ def test_fp8_control_rounds_values_and_passes_gradients_straight_through():
     import jax.numpy as jnp
     from lib import reference as ref
     x = jnp.linspace(-1.0, 1.0, 101)
-    q = ref._q(x, "fp8")
+    q = ref.q(x, "fp8")
     assert float(jnp.max(jnp.abs(q - x))) > 1e-3       # coarser than bf16
     assert float(jnp.max(jnp.abs(q - x))) < 0.07
-    g = jax.grad(lambda v: jnp.sum(ref._q(v, "fp8") ** 2))(x)
+    g = jax.grad(lambda v: jnp.sum(ref.q(v, "fp8") ** 2))(x)
     assert np.allclose(np.asarray(g), 2 * np.asarray(q), atol=1e-6)
     with pytest.raises(KeyError):
-        ref._q(x, "int3")
+        ref.q(x, "int3")
 
 
 def test_step_flips_follow_the_key_chain():
+    """The chain is ``lib/reference``'s, what a step draws from its key the
+    family's: together they flip what the one function used to."""
     import jax
+    import jax.numpy as jnp
     from lib import reference as ref
     key = np.asarray(jax.random.PRNGKey(5))
-    a = ref.step_flips(key, 3, 16)
-    b = ref.step_flips(key, 3, 16)
-    assert a.shape == (3, 16) and np.array_equal(a, b)
-    k1, sub = jax.random.split(jax.numpy.asarray(key))
+    a = ref.step_keys(key, 3)
+    assert a.shape == (3, 2) and np.array_equal(a, ref.step_keys(key, 3))
+    k1, sub = jax.random.split(jnp.asarray(key))
+    assert np.array_equal(a[0], np.asarray(sub))
+    assert np.array_equal(a[1], np.asarray(jax.random.split(k1)[1]))
+    rows = np.arange(16 * 2 * 4 * 3, dtype=np.uint8).reshape(16, 2, 4, 3)
+    view = jax.jit(family().train_view)
+
+    def flipped(step_key):
+        got = np.asarray(view(jnp.asarray(rows), jnp.asarray(step_key),
+                              jnp.asarray(True)))
+        bits = np.array([not np.array_equal(g, r)
+                         for g, r in zip(got, rows)])
+        assert all(np.array_equal(g, r[:, ::-1] if b else r)
+                   for g, r, b in zip(got, rows, bits))
+        return bits
     _, kf = jax.random.split(sub)
-    assert np.array_equal(a[0], np.asarray(
+    assert np.array_equal(flipped(a[0]), np.asarray(
         jax.random.bernoulli(kf, 0.5, (16,))))
-    assert not np.array_equal(a[0], a[1])
+    assert not np.array_equal(flipped(a[0]), flipped(a[1]))
+    still = view(jnp.asarray(rows), jnp.asarray(a[0]), jnp.asarray(False))
+    assert np.array_equal(np.asarray(still), rows)

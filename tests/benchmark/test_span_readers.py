@@ -1,27 +1,28 @@
 """The span record read from outside the program: self time, the deepest-span
-attribution of idle time, scopes, the readers, and the metric files that
-wait for a ``BENCHMARK.json`` entry."""
+attribution of idle time, scopes, the readers (which ``run.py`` finds through
+their data files' ``module``), and the glue that runs on the chip."""
 
-import glob
 import json
 import os
 import types
 
 import pytest
 
-from bench_testlib import BENCH, ROOT, manifest
+from bench_testlib import BENCH, ROOT, load, manifest
 
 from lib import readers
 from lib import spans as spans_lib
 from lib import trace as trace_lib
 
-import span_run
+import run as bench
 
 LISTED = {m["name"] for m in manifest()["per_layer"]}
-WAITING = sorted(
-    os.path.basename(p)[:-5]
-    for p in glob.glob(os.path.join(BENCH, "metrics", "*.json"))
-    if os.path.basename(p)[:-5] not in LISTED)
+# The metrics whose reader is registered outside ``lib/readers.py``: until
+# PR 27 their files waited beside ``BENCHMARK.json`` for ``run.py`` to look
+# a reader up by its module.
+SPAN_METRICS = sorted(
+    name for name in LISTED
+    if load(f"benchmarks/metrics/{name}.json").get("module") == "spans")
 
 
 def span(name, i, parent, t0, t1, tid=1, rd=2, **args):
@@ -120,11 +121,11 @@ HLO = {"jit_run_score_x": [{"copy.7": "images",
 def test_scope_of_an_operation():
     assert trace_lib.op_name(V5E_EVENT) == "copy.7"     # the old name holds
     gather = "jit(run_score_prob_stats)/pool_gather/gather"
-    assert spans_lib.scope_of(gather, span_run.SCOPES) == "pool_gather"
+    assert spans_lib.scope_of(gather, bench.SCOPES) == "pool_gather"
     inner = "jit(epoch_scan)/while/body/forward_backward/view/mul"
-    assert spans_lib.scope_of(inner, span_run.SCOPES) == "view"
-    assert spans_lib.scope_of("jit(f)/mul", span_run.SCOPES) is None
-    assert spans_lib.scope_of("images", span_run.SCOPES) is None
+    assert spans_lib.scope_of(inner, bench.SCOPES) == "view"
+    assert spans_lib.scope_of("jit(f)/mul", bench.SCOPES) is None
+    assert spans_lib.scope_of("images", bench.SCOPES) is None
 
 
 def test_operations_are_named_from_their_programs_module():
@@ -146,7 +147,7 @@ def test_operations_are_named_from_their_programs_module():
         (["pool_gather", "gather"], "jit_epoch_scan/fusion.1"),
         (["optimizer", "mul"], "jit_epoch_scan/fusion.7"),
         ("", "jit_unknown/fusion.1"), ("", "stray")]
-    got = spans_lib.scope_seconds_of(named, 150, 1100, span_run.SCOPES)
+    got = spans_lib.scope_seconds_of(named, 150, 1100, bench.SCOPES)
     # The while event covers its body's operations and has no scope.
     assert got == pytest.approx({"pool_gather": 50e-9 + 100e-9,
                                  "forward": 50e-9, "optimizer": 500e-9})
@@ -183,7 +184,7 @@ def test_a_real_trace_carries_its_programs_op_names(tmp_path):
     assert [h[0] for h in extras["host"]] == ["collect_pool"]
     tables = extras["hlo"]["jit_run_score_demo"]
     assert len(tables) == 1
-    found = {spans_lib.scope_of(path, span_run.SCOPES)
+    found = {spans_lib.scope_of(path, bench.SCOPES)
              for path in tables[0].values()}
     assert {"pool_gather", "forward"} <= found
     assert spans_lib.hlo_op_names(b"") == {}
@@ -243,10 +244,10 @@ def test_readers_are_silent_on_a_program_without_the_span_tree():
                              names=["x"]) is None
 
 
-@pytest.mark.parametrize("metric", WAITING)
+@pytest.mark.parametrize("metric", SPAN_METRICS)
 def test_waiting_metric_file(metric):
-    """A metric file without a ``BENCHMARK.json`` entry: its reader is one
-    that ``lib/spans.py`` registers, and ``span_run.py`` picks it up."""
+    """A metric whose reader ``lib/spans.py`` registers: ``run.py`` finds it
+    through the data file's ``module`` and reads it like any other."""
     with open(os.path.join(BENCH, "metrics", f"{metric}.json")) as fh:
         body = json.load(fh)
     assert body["name"] == metric and body["moves"] == "round_s"
@@ -256,14 +257,20 @@ def test_waiting_metric_file(metric):
     assert body["source"] in ("device_trace", "program_span",
                               "program_counter")
     assert body["better"] in ("lower", "higher") and body["unit"]
-    assert metric in {m["name"] for m in span_run.waiting_metrics()}
-    assert readers.READERS[body["reader"]](CTX, **body["params"]) is not None
+    cell = bench.load_cell(types.SimpleNamespace(
+        workload="r18_in224.margin_ft", workload_file=None))
+    mine = next(m for m in cell["metrics"] if m["name"] == metric)
+    assert mine["module"] == "spans" and mine["unit"] == body["unit"]
+    assert readers.reader_for(mine)(CTX, **mine["params"]) is not None
 
 
 def test_six_metrics_wait_and_two_are_listed():
-    assert WAITING == ["ckpt_s", "fit_step_useful", "gather_s",
-                       "idle_ckpt_s", "idle_reinit_s", "score_pass_s"]
+    """They waited; now all six are entries, and the duplicate is gone."""
+    assert SPAN_METRICS == ["ckpt_s", "fit_step_useful", "gather_s",
+                            "idle_ckpt_s", "idle_reinit_s", "score_pass_s"]
     assert {"reinit_s", "score_step_roofline"} <= LISTED
+    assert "score_roofline" not in LISTED
+    assert not os.path.exists(os.path.join(BENCH, "span_run.py"))
 
 
 def test_read_spans_lays_the_record_over_the_reduction(tmp_path,
@@ -311,9 +318,9 @@ def test_read_spans_lays_the_record_over_the_reduction(tmp_path,
                                 trace_anchor=100.0,
                                 trace_span=[100.0, 110.0],
                                 pauses=[(109.92, 109.97)])
-    red, extras = {"idle_gaps": [("rd_train_time", 1.3)],
-                   "top_ops": [("jit_bench_anchor/copy.7", 4.1)]}, {}
-    span_run.read_spans(ctl, red, planes, extras)
+    red = {"idle_gaps": [("rd_train_time", 1.3)],
+           "top_ops": [("jit_bench_anchor/copy.7", 4.1)]}
+    extras = spans_lib.read_spans(ctl, red, planes, bench.SCOPES)
     idle = red["idle_by_span"]
     assert idle["reinit/model_init"] == pytest.approx(0.7, abs=1e-3)
     assert idle["ckpt/publish_best"] == pytest.approx(0.6, abs=1e-3)
@@ -321,20 +328,20 @@ def test_read_spans_lays_the_record_over_the_reduction(tmp_path,
     assert red["idle_gaps"][0][0] == "reinit/model_init"
     assert red["scope_s"] == pytest.approx({"pool_gather": 4.1,
                                             "forward_backward": 1.4})
-    assert extras["device_ops_op_name"] == [
-        ["jit_bench_anchor/copy.7", "jit(run_score_x)/pool_gather/gather"]]
+    assert extras["ops_named"] == [2, 3, 1]
     assert extras["traced_round"] == 2
     assert extras["clock"]["largest_s"] == pytest.approx(3e-4, rel=1e-3)
     assert extras["idle_deeper_than_phase_share"] == pytest.approx(1.0)
     assert extras["idle_unattributed_share"] == 0.0
     assert extras["subtree_self_sum_s"] == pytest.approx(
         extras["round_span_s"], rel=1e-6)
-    assert extras["round_epilogue_s"] == pytest.approx(0.1 - 0.05)
-    assert extras["round_and_epilogue_s"][2] == pytest.approx([9.8, 0.05])
+    # The runner's pause at the boundary is not the epilogue's time.
+    assert dict(extras["host_self"])["round_epilogue"] == pytest.approx(
+        0.1 - 0.05)
     assert extras["host_self"][0][0] == "train_time"
     # A program without the recorder: nothing is laid over.
     os.remove(work / "logs" / "exp" / "trace.json")
     red2 = {"idle_gaps": [("rd_train_time", 1.3)]}
-    span_run.read_spans(ctl, red2, planes, {})
+    assert spans_lib.read_spans(ctl, red2, planes, bench.SCOPES) == {}
     assert red2 == {"idle_gaps": [("rd_train_time", 1.3)]}
     assert os.path.isdir(os.path.join(ROOT, "benchmarks"))
