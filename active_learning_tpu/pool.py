@@ -29,16 +29,19 @@ def bucket_size(n: int, floor: int = 256) -> int:
     XLA compile per round (padding is masked out of every computation by
     the callers).
 
-    Why not plain next-power-of-two: the padding is masked out of the
-    RESULTS but not the COMPUTE — a padded epoch-scan step still runs a
-    full train step, a padded pool row still rides every distance matmul
-    — so just past a pow2 boundary pure pow2 buckets would re-spend up
-    to ~2x compute on EVERY epoch/pick to save one recompile per round.
-    The 1/8-octave granularity caps that recurring waste at 25%
-    worst-case (just past a power of two; typically well under 10%)
-    while keeping the distinct-shape count small (8 buckets per
-    doubling) so consecutive rounds still reuse executables.  ``floor``
-    pins tiny inputs to one fixed bucket.
+    Why not plain next-power-of-two: a padded pool row is masked out of
+    the RESULTS but not the COMPUTE — it still rides every distance
+    matmul — so just past a pow2 boundary pure pow2 buckets would
+    re-spend up to ~2x compute on EVERY pick to save one recompile per
+    round.  (The trainer's padding costs no compute: the epoch program
+    takes its trip count from ``valid`` and runs the real steps only, so
+    there the bucket bounds the index matrices and the resident row
+    upload — bytes, every epoch and every round.)  The 1/8-octave
+    granularity caps that recurring waste at 25% worst-case (just past
+    a power of two; typically well under 10%) while keeping the
+    distinct-shape count small (8 buckets per doubling) so consecutive
+    rounds still reuse executables.  ``floor`` pins tiny inputs to one
+    fixed bucket.
     """
     n = max(int(n), int(floor))
     gran = max(int(floor), (1 << (n - 1).bit_length()) // 8)

@@ -592,11 +592,16 @@ class Trainer:
         when gather/decode is the bottleneck (disk datasets), pure dispatch
         overhead when the whole labeled set already sits in HBM (CIFAR
         scale: 50k x 32x32x3 uint8 = 150 MB).  Here the epoch is a single
-        ``lax.scan`` over a [steps, batch] index matrix: per step an
-        on-device gather + sharding constraint reproduces exactly what
+        loop over a [steps, batch] index matrix: per step an on-device
+        gather + sharding constraint reproduces exactly what
         ``shard_batch`` commits on the host path, and the PRNG-key chain
         (split once per batch) matches it bit for bit, so both paths give
         identical parameters.
+
+        ``steps`` is the bucketed SHAPE (bucket_steps); the loop's trip
+        count is a value the program reads off ``valid`` (a prefix of
+        ones, _epoch_index_matrix), so only the real steps execute and
+        ``losses`` / ``gnorms`` stay zero past them.
         """
         train_step = self._train_step
         mesh = self.mesh
@@ -606,31 +611,27 @@ class Trainer:
                            donate_argnums=(0,))
         def epoch_scan(state, images, labels, idx_mat, mask_mat, valid,
                        key, lr, class_weights, view, sharded=False):
-            def body(carry, inp):
-                state, key = carry
-                idxs, mask, v = inp
+            def body(i, carry):
+                state, key, losses, gnorms = carry
                 new_key, sub = jax.random.split(key)
                 # Row-sharded pool: batch rows assembled from their
                 # owning shards into the SAME batch sharding the
                 # replicated layout's constraint commits — bit-identical
-                # batches, shard_map composes inside the scan body.
+                # batches, shard_map composes inside the loop body.
                 img, lab = resident_lib.pool_gather(
-                    images, idxs, mesh, row_shape, labels=labels,
+                    images, idx_mat[i], mesh, row_shape, labels=labels,
                     sharded=sharded)
-                batch = {"image": img, "label": lab, "mask": mask}
-                new_state, loss, gnorm = train_step(state, batch, sub, lr,
-                                                    class_weights, view=view)
-                # Bucket-padding steps (v == 0) are fully selected away —
-                # state, key chain, and loss — so the scan is numerically
-                # identical to running exactly the real steps.
-                state = jax.tree.map(
-                    lambda n, o: jnp.where(v > 0, n, o), new_state, state)
-                key = jnp.where(v > 0, new_key, key)
-                return (state, key), (loss * v, gnorm * v)
+                batch = {"image": img, "label": lab, "mask": mask_mat[i]}
+                state, loss, gnorm = train_step(state, batch, sub, lr,
+                                                class_weights, view=view)
+                return (state, new_key, losses.at[i].set(loss),
+                        gnorms.at[i].set(gnorm))
 
-            (state, key), (losses, gnorms) = jax.lax.scan(
-                body, (state, key), (idx_mat, mask_mat, valid))
-            return state, key, losses, gnorms
+            # ``valid`` is replicated: every device reads the same count.
+            steps_real = jnp.sum(valid > 0).astype(jnp.int32)
+            zeros = jnp.zeros(valid.shape, jnp.float32)
+            return jax.lax.fori_loop(0, steps_real, body,
+                                     (state, key, zeros, zeros))
 
         return epoch_scan
 
@@ -638,13 +639,11 @@ class Trainer:
     # once per BUCKET, not once per AL round as the labeled set grows:
     # up to STEP_BUCKET steps everything lands on the one floor bucket,
     # beyond it steps round up to a bounded-waste geometric bucket
-    # (pool.bucket_size, 1/8-octave granularity).  Padded steps are
-    # masked out of the RESULTS (``valid``) but still execute the train
-    # step, so the bucket rule bounds that recurring per-epoch waste
-    # (25% worst-case, typically a few %) — pure power-of-two buckets
-    # would re-spend up to ~2x compute every epoch just past a boundary
-    # to save one recompile per round.  Bucket size never changes
-    # numerics.
+    # (pool.bucket_size, 1/8-octave granularity).  The bucket is the
+    # SHAPE of the index matrix and of the uploaded rows only: the
+    # program runs ``sum(valid)`` steps, so padding costs upload bytes
+    # (25% worst-case, typically a few %), never a train step.  Bucket
+    # size never changes numerics.
     STEP_BUCKET = 16
 
     @classmethod
@@ -1306,7 +1305,7 @@ class Trainer:
             # asynchronous call: it ends at the enqueue, and the device
             # time is waited for in fit/validate below or, without
             # validation, in the next fetch).  Recorded whenever the
-            # recorder is on; steps_run is the bucketed count.
+            # recorder is on.
             with tracer.span("epoch", args={
                     "round": round_idx, "epoch": epoch,
                     "rows": n_real}) as epoch_sp:
@@ -1435,11 +1434,10 @@ class Trainer:
                     steps_run = len(losses)
                 record = {"epoch": epoch, "lr": float(lr),
                           "train_loss": epoch_loss, "grad_norm": epoch_gnorm}
-                # steps_run: what the device executes — the scan's
-                # bucket-padded step count, the real count elsewhere.
-                epoch_sp.args.update(
-                    steps_real=steps_run,
-                    steps_run=len(valid) if use_scan else steps_run)
+                # steps_run: what the device executes — on every path
+                # the real steps (the scan's bucket is only its shape).
+                epoch_sp.args.update(steps_real=steps_run,
+                                     steps_run=steps_run)
 
             if use_es:
                 # Ends at the fetch of the counts — on the scan path this

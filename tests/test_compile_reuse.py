@@ -31,7 +31,8 @@ class TestBucketSize:
         assert bucket_size(512) == 512
         # 1/8-octave granularity, NOT pure pow2: past a boundary the
         # bucket grows by the granule (256 here), not by doubling —
-        # padded rows/steps still execute, so waste must stay bounded.
+        # padded pool rows still execute (and padded epoch steps are
+        # still uploaded as index rows), so waste must stay bounded.
         assert bucket_size(513) == 768
         assert bucket_size(130000) == 131072
 
@@ -122,10 +123,13 @@ class TestShardedKCenterCompileReuse:
 
 
 class TestEpochScanCompileReuse:
-    def test_two_rounds_grown_labeled_zero_new_compiles(self):
+    @pytest.mark.parametrize("first,grown", [(24, 60), (8, 90), (90, 24)])
+    def test_two_rounds_grown_labeled_zero_new_compiles(self, first, grown):
         """The device-resident epoch scan across two AL 'rounds' whose
         labeled sets differ but land in the same step bucket compiles
-        exactly once."""
+        exactly once: the real step count is a value of the program
+        (it runs 2, then 4 steps of the 16-step shape; 1 then 6; 6 then
+        2), never a shape."""
         from helpers import TinyClassifier, tiny_train_config
         from active_learning_tpu.data.synthetic import get_data_synthetic
         from active_learning_tpu.parallel import mesh as mesh_lib
@@ -149,11 +153,14 @@ class TestEpochScanCompileReuse:
                                np.arange(90, 96), n_epoch=2, es_patience=0,
                                rng=rng, round_idx=0)
 
-        fit_round(24, 0)  # 2 steps of 16 -> the 16-step floor bucket
+        res = fit_round(first, 0)  # e.g. 2 steps -> the 16-step floor bucket
         assert trainer._epoch_scan is not None
+        assert trainer.last_feed["form"] == "scan"
+        assert int(res.state.step) == 2 * -(-first // 16)
         scans = _cache_size(trainer._epoch_scan)
         steps = _cache_size(trainer._train_step)
-        fit_round(60, 1)  # grown labeled set, 4 steps -> same bucket
+        res = fit_round(grown, 1)  # another real step count, same bucket
+        assert int(res.state.step) == 2 * -(-grown // 16)
         assert _cache_size(trainer._epoch_scan) == scans
         assert _cache_size(trainer._train_step) == steps
 
@@ -165,8 +172,8 @@ class TestEpochScanCompileReuse:
         assert Trainer.bucket_steps(17) == 32
         assert Trainer.bucket_steps(33) == 48
         assert Trainer.bucket_steps(64) == 64
-        # The case the pure-pow2 rule got wrong: 157 steps must not pay
-        # 99 masked-but-executed train steps per epoch (256), only 3.
+        # The case the pure-pow2 rule got wrong: 157 steps must not be
+        # shaped as 256 (99 padded index rows an epoch), only as 160.
         assert Trainer.bucket_steps(157) == 160
 
 

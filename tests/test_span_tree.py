@@ -314,6 +314,42 @@ class TestSpansEndAtTheResult:
         assert sum(c["args"]["batches"] for c in chunks) == 5
 
 
+class TestEpochCounters:
+    @pytest.mark.parametrize("n_labeled,steps_real", [(8, 1), (40, 5),
+                                                      (96, 12)])
+    def test_epoch_span_on_the_scan_path_runs_the_real_steps(self, n_labeled,
+                                                             steps_real):
+        """The epoch program takes its trip count from ``valid``: the
+        16-step bucket is its shape, and ``steps_run`` says what the
+        device executes."""
+        mesh = mesh_lib.make_mesh(1)
+        train_set, _, al_set = get_data_synthetic(n_train=104, n_test=8,
+                                                  num_classes=4,
+                                                  image_size=8, seed=2)
+        cfg = dataclasses.replace(tiny_train_config(batch_size=8),
+                                  device_resident=True)
+        trainer = Trainer(TinyClassifier(num_classes=4), cfg, mesh,
+                          num_classes=4)
+        state = trainer.init_state(jax.random.PRNGKey(0),
+                                   train_set.gather(np.arange(2)))
+        tracer = spans_lib.SpanTracer(enabled=True)
+        spans_lib.set_tracer(tracer)
+        try:
+            res = trainer.fit(state, train_set, np.arange(n_labeled), al_set,
+                              np.arange(96, 104), n_epoch=2, es_patience=0,
+                              rng=np.random.default_rng(0))
+        finally:
+            spans_lib.set_tracer(None)
+        assert trainer.last_feed["form"] == "scan"
+        assert Trainer.bucket_steps(steps_real) == 16
+        epochs = [e for e in _spans(tracer.events) if e["name"] == "epoch"]
+        assert len(epochs) == 2
+        for e in epochs:
+            assert (e["args"]["steps_real"], e["args"]["steps_run"],
+                    e["args"]["rows"]) == (steps_real, steps_real, n_labeled)
+        assert int(res.state.step) == 2 * steps_real
+
+
 # -- names on the device side -------------------------------------------------
 
 class TestDeviceNames:
