@@ -169,9 +169,9 @@ class DonationSafetyChecker(Checker):
         problems: List[Finding] = []
         # Pass 1: collect every module's _DONATES registry.  The union
         # is applied package-wide — the trainer's donating steps are
-        # called through attributes from bench.py and the strategies,
-        # and an attribute call site doesn't care which module declared
-        # the step.
+        # called through attributes from the strategies, and an
+        # attribute call site doesn't care which module declared the
+        # step.
         union: Dict[str, Tuple[int, ...]] = {}
         for path in ctx.files:
             tree, err = ctx.tree(path)

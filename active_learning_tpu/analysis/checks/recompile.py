@@ -28,8 +28,8 @@ Rules, per hot-path module (train/, strategies/, parallel/, serve/):
     or lambdas — each is a fresh unhashable/identity-hashed object per
     call: a guaranteed per-call recompile (or TypeError) on a hot path.
 
-Modules outside the hot paths (bench.py, scripts/) may jit freely —
-they are measurement tools, not round code.
+Modules outside the hot paths (scripts/) may jit freely — they are
+tools, not round code.
 
 Suppression: ``# al-lint: recompile-ok <reason>``.
 """
@@ -163,9 +163,9 @@ class RecompileHazardChecker(Checker):
     def _check_module(self, tree, rel, hot, problems):
         builders = self._builders(tree, rel, problems)
         # Scope: the package hot paths are mandatory; any other module
-        # (bench.py, scripts/) opts IN by declaring _STEP_BUILDERS —
-        # measurement tools may jit freely, but a module that declares
-        # the discipline gets it enforced.
+        # opts IN by declaring _STEP_BUILDERS.  No script does today:
+        # the opt-in is how this check's own fixtures, which live
+        # outside the package, get it enforced.
         if not hot and builders is None:
             return
 

@@ -41,9 +41,8 @@ _SELF = ("trace_lint.py", "al_lint.py")
 
 
 def default_files(repo: str = REPO) -> List[str]:
-    """The whole-package file set: every .py under active_learning_tpu/,
-    bench.py, and scripts/ (minus the lint entry points) — the same walk
-    the legacy monolith did, so ported checks see the same tree."""
+    """The whole-package file set: every .py under active_learning_tpu/
+    and scripts/ (minus the lint entry points)."""
     pkg = os.path.join(repo, "active_learning_tpu")
     out: List[str] = []
     for root, dirs, files in os.walk(pkg):
@@ -51,9 +50,6 @@ def default_files(repo: str = REPO) -> List[str]:
         for name in sorted(files):
             if name.endswith(".py"):
                 out.append(os.path.join(root, name))
-    bench = os.path.join(repo, "bench.py")
-    if os.path.exists(bench):
-        out.append(bench)
     scripts = os.path.join(repo, "scripts")
     if os.path.isdir(scripts):
         for name in sorted(os.listdir(scripts)):

@@ -111,8 +111,8 @@ class TrainConfig:
     # compute dtype: bf16 models compute batch mean/var by reducing the
     # bf16 activations directly with float32 ACCUMULATION
     # (models/resnet.FusedBatchNorm) instead of flax's
-    # materialize-as-float32-then-reduce — the stats pass was measured at
-    # -23% of ResNet-50 forward throughput (mfu_decomposition.json).
+    # materialize-as-float32-then-reduce, which doubles the bytes the
+    # stats pass reads and breaks its fusion with the producer.
     # "float32" forces the flax path; running statistics are float32
     # either way.
     bn_stats_dtype: str = "auto"
@@ -192,8 +192,9 @@ class TrainConfig:
     # (al scoring + test set, data/cache.DecodedPoolCache): each row is
     # JPEG-decoded exactly once per experiment lifetime instead of once
     # per round/epoch, so steady-state ImageNet scoring is bounded by
-    # host->device bandwidth, not decode (bench r3: 1,048 img/s/core
-    # decode vs 3,133 img/s h2d vs 9,742 img/s device).  Applied only
+    # host->device bandwidth, not decode (a capture from before the
+    # ledger: 1,048 img/s/core decode vs 3,133 img/s h2d vs 9,742 img/s
+    # device).  Applied only
     # when the FULL pool fits the byte budget (sparse file; a partial
     # cache would still thrash).  dir=None -> <tempdir>/al_tpu_decoded.
     cache_decoded_bytes: int = 32 << 30
@@ -274,7 +275,7 @@ class ServeConfig:
 
     host: str = "127.0.0.1"
     # 0 = ephemeral (the bound port is logged and exposed on the server
-    # object) — tests and the bench phase run over loopback this way.
+    # object) — tests run over loopback this way.
     port: int = 8000
     # Rows per dispatched device batch, upper bound.  Served shapes are
     # the geometric bucket ladder serve_buckets(max_batch, bucket_floor)
@@ -306,7 +307,7 @@ class StreamConfig:
 
     host: str = "127.0.0.1"
     # 0 = ephemeral (the bound port is logged and exposed on the service
-    # object) — tests and the bench smoke phase run over loopback.
+    # object) — tests run over loopback this way.
     port: int = 8008
     # Rows one POST /v1/pool may carry; beyond it the request is a
     # non-retryable 413 (it could never be admitted — split it).

@@ -11,8 +11,9 @@ independent of ``set_epoch``):
   * ``DecodedPoolCache`` — disk memmap, per-EXPERIMENT: acquisition
     scoring re-reads the WHOLE unlabeled pool every round, and on
     ImageNet-scale trees the JPEG decode is ~30x slower than the
-    device's scoring rate (bench: 1,048 img/s/core decode vs 9,742
-    img/s/chip scoring, h2d ceiling 3,133 img/s).  Each row is decoded
+    device's scoring rate (a capture from before the ledger: 1,048
+    img/s/core decode vs 9,742 img/s/chip scoring, h2d ceiling 3,133
+    img/s).  Each row is decoded
     exactly once for the life of the cache file and every later round
     (and validation, and the test set) streams uint8 rows at disk/page-
     cache speed.  The reference re-decodes per epoch via DataLoader

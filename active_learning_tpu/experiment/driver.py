@@ -73,8 +73,7 @@ DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
 def resolve_compilation_cache_dir(cache_dir: Optional[str] = None
                                   ) -> Optional[str]:
     """WHERE the persistent compilation cache goes — the one rule, no
-    side effects (``enable_compilation_cache`` applies it; bench.py asks
-    it whether a run starts cache-warm).
+    side effects (``enable_compilation_cache`` applies it).
 
     The cache is PLACED FROM OUTSIDE: where ``$JAX_COMPILATION_CACHE_DIR``
     is set, that directory is used whatever ``cache_dir`` says (a
@@ -539,8 +538,7 @@ def _emit_overlap_telemetry(telemetry, sink: MetricsSink, rd: int,
                             round_s: float, phase_s: dict,
                             spec_s: float, pipeline_mode: str) -> None:
     """The pipelined round's proof-of-overlap metrics, from the driver's
-    OWN telemetry stream (bench reads these back rather than timing the
-    loop again):
+    OWN telemetry stream:
 
       rd_round_time       the round span's wall;
       overlap_frac        1 − round / (Σ phase walls + speculative-
@@ -574,8 +572,8 @@ def _emit_round_telemetry(telemetry, sink: MetricsSink, rd: int,
     the test_compile_reuse regression, now visible in production
     metrics), the HBM high-water where the backend exposes
     memory_stats, the failure-model counters (fault_retries_total
-    cumulative, degrade_events — DESIGN.md §10; bench rides both on the
-    al_round phases), the Prometheus gauge refresh, and an incremental
+    cumulative, degrade_events — DESIGN.md §10), the Prometheus gauge
+    refresh, and an incremental
     trace export so a crash mid-run still leaves trace.json on disk."""
     if not telemetry.train_metrics:
         return
@@ -597,9 +595,9 @@ def _emit_round_telemetry(telemetry, sink: MetricsSink, rd: int,
         if stale:
             telemetry.set_gauges(**stale)
     # Per-RUN retries: the process counter is cumulative across every
-    # run/phase sharing this interpreter (bench runs many), so the
-    # run-start baseline is subtracted — the al_round retries rider must
-    # attribute only what the measured rounds absorbed.
+    # run sharing this interpreter (a fleet worker, pytest), so the
+    # run-start baseline is subtracted — a run reports only the retries
+    # its own rounds absorbed.
     retries = faults.retry_counters()
     run_retries = retries["total"] - retries_baseline
     hbm = tele_runtime.hbm_high_water_gb()
@@ -786,7 +784,7 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
     # XLA_FLAGS is restored at run exit: XLA latched it at backend init,
     # so the env var is dead weight for THIS process afterwards — but a
     # leaked --xla_dump_to would arm dumping in every later subprocess
-    # (bench children, status probes) against a dir this run owns.
+    # (fleet children, status probes) against a dir this run owns.
     prev_xla_flags = os.environ.get("XLA_FLAGS")
     if profiling_armed:
         profile_dir = cfg.profile_dir or os.path.join(cfg.log_dir,
@@ -805,8 +803,8 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
     # $AL_FAULT_SPEC must not clobber an arming a test installed
     # programmatically before calling run_experiment.  What this run
     # arms, its finally disarms: the registry is process-global, and a
-    # spec leaking into the NEXT in-process run (bench phases, pytest)
-    # would corrupt a clean measurement with no indication why.
+    # spec leaking into the NEXT in-process run (pytest, a benchmark's
+    # warm-up) would corrupt a clean run with no indication why.
     fault_spec = cfg.fault_spec or os.environ.get("AL_FAULT_SPEC")
     if fault_spec:
         faults.configure(fault_spec, seed=cfg.run_seed)

@@ -17,8 +17,7 @@ Rung order, each reversible at the next round boundary (``relax``):
                       host-streamed path with zero recompiles (the
                       documented demotion path) — feeds are
                       bit-identical by the PR 5 contract
-  4. batch_half       OOM only: halve the train batch (the bench-only
-                      crash ladder promoted into the driver).  The ONE
+  4. batch_half       OOM only: halve the train batch.  The ONE
                       rung that is not bit-identical — batch size
                       changes BN statistics — which is why OOM is
                       outside the chaos matrix's bit-identity claim.

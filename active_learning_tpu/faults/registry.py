@@ -109,9 +109,8 @@ class InjectedFault(RuntimeError):
 
 class InjectedOOM(InjectedFault):
     """Injected allocator exhaustion — the message carries the XLA
-    RESOURCE_EXHAUSTED marker so string-matching classifiers (the bench
-    crash ladder's, retry.classify_exception) treat it exactly like the
-    real thing."""
+    RESOURCE_EXHAUSTED marker so string-matching classifiers
+    (retry.classify_exception) treat it exactly like the real thing."""
 
     def __init__(self, site_name: str):
         super().__init__(site_name, "RESOURCE_EXHAUSTED (injected)")

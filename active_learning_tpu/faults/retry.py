@@ -86,8 +86,7 @@ def _record_retry(site: str) -> None:
 
 def retry_counters() -> Dict[str, Any]:
     """Process-cumulative retry accounting: {"total", "by_site",
-    "last_site"} — the driver emits total per round, bench rides it on
-    the al_round phases."""
+    "last_site"} — the driver emits total per round."""
     with _COUNTERS_LOCK:
         return {"total": _RETRIES_TOTAL,
                 "by_site": dict(_RETRIES_BY_SITE),

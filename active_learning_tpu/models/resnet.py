@@ -37,9 +37,8 @@ Design notes (TPU-first, not a translation):
   * Fused bf16 BN statistics (``bn_stats_dtype``): flax's BatchNorm promotes
     the FULL activation tensor to float32 before its mean/var reductions —
     on a bf16 model that materializes a 2x-size tensor between the conv and
-    the stats pass and breaks producer fusion (measured -23% of forward
-    throughput, mfu_decomposition.json).  ``FusedBatchNorm`` reduces the
-    bf16 activations directly with float32 ACCUMULATION (jnp.mean's dtype
+    the stats pass and breaks producer fusion.  ``FusedBatchNorm`` reduces
+    the bf16 activations directly with float32 ACCUMULATION (jnp.mean's dtype
     argument lowers to a bf16-read/f32-accumulate XLA reduce), so the stats
     pass reads half the bytes and fuses with its producer.  Parameters and
     running statistics stay float32 either way.

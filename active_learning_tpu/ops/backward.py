@@ -1,10 +1,10 @@
 """Hand-written backward passes for the two hand-built forward kernels
 (DESIGN.md §4, "The gradient path").
 
-`mfu_decomposition.json` names the backward pass as the step's largest
-cost: the forward runs at 0.467 MFU, the full train step at 0.318, and
-the ~0.33 implied backward is whatever XLA derives from the forward
-graph.  For the two kernels this repo hand-built — the space-to-depth
+The backward pass is the step's largest cost, and it is whatever XLA
+derives from the forward graph.  (Whether these hand-written forms win
+on the chip has no ledger line yet: ROADMAP S1.)  For the two kernels
+this repo hand-built — the space-to-depth
 stem conv and the fused bf16 BN statistics — XLA's derivation loses the
 very properties the forwards were built for:
 

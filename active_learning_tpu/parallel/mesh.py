@@ -37,8 +37,7 @@ def platform_is_cpu() -> bool:
     from the jax config knob OR the env var, WITHOUT initializing a
     backend (callers run before the multi-host rendezvous).  Unset reads
     as not-CPU: accelerator machines rarely set it, CPU test/smoke
-    environments always do (conftest, the tier-1 recipe, bench's CPU
-    children)."""
+    environments always do (conftest, the tier-1 recipe)."""
     spec = jax.config.jax_platforms or os.environ.get("JAX_PLATFORMS") or ""
     return spec.split(",")[0].strip().lower() == "cpu"
 

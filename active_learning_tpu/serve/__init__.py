@@ -38,7 +38,7 @@ accelerator saturated under irregular load):
 
 No dependencies beyond the stdlib and the existing JAX stack.  Request
 latency — not round wall-clock — is this subsystem's metric; see
-``scripts/serve_loadgen.py`` and the ``serve_throughput`` bench phase.
+``scripts/serve_loadgen.py``.
 """
 
 from .batcher import MicroBatcher, QueueFullError, serve_buckets  # noqa: F401

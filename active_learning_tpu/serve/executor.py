@@ -17,9 +17,8 @@ loop.  Design decisions, each load-bearing:
     (experiment/driver.enable_compilation_cache — the serve CLI turns
     it on) those warmup compiles are disk hits after the first server
     start on a machine.  ``compile_counts()`` exposes the jit caches'
-    sizes (the tests/test_compile_reuse.py counter) so /metrics — and
-    the serve_throughput bench phase — can assert the request path
-    never compiled.
+    sizes (the tests/test_compile_reuse.py counter) so /metrics can
+    show that the request path never compiled.
   * **Double-buffered H2D.**  The inbox drain is wrapped in
     data/cache.device_prefetch: a feeder thread shards + dispatches the
     host->device transfer of batch n+1 while batch n computes, so

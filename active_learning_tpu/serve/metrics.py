@@ -4,8 +4,8 @@ batch-occupancy histogram.
 Request latency is THIS subsystem's headline metric (round wall-clock
 is the driver's), so the reservoir keeps the most recent window of
 per-request latencies and serves p50/p99 on demand — the same numbers
-``scripts/serve_loadgen.py`` measures from the client side and the
-``serve_throughput`` bench phase records.  The occupancy histogram
+``scripts/serve_loadgen.py`` measures from the client side.  The
+occupancy histogram
 (real rows per dispatched bucket) is the direct readout of how well the
 microbatcher is filling the shapes it pays for: a service living at
 occupancy 1 in a 64-bucket is latency-bound, one pegged at max_batch is

@@ -190,7 +190,7 @@ class ScoringServer:
                 fmt = (parse_qs(query).get("format") or [""])[0]
                 if fmt == "prometheus":
                     # Text exposition for stock scrapers; the JSON view
-                    # stays the default (loadgen/bench read it).
+                    # stays the default (the loadgen reads it).
                     return 200, self._metrics_prometheus(), {
                         "Content-Type":
                             "text/plain; version=0.0.4; charset=utf-8"}

@@ -46,7 +46,7 @@ ran three times at 0.67x/1.11x/0.93x the XLA scan with
 ``pallas_picks_match: False`` every time, so it was deleted per the r5
 verdict (wrong-on-hardware code behind an env var is a trap, not a
 feature).  The decision record survives in DESIGN.md §5;
-``LAST_BACKEND`` keeps the bench's backend attribution.
+``LAST_BACKEND`` says which scan answered a call.
 
 Pool shapes are padded to bounded-waste geometric buckets
 (pool.bucket_size: 1/8-octave granularity — padded rows ride every
@@ -78,19 +78,17 @@ from ..pool import bucket_size
 Factors = Tuple[jnp.ndarray, ...]
 
 # Which scan answered the last kcenter_greedy call ("xla" sequential /
-# "xla-batched"): bench.py's kcenter phases record it so a capture is
-# attributable to its code path.
+# "xla-batched"); tests/test_compile_reuse.py reads it.
 LAST_BACKEND: Optional[str] = None
 
 # Which pool layout the last kcenter_greedy call selected over
-# ("replicated" / "row") — the bench's pool_sharding attribution.
+# ("replicated" / "row"); tests/test_pool_sharding.py reads it.
 LAST_SHARDING: Optional[str] = None
 
 # Whether the last kcenter_greedy call fed its initial-min/minimax
 # column scans through the ring-permute feed (the row-sharded backend's
-# only column feed since ISSUE 15) — the bench's ring_feed attribution
-# on al_round lines.  None until a call runs; False on the replicated
-# backend.
+# only column feed since ISSUE 15); tests/test_pod_tier.py reads it.
+# None until a call runs; False on the replicated backend.
 LAST_RING_FEED: Optional[bool] = None
 
 # Each pick's squared distance-to-(labeled ∪ earlier picks) AT PICK
@@ -792,11 +790,9 @@ def row_capable(n: int, budget: int, mesh, batch_q: Optional[int] = None,
     a single-process mesh with >1 device, the bucketed pool size
     dividing evenly over it, and at least one candidate batch of rows
     per shard.  This IS the gate ``kcenter_greedy`` applies — callers
-    that must know the layout BEFORE paying for a selection (the
-    ``kcenter_select_maxn`` bench climbs an ndev-times-larger pool on
-    the row rungs) pre-check here instead of discovering a silent
-    replicated fallback, at ndev times the per-chip bytes, after the
-    run."""
+    that must know the layout BEFORE paying for a selection pre-check
+    here instead of discovering a silent replicated fallback, at ndev
+    times the per-chip bytes, after the run."""
     if mesh is None:
         return False
     ndev = mesh.devices.size

@@ -3,8 +3,8 @@ classification, collective-bytes accounting, and the merged host+device
 timeline (DESIGN.md §11).
 
 Everything the run reports elsewhere is HOST wall-clock — the span
-tracer (spans.py), ``mfu_decomposition``, and bench all time dispatch
-loops from the host, which cannot distinguish "the device was busy" from
+tracer (spans.py) times dispatch loops from the host, which cannot
+distinguish "the device was busy" from
 "the host stalled feeding it" or "the collective waited on a peer".
 This module is the one place the framework asks the DEVICE what
 happened:
@@ -12,9 +12,9 @@ happened:
   * **Bounded capture windows.**  ``start_capture``/``finish_capture``
     (and the ``capture_window`` context manager over them) arm
     ``jax.profiler.start_trace``/``stop_trace`` around a chosen slice of
-    the run — one warm AL round (``--profile_rounds``), a serve window
-    under live load (``POST /v1/profile``), or a bench timing loop
-    (``AL_BENCH_PROFILE_DIR``).  One window at a time, process-wide;
+    the run — one warm AL round (``--profile_rounds``) or a serve window
+    under live load (``POST /v1/profile``).  One window at a time,
+    process-wide;
     never a whole run (a multi-hour trace is unusable and its overhead
     taints every number recorded during it).  This module is the ONLY
     place ``jax.profiler`` may be imported or invoked —
@@ -53,8 +53,8 @@ happened:
     spec-scorer / feed-prefetch tracks.
 
 Parsing and classification are stdlib-only and import no jax — the
-tests and ``scripts/perf_report.py`` read capture summaries from hosts
-that could never initialize the run's backend.  ``jax.profiler`` is
+tests read capture summaries from hosts that could never initialize
+the run's backend.  ``jax.profiler`` is
 imported lazily inside the capture entry points only.
 """
 
@@ -190,7 +190,7 @@ def arm_hlo_dump(dump_dir: str) -> Optional[str]:
     flag is inert; set before, every module compiled in the run lands in
     the dump) — so the driver arms this BEFORE its multi-host rendezvous,
     which is the run's first backend touch on the production CLI path.
-    In a process whose backend is already up (bench in-process, pytest)
+    In a process whose backend is already up (pytest)
     the env change is silently inert and the byte table stays empty —
     the capture then reports counts/time without bytes rather than
     guessing.  Returns the directory armed, or the one an operator
@@ -454,8 +454,8 @@ def hlo_text_collective_bytes(text: str) -> Dict[str, int]:
     the parsing core of ``hlo_collective_bytes``, exposed so callers
     holding compiled executables directly (``jitted.lower(...)
     .compile().as_text()`` — the pod-tier wire-bytes cross-check in
-    tests/test_pod_tier.py and the gradient-sync bench rider) can
-    measure collective payload bytes without arming a disk dump."""
+    tests/test_pod_tier.py) can measure collective payload bytes
+    without arming a disk dump."""
     table: Dict[str, int] = {}
     for name, shape_text, _op in _collective_inst_re().findall(text):
         nbytes = _shape_bytes(shape_text)

@@ -4,8 +4,8 @@
 Runs the 18-check registry (10 legacy trace_lint invariants + the
 lock-discipline / donation-safety / recompile-hazard /
 collective-axis / diagnostics-inert / wal-before-ack
-deep checkers) over active_learning_tpu/, bench.py, and scripts/
-through ONE shared-parse AST cache.
+deep checkers) over active_learning_tpu/ and scripts/ through ONE
+shared-parse AST cache.
 
     python scripts/al_lint.py                 # run everything
     python scripts/al_lint.py --list          # show the registry

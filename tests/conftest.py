@@ -38,3 +38,25 @@ import pytest  # noqa: E402
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture
+def collective_lock(tmp_path_factory):
+    """At most one of the eight-device CPU collective tests at a time,
+    across the xdist workers of one run: each keeps eight device threads
+    in a rendezvous that aborts the worker (``Fatal Python error:
+    Aborted``) when they are starved, and two of them beside four busy
+    workers starve each other.  The lock is a file in the run's own
+    temporary root, which every worker shares; ``xdist_group`` would not
+    do, since it has no effect under ``--dist load``."""
+    import fcntl
+
+    root = tmp_path_factory.getbasetemp()
+    if os.environ.get("PYTEST_XDIST_WORKER"):
+        root = root.parent
+    with open(root / "collective.lock", "w") as fh:
+        fcntl.flock(fh, fcntl.LOCK_EX)
+        try:
+            yield
+        finally:
+            fcntl.flock(fh, fcntl.LOCK_UN)

@@ -6,6 +6,7 @@ tests on the virtual 8-device mesh."""
 
 from __future__ import annotations
 
+import os
 import tempfile
 
 import jax
@@ -102,6 +103,17 @@ def make_strategy(name: str = "RandomSampler", n_train: int = 64,
     return strategy
 
 
+def load_script(name: str):
+    """``scripts/<name>.py`` as a module (scripts/ is not a package)."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                           "scripts", f"{name}.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def build_jpeg_tree(root: str, n_classes: int = 3, n_per_class: int = 6,
                     seed: int = 0, min_hw: int = 40, max_hw: int = 80) -> str:
     """Seeded class-per-subdirectory JPEG tree, built ATOMICALLY (written
@@ -109,7 +121,6 @@ def build_jpeg_tree(root: str, n_classes: int = 3, n_per_class: int = 6,
     build can never leave a partial tree that later runs silently reuse.
     Shared by the pytest jpeg_tree fixture and the multihost worker."""
     import json
-    import os
     import shutil
 
     from PIL import Image

@@ -106,6 +106,22 @@ class TestPackageClean:
             "a file was parsed more than once — the single-parse AST "
             f"cache contract broke (max={worst})")
 
+    def test_default_targets_are_the_package_and_scripts(self):
+        """What al_lint walks by default: every .py of the package and
+        of scripts/ (less the two lint entry points), nothing at the
+        repo root, and every target is a file that exists."""
+        from active_learning_tpu.analysis import engine
+        files = engine.default_files()
+        assert files and all(os.path.isfile(f) for f in files)
+        rels = [os.path.relpath(f, REPO) for f in files]
+        tops = {r.split(os.sep)[0] for r in rels}
+        assert tops == {"active_learning_tpu", "scripts"}
+        scripts = sorted(n for n in os.listdir(os.path.join(REPO, "scripts"))
+                         if n.endswith(".py"))
+        assert sorted(os.path.basename(r) for r in rels
+                      if r.startswith("scripts" + os.sep)) == [
+            n for n in scripts if n not in engine._SELF]
+
     def test_shim_matches_engine_on_live_tree(self):
         """The 10 legacy checks produce identical verdicts through the
         shim and through the engine registry (both clean here; fixture
@@ -271,8 +287,8 @@ class TestSuppressions:
 
     def test_donates_registry_is_package_global(self, tmp_path):
         """The trainer's donating steps are called through attributes
-        from bench.py and the strategies — a _DONATES declared in one
-        module must cover call sites in every other."""
+        from the strategies — a _DONATES declared in one module must
+        cover call sites in every other."""
         a = tmp_path / "a.py"
         a.write_text("_DONATES = {'_train_step': (0,)}\n"
                      "class T:\n"

@@ -377,157 +377,6 @@ class TestGenJobs:
             assert cli.args_to_config(ns).vaal.adversary_param == 2.5
 
 
-class TestBenchHarness:
-    """The benchmark harness's pure helpers (bench.py at the repo root)."""
-
-    def _bench(self):
-        import importlib.util
-        import os
-        path = os.path.join(os.path.dirname(__file__), "..", "bench.py")
-        spec = importlib.util.spec_from_file_location("bench", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def test_parse_child_json_requires_keys(self):
-        bench = self._bench()
-        out = ('{"note": "stray library json"}\n'
-               '{"phase": "p", "ips": 1.0, "ips_per_chip": 1.0}\n'
-               '{"also": "stray"}\n')
-        got = bench._parse_child_json(out)
-        assert got == {"phase": "p", "ips": 1.0, "ips_per_chip": 1.0}
-        # With a different required set the scan must skip parseable
-        # lines missing the key instead of stopping at them.
-        flops = bench._parse_child_json(
-            '{"flops_per_image": 7.0}\n{"other": 1}\n',
-            required=("flops_per_image",))
-        assert flops == {"flops_per_image": 7.0}
-        assert bench._parse_child_json("no json here\n{broken\n") is None
-
-    def test_crashed_child_keeps_completed_measurement(self, monkeypatch):
-        """A child that printed a complete measurement and then died in a
-        later optional pass produced real evidence: the parent must keep
-        it (same discipline as the timeout path) instead of burning a
-        retry and reporting failure."""
-        import types
-
-        bench = self._bench()
-        good = ('{"phase": "p", "ips": 5.0, "ips_per_chip": 5.0}\n')
-
-        calls = []
-
-        def fake_run(cmd, **kwargs):
-            calls.append(cmd)
-            return types.SimpleNamespace(returncode=1, stdout=good,
-                                         stderr="boom in optional pass")
-
-        monkeypatch.setattr(bench.subprocess, "run", fake_run)
-        result, failure = bench.run_phase_with_retries(
-            "p", iters=3, per_chip=8, timeout=30,
-            deadline=bench.time.monotonic() + 300)
-        assert failure is None
-        assert result == {"phase": "p", "ips": 5.0, "ips_per_chip": 5.0}
-        assert len(calls) == 1  # no retry burned
-
-        # Without any parseable stdout the crash is a real failure and
-        # the retry ladder proceeds.
-        def fake_run_bad(cmd, **kwargs):
-            calls.append(cmd)
-            return types.SimpleNamespace(returncode=1, stdout="",
-                                         stderr="hard crash")
-
-        monkeypatch.setattr(bench.subprocess, "run", fake_run_bad)
-        result, failure = bench.run_phase_with_retries(
-            "p", iters=3, per_chip=8, timeout=30,
-            deadline=bench.time.monotonic() + 300, max_attempts=2)
-        assert result is None and failure.startswith("exit 1")
-        assert len(calls) == 3  # both attempts of the ladder actually ran
-
-    def test_oom_crash_stashes_snapshot_and_still_retries(self,
-                                                          monkeypatch):
-        """A child that OOMed (RESOURCE_EXHAUSTED) after printing a
-        partial measurement must NOT end the ladder: the halved-batch
-        retry can recover the measurements the crash cut short.  The
-        snapshot is returned only when the retry also fails (ADVICE r5
-        #3)."""
-        import types
-
-        bench = self._bench()
-        partial = '{"phase": "p", "ips": 5.0, "ips_per_chip": 5.0}\n'
-        full = ('{"phase": "p", "ips": 4.0, "ips_per_chip": 4.0, '
-                '"ips_warm": 9.0}\n')
-
-        calls = []
-
-        def fake_run_retry_wins(cmd, **kwargs):
-            calls.append(cmd)
-            if len(calls) == 1:
-                return types.SimpleNamespace(
-                    returncode=1, stdout=partial,
-                    stderr="RESOURCE_EXHAUSTED: out of memory")
-            return types.SimpleNamespace(returncode=0, stdout=full,
-                                         stderr="")
-
-        monkeypatch.setattr(bench.subprocess, "run", fake_run_retry_wins)
-        result, failure = bench.run_phase_with_retries(
-            "p", iters=30, per_chip=64, timeout=30,
-            deadline=bench.time.monotonic() + 300, max_attempts=2)
-        assert failure is None and result["ips_warm"] == 9.0
-        assert len(calls) == 2  # the retry actually ran
-        # ... at half the per-chip batch.
-        assert "32" in calls[1][calls[1].index("--per-chip-batch") + 1]
-
-        calls.clear()
-
-        def fake_run_retry_fails(cmd, **kwargs):
-            calls.append(cmd)
-            if len(calls) == 1:
-                return types.SimpleNamespace(
-                    returncode=1, stdout=partial,
-                    stderr="RESOURCE_EXHAUSTED: out of memory")
-            return types.SimpleNamespace(returncode=1, stdout="",
-                                         stderr="hard crash")
-
-        monkeypatch.setattr(bench.subprocess, "run", fake_run_retry_fails)
-        result, failure = bench.run_phase_with_retries(
-            "p", iters=30, per_chip=64, timeout=30,
-            deadline=bench.time.monotonic() + 300, max_attempts=2)
-        assert failure is None  # the stashed snapshot is the answer
-        assert result == {"phase": "p", "ips": 5.0, "ips_per_chip": 5.0}
-        assert len(calls) == 2
-
-    @pytest.mark.slow
-    def test_al_round_phase_smoke(self, monkeypatch):
-        """run_al_round_phase end to end at smoke scale: the phase that
-        carries BASELINE.md metric #1 must be known-working BEFORE its
-        one chance at a live-TPU capture.  (The imagenet variant differs
-        only in its dataset branch — JPEG tree + ImageFolderDataset —
-        which test_imagenet_pipeline covers; the full variant is
-        CPU-compile-bound, not CI material.)"""
-        monkeypatch.setenv("AL_BENCH_ROUND_SMOKE", "1")
-        bench = self._bench()
-        result = bench.run_al_round_phase("cifar", epochs=2)
-        assert result["phase"] == "al_round_cifar"
-        assert result["ips"] is None or result["ips"] > 0
-        for key in ("round_sec_warm", "round_sec_cold", "total_sec",
-                    "test_accuracy_rd1"):
-            assert result[key] is not None, key
-        rounds = result["phases_sec"]
-        for rd in ("round0", "round1"):
-            for name in ("query_time", "train_time", "test_time"):
-                assert rounds[rd][name] > 0, (rd, name)
-        # Warm round must not include round 0's XLA compiles.
-        assert result["round_sec_warm"] < result["round_sec_cold"]
-
-    def test_kcenter_phase_tiny(self):
-        bench = self._bench()
-        result, picks = bench.run_kcenter_phase(8, dim=16, pool_n=128)
-        assert result["ips"] > 0 and result["budget"] == 8
-        assert result["unit"] == "picks/sec"
-        assert result["backend"] in ("xla", "xla-batched")
-        assert len(picks) == 8 and len(set(picks.tolist())) == 8
-
-
 class TestCollapseGuard:
     """The evidence protocol's dead-round guard (VERDICT r5 #3,
     scripts/cifar10_evidence.py): a fit whose BEST validation accuracy
@@ -665,371 +514,3 @@ class TestEverySamplerEndToEnd:
         assert strategy.pool.num_labeled == 16
         picked = strategy.pool.labeled_idxs()
         assert len(np.unique(picked)) == 16
-
-
-class TestBenchEvidence:
-    """bench.py's _finalize evidence assembly — the machinery that turned
-    round 3's rc=124/parsed=null into guaranteed output.  Pure-logic
-    tests over the module state; no backend is touched."""
-
-    def _bench_with_state(self, phases=None, failures=None, cache=None,
-                          probe=None):
-        import importlib.util
-        import os as os_mod
-        path = os_mod.path.join(os_mod.path.dirname(__file__), "..",
-                                "bench.py")
-        spec = importlib.util.spec_from_file_location("bench_ev", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        import time as time_mod
-        mod._STATE.update(start=time_mod.monotonic(), phases=phases or {},
-                          failures=failures or {}, cache=cache or {},
-                          probe=probe, emitted=False)
-        return mod
-
-    def _entry(self, name, **extra):
-        return dict({"phase": name, "ips": 100.0, "ips_per_chip": 100.0,
-                     "n_chips": 1, "device_kind": "TPU v5 lite",
-                     "captured_utc": "2026-01-01T00:00:00Z"}, **extra)
-
-    def test_dead_probe_reuses_cache_unverified(self):
-        bench = self._bench_with_state(
-            cache={"resnet50_imagenet_train":
-                   self._entry("resnet50_imagenet_train")},
-            probe={"ok": False, "error": "probe timeout"})
-        out = bench._finalize()
-        entry = out["phases"]["resnet50_imagenet_train"]
-        assert entry["cached"] and entry["device_unverified"]
-        assert out["value"] == 100.0
-        # Phases with no cache show up as explicit failures naming the
-        # dead backend.
-        assert "backend unreachable" in \
-            out["failed_phases"]["kcenter_select"]
-
-    def test_hw_mismatch_never_resurrects_cache(self):
-        bench = self._bench_with_state(
-            cache={"resnet50_imagenet_train":
-                   self._entry("resnet50_imagenet_train")},
-            probe={"ok": True, "device_kind": "TPU v4", "n_devices": 4,
-                   "platform": "tpu", "seconds": 5.0})
-        out = bench._finalize()
-        assert "resnet50_imagenet_train" not in out["phases"]
-        assert "TPU v4" in out["failed_phases"]["resnet50_imagenet_train"]
-        assert out["value"] is None
-
-    def test_profiled_and_decode_only_never_headline(self):
-        bench = self._bench_with_state(phases={
-            "resnet50_imagenet_train":
-                self._entry("resnet50_imagenet_train", profiled=True),
-            "imagenet_datapath":
-                self._entry("imagenet_datapath", decode_only=True),
-            "resnet18_cifar_train":
-                self._entry("resnet18_cifar_train", ips_per_chip=50.0),
-        })
-        out = bench._finalize()
-        assert out["metric"].startswith("resnet18_cifar_train")
-        assert out["value"] == 50.0
-
-    def test_emit_final_survives_malformed_cache(self, capsys, tmp_path):
-        # A cache entry missing ips_per_chip must degrade the headline to
-        # null, never suppress the output line.
-        bench = self._bench_with_state(
-            cache={"resnet50_imagenet_train": {
-                "phase": "resnet50_imagenet_train",
-                "device_kind": "TPU v5 lite", "n_chips": 1}},
-            probe={"ok": False, "error": "dead"})
-        bench.PARTIAL_PATH = str(tmp_path / "partial.json")
-        bench.EVIDENCE_PATH = str(tmp_path / "evidence.json")
-        bench._emit_final()
-        line = capsys.readouterr().out.strip().splitlines()[-1]
-        out = json.loads(line)
-        assert out["value"] is None
-        assert bench._STATE["emitted"]
-
-    def test_headline_skips_rateless_entry(self):
-        # ADVICE r4: a malformed entry without ips_per_chip used to win
-        # the headline slot, making value None and (with a V100 baseline)
-        # crashing the vs_baseline math — which degraded the output to
-        # the minimal error line, dropping every phase's evidence.
-        bench = self._bench_with_state(phases={
-            "resnet50_imagenet_train": {
-                "phase": "resnet50_imagenet_train", "n_chips": 1,
-                "device_kind": "TPU v5 lite"},  # no ips_per_chip
-            "resnet18_cifar_train":
-                self._entry("resnet18_cifar_train", ips_per_chip=3600.0),
-        })
-        out = bench._finalize()
-        assert out["metric"].startswith("resnet18_cifar_train")
-        assert out["value"] == 3600.0
-        assert out["vs_baseline"] == 2.0  # 3600 / the 1800 V100 envelope
-
-    def test_headline_skips_nan_rate(self):
-        # A stale cache file can carry a literal NaN (json.load accepts
-        # the token): such an entry must not win the headline over a
-        # phase holding a real number.
-        bench = self._bench_with_state(phases={
-            "resnet50_imagenet_train":
-                self._entry("resnet50_imagenet_train",
-                            ips_per_chip=float("nan")),
-            "resnet18_cifar_train":
-                self._entry("resnet18_cifar_train", ips_per_chip=1800.0),
-        })
-        out = bench._finalize()
-        assert out["metric"].startswith("resnet18_cifar_train")
-        assert out["value"] == 1800.0
-
-    def _full_entry(self, name):
-        # The optional fields each phase ACTUALLY produces, all at once —
-        # the realistic-maximal line must keep its rich form.  mfu/flops
-        # only exist on the 4 model train/score phases (cost_analysis of
-        # a jitted step); claiming them on every phase made the fixture
-        # ~100 bytes FATTER than any real line can be.
-        extra = dict(cached=True, fresh_failure="not attempted",
-                     device_unverified=True,
-                     batch_per_chip=128, iters=30, platform="tpu")
-        if name in ("resnet50_imagenet_train", "resnet18_cifar_train",
-                    "resnet50_imagenet_score", "resnet18_cifar_score"):
-            extra.update(mfu=0.321, tflops_per_sec_per_chip=77.6,
-                         peak_tflops_per_chip=197.0, gflop_per_image=7.97,
-                         flops_source="device-cost-analysis")
-        if name.endswith("_train"):
-            extra.update(feed_source="resident", feed_stall_frac=0.0)
-        if name == "imagenet_datapath":
-            # Canonical names only: the ips_warm alias and its
-            # deprecated_keys shim are gone (kept one release, PR 5).
-            extra.update(warm_memmap_ips=9000.1,
-                         cold_populate_ips=100.0, decode_ips=1047.8)
-        if name == "imagenet_train_feed":
-            extra.update(unit="train images/sec (in-fit)",
-                         feed_source="resident", feed_stall_frac=0.013,
-                         ips_resident=21000.4, ips_host_prefetch=1100.2,
-                         ips_host_serial=160.9, resident_x_serial=130.5)
-        if name.startswith("al_round"):
-            extra.update(round_sec_warm=123.45, round_sec_cold=456.78,
-                         test_accuracy_rd1=0.8125,
-                         feed_source="resident", feed_stall_frac=0.02,
-                         # The pipelined round's riders (ISSUE 7) and
-                         # the failure model's counters (ISSUE 8) both
-                         # ride every end-to-end round phase.
-                         round_pipeline="speculative", overlap_frac=0.389,
-                         round_vs_max_phase=1.18, spec_hit_frac=0.33,
-                         fault_retries_total=12, degrade_events=3,
-                         phases_sec={"round0": {"train_time": 100.0}})
-        if name.startswith("kcenter_select"):
-            # Every selection phase now attributes its pool layout
-            # alongside the scan backend (ISSUE 6).
-            extra.update(unit="picks/sec", backend="xla-batched",
-                         pool_sharding="row")
-        if name == "kcenter_select_maxn":
-            # The sharded-pool probe's extra evidence: the row-vs-
-            # replicated ceiling comparison (file-only; pool_sharding
-            # is the field that rides the line).
-            extra.update(max_n=2_560_000, replicated_max_n=1_280_000,
-                         row_scale_x=2.0)
-        if name == "serve_throughput":
-            extra.update(unit="scored images/sec (served)",
-                         qps_closed=137.2, p99_ms_closed=25.0,
-                         request_path_compiles=0,
-                         batch_occupancy={"8": {"4": 64, "8": 236}})
-        if name == "stream_round":
-            # The streaming phase's line riders (ISSUE 14) plus its
-            # file-only figures — absent from this fixture until ISSUE
-            # 16 made the maximal pin actually cover the margin math.
-            extra.update(unit="ingested rows/sec (acked)",
-                         ack_p99_ms=142.375, trigger_cause="watermark",
-                         ingest_qps=250.1, ack_p50_ms=2.8,
-                         pool_rows_final=6304)
-        if name == "disk_pool_feed":
-            # The disk tier (ISSUE 16): hit fraction + stall tail ride
-            # the line; the rest is evidence-file-only.
-            extra.update(unit="train images/sec (disk-backed pool)",
-                         cache_hit_frac=0.982, page_stall_ms_p99=41.75,
-                         page_stall_ms_p50=3.2,
-                         page_in_rows_per_sec=51200.5,
-                         pool_disk_rows=50000, pool_over_budget_x=4.0,
-                         ips_memory=4100.2, disk_vs_memory=0.873,
-                         picks_identical=True)
-        if name == "fleet_smoke":
-            # The fleet tier (ISSUE 18): runs finished / resumed and
-            # the fleet wall ride the line; the attempt/kill detail is
-            # evidence-file-only.
-            extra.update(unit="runs finished/min (2-worker localhost "
-                              "fleet)",
-                         runs_finished=2, runs_failed=0, runs_resumed=1,
-                         attempts_total=3,
-                         killed_run="MarginSampler-synthetic-8-0-abcd1234",
-                         merged_prom_runs=2, comparison_rendered=True,
-                         total_sec=131.5, workers=2)
-        return self._entry(name, **extra)
-
-    def test_compact_line_bounded_all_phases_full(self, capsys, tmp_path):
-        """Worst realistic case — every phase present with every optional
-        field it produces — must fit the driver's tail window in RICH
-        form, and the full evidence must land in the file the line
-        references."""
-        phases = {name: self._full_entry(name)
-                  for name, _, _, _ in
-                  self._bench_with_state().PHASES}
-        bench = self._bench_with_state(
-            phases=phases,
-            probe={"ok": True, "device_kind": "TPU v5 lite",
-                   "n_devices": 1, "platform": "tpu", "seconds": 5.0})
-        bench.PARTIAL_PATH = str(tmp_path / "partial.json")
-        bench.EVIDENCE_PATH = str(tmp_path / "evidence.json")
-        bench._emit_final(extra={"error": "x" * 400})
-        line = capsys.readouterr().out.strip().splitlines()[-1]
-        assert len(line.encode()) <= bench.MAX_LINE_BYTES
-        out = json.loads(line)
-        assert out["evidence"] == bench.EVIDENCE_PATH
-        assert out["phases"]["resnet50_imagenet_train"]["ips"] == 100.0
-        assert out["phases"]["al_round_cifar"]["warm_s"] == 123.45
-        assert out["phases"]["al_round_cifar"]["retries"] == 12
-        assert out["phases"]["al_round_cifar"]["degraded"] == 3
-        assert out["phases"]["imagenet_datapath"]["warm_ips"] == 9000.1
-        # The disk tier's riders (ISSUE 16) ride in rich form alongside
-        # everything above — the 15-phase maximal line still fits.
-        assert out["phases"]["disk_pool_feed"]["hit"] == 0.982
-        assert out["phases"]["disk_pool_feed"]["stall_ms"] == 41.75
-        assert "disk_vs_memory" not in out["phases"]["disk_pool_feed"]
-        assert out["phases"]["stream_round"]["ack_p99"] == 142.375
-        # The fleet tier's riders (ISSUE 18) — the 16-phase maximal
-        # line still fits the tail window.
-        assert out["phases"]["fleet_smoke"]["runs"] == 2
-        assert out["phases"]["fleet_smoke"]["resumed"] == 1
-        assert out["phases"]["fleet_smoke"]["wall_s"] == 131.5
-        assert "killed_run" not in out["phases"]["fleet_smoke"]
-        # The file carries what the line dropped.
-        with open(bench.EVIDENCE_PATH) as fh:
-            full = json.load(fh)
-        assert full["phases"]["resnet50_imagenet_train"][
-            "tflops_per_sec_per_chip"] == 77.6
-
-    def test_compact_line_bounded_all_phases_failed(self, capsys, tmp_path):
-        """Opposite extreme — nothing captured, every phase failing with a
-        long message — must also fit and stay strictly parseable."""
-        failures = {name: "e" * 500 for name, _, _, _ in
-                    self._bench_with_state().PHASES}
-        bench = self._bench_with_state(
-            failures=failures, probe={"ok": False, "error": "p" * 300})
-        bench.PARTIAL_PATH = str(tmp_path / "partial.json")
-        bench.EVIDENCE_PATH = str(tmp_path / "evidence.json")
-        bench._emit_final()
-        line = capsys.readouterr().out.strip().splitlines()[-1]
-        assert len(line.encode()) <= bench.MAX_LINE_BYTES
-        out = json.loads(line)
-        assert out["value"] is None and not out["probe_ok"]
-        assert "al_round_cifar" in out["failed"]
-
-    def test_compact_line_degrades_on_adversarial_bloat(self, tmp_path):
-        """Even an impossible shape — every phase carrying every optional
-        field at once — stays under the bound via staged truncation."""
-        bench = self._bench_with_state()
-        entry = self._entry(
-            "x", mfu=0.3, unit="picks/sec", cached=True, ips_warm=1.0,
-            round_sec_warm=1.0, round_sec_cold=2.0, test_accuracy_rd1=0.5,
-            qps_closed=137.2, p99_ms_closed=25.0, request_path_compiles=0,
-            backend="xla-batched")
-        out = {
-            "metric": "m" * 60, "value": 1.0, "unit": "u",
-            "vs_baseline": 1.0, "backend_probe": {"ok": True},
-            "elapsed_sec": 1.0, "error": "e" * 1000,
-            "phases": {f"phase_{i:02d}_{'n' * 20}": dict(entry)
-                       for i in range(12)},
-            "failed_phases": {f"fail_{i:02d}": "f" * 500
-                              for i in range(12)},
-        }
-        line = bench._compact_line(out)
-        assert len(line.encode()) <= bench.MAX_LINE_BYTES
-        parsed = json.loads(line)
-        assert parsed["evidence"] == bench.EVIDENCE_PATH
-
-    def test_finalize_crash_keeps_partial_and_recovers_it(self, capsys,
-                                                          tmp_path):
-        """A finalize crash at emit time must not clobber the last good
-        per-phase snapshot — it is recovered as the evidence body with
-        the error attached, and the partial mirror is left alone."""
-        bench = self._bench_with_state(
-            # A non-dict cache entry makes _finalize's dict(entry, ...)
-            # raise — the malformed-cache crash class.
-            cache={"resnet50_imagenet_train": "corrupt"},
-            probe={"ok": False, "error": "dead"})
-        bench.PARTIAL_PATH = str(tmp_path / "partial.json")
-        bench.EVIDENCE_PATH = str(tmp_path / "evidence.json")
-        bench._STATE["run_id"] = "this-run"
-        good = {"phases": {"resnet18_cifar_train":
-                           self._entry("resnet18_cifar_train")},
-                "partial": True, "run_id": "this-run"}
-        with open(bench.PARTIAL_PATH, "w") as fh:
-            json.dump(good, fh)
-        bench._emit_final()
-        line = capsys.readouterr().out.strip().splitlines()[-1]
-        out = json.loads(line)
-        assert "error" in out
-        # The snapshot survived in BOTH files.
-        with open(bench.PARTIAL_PATH) as fh:
-            assert json.load(fh) == good
-        with open(bench.EVIDENCE_PATH) as fh:
-            ev = json.load(fh)
-        assert ev["phases"]["resnet18_cifar_train"]["ips"] == 100.0
-        assert "finalize failed" in ev["error"]
-
-    def test_finalize_crash_never_adopts_other_runs_partial(self, capsys,
-                                                            tmp_path):
-        """A PREVIOUS run's snapshot (different run_id) must not be
-        presented as this run's evidence."""
-        bench = self._bench_with_state(
-            cache={"resnet50_imagenet_train": "corrupt"},
-            probe={"ok": False, "error": "dead"})
-        bench.PARTIAL_PATH = str(tmp_path / "partial.json")
-        bench.EVIDENCE_PATH = str(tmp_path / "evidence.json")
-        bench._STATE["run_id"] = "this-run"
-        stale = {"phases": {"resnet18_cifar_train":
-                            self._entry("resnet18_cifar_train")},
-                 "partial": True, "run_id": "previous-run", "value": 100.0}
-        with open(bench.PARTIAL_PATH, "w") as fh:
-            json.dump(stale, fh)
-        bench._emit_final()
-        line = capsys.readouterr().out.strip().splitlines()[-1]
-        out = json.loads(line)
-        assert out["value"] is None and "error" in out
-        with open(bench.EVIDENCE_PATH) as fh:
-            ev = json.load(fh)
-        assert "phases" not in ev  # the minimal dict, not the stale one
-        with open(bench.PARTIAL_PATH) as fh:
-            assert json.load(fh) == stale  # and the stale file untouched
-
-    def test_failed_evidence_write_nulls_the_path(self, capsys, tmp_path):
-        """If the evidence file cannot be written, the line must not point
-        at a stale previous file."""
-        bench = self._bench_with_state(
-            phases={"resnet18_cifar_train":
-                    self._entry("resnet18_cifar_train")})
-        bench.PARTIAL_PATH = str(tmp_path / "partial.json")
-        bench.EVIDENCE_PATH = str(tmp_path / "no_such_dir" / "evidence.json")
-        bench._emit_final()
-        line = capsys.readouterr().out.strip().splitlines()[-1]
-        out = json.loads(line)
-        assert out["evidence"] is None
-        assert out["value"] == 100.0  # the line itself still carries data
-
-    def test_nan_never_serialized(self, capsys, tmp_path):
-        """ADVICE r4: a NaN rate must serialize as null — the bare `NaN`
-        token is non-standard JSON and strict parsers reject the line."""
-        bench = self._bench_with_state(phases={
-            "resnet18_cifar_train":
-                self._entry("resnet18_cifar_train",
-                            ips=float("nan"), ips_per_chip=float("nan"),
-                            mfu=float("inf"))})
-        bench.PARTIAL_PATH = str(tmp_path / "partial.json")
-        bench.EVIDENCE_PATH = str(tmp_path / "evidence.json")
-        bench._emit_final()
-        line = capsys.readouterr().out.strip().splitlines()[-1]
-        assert "NaN" not in line and "Infinity" not in line
-
-        def reject(_):
-            raise AssertionError("non-standard JSON constant in line")
-
-        out = json.loads(line, parse_constant=reject)
-        assert out["phases"]["resnet18_cifar_train"]["ips"] is None
-        with open(bench.EVIDENCE_PATH) as fh:
-            json.load(fh, parse_constant=reject)

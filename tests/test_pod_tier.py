@@ -168,6 +168,7 @@ class TestMeasuredWireBytes:
         assert a2a and min(a2a) <= per_shard + 1024
 
 
+@pytest.mark.usefixtures("collective_lock")
 class TestInt8ReduceScatter:
     def _exact_and_rs(self, x):
         exact = x.reshape(NDEV, -1).sum(0)
@@ -229,6 +230,7 @@ class TestInt8ReduceScatter:
         assert np.abs(rs[0] - exact).max() < 0.05
 
 
+@pytest.mark.usefixtures("collective_lock")
 class TestRingPrimitives:
     def test_ring_shift_rotates_right_and_closes(self):
         mesh = mesh_lib.make_mesh()
@@ -373,6 +375,7 @@ class TestBatchScaling:
                              model=TinyClassifier(num_classes=4))
 
 
+@pytest.mark.usefixtures("collective_lock")
 class TestReduceScatterGating:
     def test_probe_passes_on_reduce_scatter_form(self):
         """The learning probe actually trains through the reduce-scatter
@@ -443,6 +446,7 @@ class TestReduceScatterGating:
         assert jr["grad_allreduce"] == "f32_degraded"
 
 
+@pytest.mark.usefixtures("collective_lock")
 class TestReduceScatterCompileReuse:
     def test_warm_rounds_zero_new_compiles_under_int8_rs(self, tmp_path):
         """The acceptance's every-new-path compile-freeness, on the

@@ -2,12 +2,10 @@
 round-window selection (never round 0), the op-classification table,
 the HLO collective-bytes table, capture summarisation, the merged
 host+device timeline, the off-path inertness bound, the serve
-``POST /v1/profile`` verb, the perf-regression gate
-(scripts/perf_report.py), and the end-to-end CPU-mesh acceptance smoke
+``POST /v1/profile`` verb, and the end-to-end CPU-mesh acceptance smoke
 through the production CLI."""
 
 import contextlib
-import importlib.util
 import json
 import os
 import subprocess
@@ -20,14 +18,6 @@ from active_learning_tpu.telemetry import profiler as prof
 from active_learning_tpu.telemetry import spans as spans_lib
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-
-
-def _load_script(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(REPO, "scripts", f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 class TestProfileRounds:
@@ -433,112 +423,6 @@ class TestServeProfileVerb:
             assert status == 409, payload
         finally:
             prof.finish_capture(handle)
-
-
-class TestPerfReport:
-    def test_real_trajectory_renders_and_exits_zero(self, capsys):
-        pr = _load_script("perf_report")
-        rc = pr.main([])
-        out = capsys.readouterr().out
-        assert rc == 0
-        # Every salvageable round renders; the dead ones show as
-        # explicit skips, never KeyErrors.
-        assert "r05" in out and "skipped" in out
-        assert "al_round_imagenet warm_s" in out
-
-    def test_degraded_compact_line_only_json_is_salvaged(self, tmp_path):
-        pr = _load_script("perf_report")
-        compact = {"metric": "m", "value": 1.0, "phases": {
-            "al_round_cifar": {"ips": 400.0, "warm_s": 22.0,
-                               "cached": True}}}
-        wrapper = {"n": 7, "rc": 0, "parsed": None,
-                   "tail": "noise\n" + json.dumps(compact) + "\n"}
-        path = tmp_path / "BENCH_r07.json"
-        path.write_text(json.dumps(wrapper))
-        series = pr.load_series([str(path)])
-        assert series[0]["phases"]["al_round_cifar"]["warm_s"] == 22.0
-        assert "tail" in series[0]["note"]
-
-    def test_schema_drift_aliases_resolve(self, tmp_path):
-        pr = _load_script("perf_report")
-        old = {"phases": {
-            # Full-evidence shape: total ips + n_chips, old warm keys.
-            "imagenet_datapath": {"ips": 697.2, "ips_per_chip": 348.6,
-                                  "n_chips": 2, "ips_warm": 157.7},
-            "al_round_cifar": {"ips": 830.0, "n_chips": 2,
-                               "round_sec_warm": 22.59,
-                               "round_sec_cold": 80.47,
-                               "test_accuracy_rd1": 0.6}}}
-        new = {"phases": {
-            "imagenet_datapath": {"ips": 350.0,
-                                  "warm_memmap_ips": 160.0}}}
-        a, b = tmp_path / "a.json", tmp_path / "b.json"
-        a.write_text(json.dumps(old))
-        b.write_text(json.dumps(new))
-        series = pr.load_series([str(a), str(b)])
-        dp0 = series[0]["phases"]["imagenet_datapath"]
-        assert dp0["warm_ips"] == 157.7          # ips_warm alias
-        assert dp0["ips_per_chip"] == 348.6
-        rd0 = series[0]["phases"]["al_round_cifar"]
-        assert rd0["warm_s"] == 22.59 and rd0["cold_s"] == 80.47
-        assert rd0["ips_per_chip"] == pytest.approx(415.0)  # ips/n_chips
-        assert series[1]["phases"]["imagenet_datapath"][
-            "warm_ips"] == 160.0                 # canonical spelling
-
-    def test_regression_gate_trips_and_passes(self, tmp_path, capsys):
-        pr = _load_script("perf_report")
-        base = tmp_path / "base.json"
-        base.write_text(json.dumps({"phases": {
-            "al_round_cifar": {"ips": 400.0, "warm_s": 20.0},
-            "resnet18_cifar_train": {"ips": 20_000.0}}}))
-        ok = tmp_path / "ok.json"
-        ok.write_text(json.dumps({"phases": {
-            "al_round_cifar": {"ips": 390.0, "warm_s": 21.0},
-            "resnet18_cifar_train": {"ips": 19_000.0}}}))
-        bad = tmp_path / "bad.json"
-        bad.write_text(json.dumps({"phases": {
-            "al_round_cifar": {"ips": 200.0, "warm_s": 30.0},
-            "resnet18_cifar_train": {"ips": 12_000.0}}}))
-        assert pr.main([str(base), str(ok)]) == 0
-        capsys.readouterr()
-        assert pr.main([str(base), str(bad)]) == 1
-        err = capsys.readouterr().err
-        assert "REGRESSION al_round_cifar warm_s" in err
-        assert "REGRESSION resnet18_cifar_train ips_per_chip" in err
-        # A phase the latest round simply did not capture is absence,
-        # not regression (the absence rule).
-        missing = tmp_path / "missing.json"
-        missing.write_text(json.dumps({"phases": {
-            "kcenter_select": {"ips": 500.0}}}))
-        assert pr.main([str(base), str(missing)]) == 0
-
-    def test_first_capture_is_baseline_not_regression(self, tmp_path):
-        pr = _load_script("perf_report")
-        only = tmp_path / "only.json"
-        only.write_text(json.dumps({"phases": {
-            "al_round_cifar": {"ips": 1.0, "warm_s": 9999.0}}}))
-        assert pr.main([str(only)]) == 0
-
-    def test_unusable_current_is_loud_exit_3_not_silent_ok(self,
-                                                          tmp_path,
-                                                          capsys):
-        """The gate asked to judge THIS run must not substitute history
-        as 'latest' when the current file is unreadable or carries no
-        phases: distinct exit 3, never a silent ok or a history-vs-
-        itself verdict."""
-        pr = _load_script("perf_report")
-        base = tmp_path / "base.json"
-        base.write_text(json.dumps({"phases": {
-            "al_round_cifar": {"ips": 400.0, "warm_s": 20.0}}}))
-        empty = tmp_path / "empty_evidence.json"
-        empty.write_text(json.dumps({"phases": {}}))
-        assert pr.main([str(base), "--current", str(empty)]) == 3
-        assert "NO-EVIDENCE" in capsys.readouterr().err
-        assert pr.main([str(base), "--current",
-                        str(tmp_path / "absent.json")]) == 3
-        # The same file as a plain HISTORICAL entry stays a skip-with-
-        # note, not an error — only the explicit --current is gated.
-        assert pr.main([str(base), str(empty)]) == 0
 
 
 class TestEndToEndDeviceTruth:

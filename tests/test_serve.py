@@ -48,7 +48,7 @@ from active_learning_tpu.serve.executor import DeviceExecutor
 from active_learning_tpu.serve.server import ScoringServer
 from active_learning_tpu.train import checkpoint as ckpt_lib
 
-from helpers import TinyClassifier, tiny_train_config
+from helpers import TinyClassifier, load_script, tiny_train_config
 
 IMG = (8, 8, 3)
 
@@ -918,22 +918,24 @@ class TestHostS2d:
 
 
 # ---------------------------------------------------------------------------
-# Bench phase smoke (the serve_throughput capture path)
+# Under load: the closed-loop generator against the in-process server
 # ---------------------------------------------------------------------------
 
-class TestBenchServePhase:
-    def test_smoke_records_qps_and_zero_compiles(self, monkeypatch):
-        import importlib.util
-
-        monkeypatch.setenv("AL_BENCH_SERVE_SMOKE", "1")
-        path = os.path.join(os.path.dirname(__file__), "..", "bench.py")
-        spec = importlib.util.spec_from_file_location("bench_serve", path)
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        result = bench.run_serve_phase(2, 8)
-        assert result["phase"] == "serve_throughput"
-        assert result["ips"] > 0 and result["qps_closed"] > 0
-        assert result["p99_ms_closed"] is not None
-        assert result["request_path_compiles"] == 0
-        assert result["batch_occupancy"]
-        assert result["n_429"] == 0 or result["qps_open"] > 0
+class TestClosedLoopLoad:
+    def test_load_after_warmup_adds_no_compile(self, stack):
+        """Concurrent clients sending back to back (scripts/
+        serve_loadgen.py's closed loop, the generator an operator points
+        at a deployment) land partial and full batches in every bucket;
+        every shape was compiled at startup, so the request path
+        compiles nothing and every request is answered."""
+        loadgen = load_script("serve_loadgen")
+        url = f"http://127.0.0.1:{stack.port}"
+        workers, rows, warm = 3, 3, 2
+        out = loadgen.run_closed(url, 1.0, workers, rows, IMG,
+                                 warmup_requests=warm)
+        assert out["n_ok"] > 0 and out["n_err"] == 0 and out["n_429"] == 0
+        assert out["p99_ms"] is not None
+        _, m = stack.get("/metrics")
+        assert m["compiles"]["request_path_compiles"] == 0
+        assert m["rows_served"] == rows * (out["n_ok"] + workers * warm)
+        assert m["batch_occupancy"]

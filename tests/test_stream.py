@@ -35,7 +35,7 @@ import time
 import numpy as np
 import pytest
 
-from helpers import TinyClassifier, tiny_train_config
+from helpers import TinyClassifier, load_script, tiny_train_config
 
 from active_learning_tpu import faults
 from active_learning_tpu.config import (ExperimentConfig, StreamConfig,
@@ -54,8 +54,6 @@ from active_learning_tpu.stream.wal import (IngestWAL, iter_payloads,
 from active_learning_tpu.telemetry import prom as prom_lib
 from active_learning_tpu.telemetry import status as status_lib
 from active_learning_tpu.utils.metrics import JsonlSink, NullSink
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _rows(n, px=8, seed=0):
@@ -597,12 +595,7 @@ class TestHTTPServiceEndToEnd:
 
     def test_loadgen_ingest_mode_drives_both_endpoints(self, stream_data,
                                                        tmp_path):
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            "serve_loadgen",
-            os.path.join(REPO, "scripts", "serve_loadgen.py"))
-        loadgen = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(loadgen)
+        loadgen = load_script("serve_loadgen")
 
         cfg = _cfg("loadgen", str(tmp_path))
         # Run-forever: the test stops the service itself.

@@ -252,6 +252,7 @@ class TestDeviceResidentEpochs:
         assert len(seen) > 0  # hook ran => host path was used
 
 
+@pytest.mark.usefixtures("collective_lock")
 class TestResidentGatherFeed:
     """The resident-gather train feed (DESIGN.md §2a): train batches are
     on-device gathers of labeled indices from the SAME pinned pool that
