@@ -27,24 +27,35 @@ Key architectural differences (deliberate, TPU-first):
 
 from __future__ import annotations
 
+import functools
+import math
+import os
 import time
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from flax.traverse_util import flatten_dict, unflatten_dict
 
 from .. import faults
 from ..config import ExperimentConfig, TrainConfig
 from ..data.core import Dataset
+from ..parallel import mesh as mesh_lib
 from ..pool import PoolState
 from ..registry import STRATEGIES
 from ..telemetry import diagnostics as diag_lib
+from ..telemetry import runtime as tele_runtime
 from ..telemetry import spans as tele_spans
 from ..train import checkpoint as ckpt_lib
 from ..train.trainer import Trainer, TrainState
 from ..utils.logging import get_logger
 from ..utils.metrics import MetricsSink, NullSink
 from . import scoring
+
+# Where this module compiles (al_lint recompile-hazard): the
+# re-initialisation program, once per strategy.
+_STEP_BUILDERS = ("_build_reinit",)
 
 # Pool scoring is stateless (consumes no rng, reads frozen weights), so
 # a whole-pass retry after a transient failure — a dead prefetch feeder
@@ -129,6 +140,10 @@ class Strategy:
         # Per-experiment init key; split once per re-init so every round's
         # random re-initialization is fresh but reproducible.
         self._init_key = jax.random.PRNGKey(int(self.rng.integers(2 ** 31)))
+        # The re-initialisation program and its device-resident template
+        # (init_network_weights builds both on its first call).
+        self._reinit: Optional[Callable] = None
+        self._reinit_template: Optional[Dict] = None
 
     # -- identity --------------------------------------------------------
 
@@ -169,40 +184,96 @@ class Strategy:
                                      self.exp_hash, self.round)
 
     def init_network_weights(self) -> None:
-        """Fresh random init every round (so the linear head always resets,
-        strategy.py:182-184), then overlay a pretrained SSL/transfer ckpt if
-        one is configured (strategy.py:185-196)."""
+        """The variables every round starts from (so the linear head
+        always resets, strategy.py:182-184): the pretrained SSL/transfer
+        checkpoint's leaves where one is configured (strategy.py:185-196),
+        a fresh random draw for every leaf it does not cover — all from
+        ONE compiled program whose inputs are the round's key and a
+        device-resident template.  The file is read and overlaid only
+        when the template is built."""
         tracer = tele_spans.get_tracer()
         self._init_key, sub = jax.random.split(self._init_key)
-        sample = self.train_set.gather(np.zeros(1, dtype=np.int64))
-        # The three reinit/* spans end at an ENQUEUE: model.init and the
-        # device copy behind replace_variables are asynchronous, so the
-        # device work they start may finish under a later span.
-        with tracer.span("reinit/model_init"):
+        built = self._refresh_reinit_template(sub)
+        template = self._reinit_template
+        # Ends at an ENQUEUE: the program is asynchronous, so the copies
+        # and draws it starts may finish under a later span.
+        with tracer.span("reinit/apply", args={
+                "template": "built" if built else "hit",
+                "leaves_copied": len(template["leaves"]),
+                "leaves_drawn": template["drawn"],
+                "bytes": template["bytes"]}):
+            variables = self._reinit(sub, template["leaves"])
             if self.state is None:
-                self.state = self.trainer.init_state(sub, sample)
+                self.state = self.trainer.state_of(variables)
             else:
-                variables = self.model.init(sub, sample.astype(np.float32),
-                                            train=False)
-                self.state = self.trainer.replace_variables(self.state,
-                                                            variables)
+                self.state = self.state.replace(
+                    params=variables["params"],
+                    batch_stats=variables.get("batch_stats", {}))
         if self.train_cfg.has_pretrained:
-            from ..utils import pretrained as pretrained_lib
-            cfg = self.train_cfg.pretrained
-            with tracer.span("reinit/pretrained_read"):
-                torch_state = pretrained_lib.load_torch_state_dict(cfg.path)
-            # Key surgery, the torch->flax mapping and the copy to the
-            # device.
-            with tracer.span("reinit/overlay"):
-                variables = pretrained_lib.apply_pretrained(
-                    dict(self.state.variables), cfg, state=torch_state)
-                self.state = self.trainer.replace_variables(self.state,
-                                                            variables)
             self.logger.info(
                 f"Initialized network weights from "
                 f"{self.train_cfg.pretrained.path}")
         else:
             self.logger.info("Initialized Network Weights Randomly.")
+
+    def _build_reinit(self) -> Callable:
+        """``reinit(key, template) -> variables``, compiled once.  The
+        covered set is read off the template itself (its paths are part
+        of the jit's cache key): a leaf the template holds is a COPY of
+        it — no donation: the fit donates the state it is given, and the
+        template must outlive it — and every other leaf keeps
+        ``model.init``'s draw, so XLA drops the forward pass and the
+        draws nothing reads as dead code.  ``out_shardings`` pins the
+        REPLICATED layout the epoch program was compiled against (the
+        lesson of Trainer.reinit_optimizer)."""
+        model = self.model
+        shape = self.train_set.gather(np.zeros(1, dtype=np.int64)).shape
+
+        @functools.partial(
+            jax.jit, out_shardings=mesh_lib.replicated_sharding(self.mesh))
+        def reinit(key, template):
+            flat = flatten_dict(model.init(
+                key, jnp.zeros(shape, jnp.float32), train=False))
+            flat.update(template)
+            return unflatten_dict(flat)
+        tele_runtime.get_run().register_jit(f"reinit@{id(self):x}", reinit)
+        return reinit
+
+    def _refresh_reinit_template(self, key: jax.Array) -> bool:
+        """The re-initialisation program (built on the first call) and
+        its template: the leaves the pretrained checkpoint covers,
+        overlaid on the host ONCE and kept replicated on the mesh.  It is
+        rebuilt only when the file's (path, mtime, size) has changed —
+        one ``os.stat`` a round honours a replaced file as the
+        reference's read-every-round would.  True when it was built."""
+        cfg = self.train_cfg.pretrained
+        stamp = None
+        if self.train_cfg.has_pretrained:
+            st = os.stat(cfg.path)
+            stamp = (cfg.path, st.st_mtime_ns, st.st_size)
+        if self._reinit is None:
+            self._reinit = self._build_reinit()
+        elif self._reinit_template["stamp"] == stamp:
+            return False
+        tracer = tele_spans.get_tracer()
+        # The model's leaves in the abstract (no draw, nothing fetched).
+        like = flatten_dict(jax.eval_shape(self._reinit, key, {}))
+        leaves = {}
+        if stamp is not None:
+            from ..utils import pretrained as pretrained_lib
+            with tracer.span("reinit/pretrained_read"):
+                torch_state = pretrained_lib.load_torch_state_dict(cfg.path)
+            # Key surgery, the torch->flax mapping and the one upload.
+            with tracer.span("reinit/overlay"):
+                leaves = mesh_lib.replicate(
+                    pretrained_lib.pretrained_leaves(like, cfg, torch_state),
+                    self.mesh)
+        self._reinit_template = {
+            "stamp": stamp, "leaves": leaves,
+            "drawn": len(like) - len(leaves),
+            "bytes": sum(math.prod(leaf.shape) * leaf.dtype.itemsize
+                         for leaf in like.values())}
+        return True
 
     def load_best_ckpt(self) -> None:
         path = self.weight_paths()["best_ckpt"]
@@ -401,7 +472,6 @@ class Strategy:
             # join the trainer's in the generalized jit-cache counter —
             # a nonzero per-round miss delta after round 1 is a shape
             # leak.  No-op without an installed run.
-            from ..telemetry import runtime as tele_runtime
             tele_runtime.get_run().register_jit(
                 f"score_{kind}@{id(self):x}", self._score_steps[kind])
         return self._score_steps[kind]
@@ -418,7 +488,6 @@ class Strategy:
         served as-is and the rest are completed inline — bit-identical
         either way (experiment/pipeline.py's correctness contract), so
         speculation only ever changes wall-clock."""
-        from ..telemetry import runtime as tele_runtime
         bs = self._score_batch_size()
         if self.pipeline is not None:
             out = self.pipeline.consume(kind, keys, np.asarray(idxs), bs,

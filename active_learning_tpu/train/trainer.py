@@ -305,7 +305,12 @@ class Trainer:
                    ) -> TrainState:
         variables = self.model.init(rng, jnp.asarray(sample_input),
                                     train=False)
-        variables = mesh_lib.replicate(variables, self.mesh)
+        return self.state_of(mesh_lib.replicate(variables, self.mesh))
+
+    def state_of(self, variables) -> TrainState:
+        """A TrainState with a fresh optimizer state around variables
+        that are already replicated on the mesh (the re-initialisation
+        program's own outputs, Strategy.init_network_weights)."""
         opt_state = mesh_lib.replicate(self._opt_init(variables["params"]),
                                        self.mesh)
         return TrainState(params=variables["params"],

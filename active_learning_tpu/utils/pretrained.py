@@ -158,16 +158,17 @@ def _transform(value: np.ndarray, kind: Optional[str]) -> np.ndarray:
     return value
 
 
-def overlay_torch_state(variables: Dict[str, Any],
-                        torch_state: Mapping[str, np.ndarray],
-                        strict: bool = True) -> Dict[str, Any]:
-    """Partial update: write every mappable checkpoint tensor into a copy of
-    ``variables`` (the reference's ``init_dict.update(net_dict)``,
-    load_pretrained_weights.py:64-65).  Shape mismatches always raise;
-    unknown keys raise when ``strict``."""
-    from flax.traverse_util import flatten_dict, unflatten_dict
-    flat = flatten_dict(variables)
-    loaded = 0
+def map_torch_state(like: Mapping[FlaxPath, Any],
+                    torch_state: Mapping[str, np.ndarray],
+                    strict: bool = True) -> Dict[FlaxPath, np.ndarray]:
+    """Every mappable checkpoint tensor as the model's own leaf: keyed
+    by its Flax path, transposed to the Flax layout and cast to the
+    dtype of ``like[path]``.  ``like`` is the flattened variable tree;
+    only ``.shape`` and ``.dtype`` of its leaves are read, so an abstract
+    tree (``jax.eval_shape`` of ``model.init``) serves and no device
+    array is fetched.  Shape mismatches always raise; unknown keys raise
+    when ``strict``."""
+    covered: Dict[FlaxPath, np.ndarray] = {}
     for key, value in torch_state.items():
         try:
             mapped = torch_key_to_flax(key)
@@ -179,38 +180,57 @@ def overlay_torch_state(variables: Dict[str, Any],
             continue
         path, kind = mapped
         arr = _transform(np.asarray(value), kind)
-        if path not in flat:
+        if path not in like:
             raise KeyError(
                 f"Checkpoint key '{key}' maps to {'/'.join(path)}, absent "
                 f"from the model (wrong depth/variant?)")
+        shape = tuple(like[path].shape)
         if (path[-2:] == ("conv_stem", "kernel") and arr.shape[:2] == (7, 7)
-                and tuple(flat[path].shape)[:2] == (4, 4)):
+                and shape[:2] == (4, 4)):
             # s2d-stem model consuming a standard 7x7-stem checkpoint:
             # fold the kernel exactly (models/resnet.s2d_stem_kernel) —
             # the loaded network computes the identical convolution.
             from ..models.resnet import s2d_stem_kernel
             arr = np.asarray(s2d_stem_kernel(arr))
-        if tuple(flat[path].shape) != tuple(arr.shape):
+        if shape != tuple(arr.shape):
             raise ValueError(
                 f"Shape mismatch for '{key}' -> {'/'.join(path)}: "
-                f"ckpt {arr.shape} vs model {tuple(flat[path].shape)}")
-        flat[path] = arr.astype(np.asarray(flat[path]).dtype)
-        loaded += 1
-    get_logger().info(f"Overlaid {loaded} pretrained tensors")
+                f"ckpt {arr.shape} vs model {shape}")
+        covered[path] = arr.astype(like[path].dtype)
+    get_logger().info(f"Overlaid {len(covered)} pretrained tensors")
+    return covered
+
+
+def overlay_torch_state(variables: Dict[str, Any],
+                        torch_state: Mapping[str, np.ndarray],
+                        strict: bool = True) -> Dict[str, Any]:
+    """Partial update: write every mappable checkpoint tensor into a copy of
+    ``variables`` (the reference's ``init_dict.update(net_dict)``,
+    load_pretrained_weights.py:64-65)."""
+    from flax.traverse_util import flatten_dict, unflatten_dict
+    flat = flatten_dict(variables)
+    flat.update(map_torch_state(flat, torch_state, strict))
     return unflatten_dict(flat)
 
 
-def apply_pretrained(variables: Dict[str, Any], cfg: PretrainedConfig,
-                     state: Optional[Dict[str, np.ndarray]] = None
-                     ) -> Dict[str, Any]:
-    """Full pipeline: load -> surgery -> overlay.  Called from
-    Strategy.init_network_weights after the random re-init
-    (strategy.py:185-196), which reads the file itself (``state``) so the
-    read and the overlay are two sibling spans there."""
-    if state is None:
-        state = load_torch_state_dict(cfg.path)
+def pretrained_leaves(like: Mapping[FlaxPath, Any], cfg: PretrainedConfig,
+                      state: Mapping[str, np.ndarray]
+                      ) -> Dict[FlaxPath, np.ndarray]:
+    """Surgery -> mapping: the leaves the checkpoint ``state`` covers
+    under ``cfg``'s key filters, as ``map_torch_state`` gives them.
+    Strategy.init_network_weights builds its device-resident template
+    from these, once per checkpoint file."""
     state = surgery(state, required_key=cfg.required_key,
                     skip_key=cfg.skip_key, replace_map=cfg.replace_map)
-    return overlay_torch_state(variables, state)
+    return map_torch_state(like, state)
 
 
+def apply_pretrained(variables: Dict[str, Any], cfg: PretrainedConfig
+                     ) -> Dict[str, Any]:
+    """Full pipeline on a host tree: load -> surgery -> overlay (the
+    reference's load_pretrained_weights, strategy.py:185-196)."""
+    from flax.traverse_util import flatten_dict, unflatten_dict
+    flat = flatten_dict(variables)
+    flat.update(pretrained_leaves(flat, cfg,
+                                  load_torch_state_dict(cfg.path)))
+    return unflatten_dict(flat)

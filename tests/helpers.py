@@ -66,15 +66,16 @@ def tiny_train_config(batch_size: int = 16) -> TrainConfig:
 def make_strategy(name: str = "RandomSampler", n_train: int = 64,
                   n_test: int = 32, num_classes: int = 4, image_size: int = 8,
                   seed: int = 0, init_pool: int = 8, eval_count: int = 8,
-                  n_epoch: int = 2, sink=None, **cfg_overrides):
+                  n_epoch: int = 2, sink=None, model=None, train_cfg=None,
+                  init_weights: bool = True, **cfg_overrides):
     """Build a fully wired Strategy over synthetic data on the 8-device CPU
     mesh."""
     train_set, test_set, al_set = get_data_synthetic(
         n_train=n_train, n_test=n_test, num_classes=num_classes,
         image_size=image_size, seed=seed)
-    model = TinyClassifier(num_classes=num_classes)
+    model = model or TinyClassifier(num_classes=num_classes)
     mesh = mesh_lib.make_mesh()
-    train_cfg = tiny_train_config()
+    train_cfg = train_cfg or tiny_train_config()
     cfg_overrides.setdefault(
         "ckpt_path", tempfile.mkdtemp(prefix="al_tpu_test_ckpt_"))
     cfg_overrides.setdefault(
@@ -99,7 +100,8 @@ def make_strategy(name: str = "RandomSampler", n_train: int = 64,
             targets, num_classes, eval_idxs, init_pool,
             random_seed=cfg.init_pool_seed)
         strategy.update(init_idxs, len(init_idxs))
-    strategy.init_network_weights()
+    if init_weights:
+        strategy.init_network_weights()
     return strategy
 
 

@@ -36,7 +36,9 @@ _OLD_STACK = {"bench.py", "bench_cache.json", "bench_evidence_r05.json",
               "mfu_decomposition.json", "scripts/mfu_decomposition.py",
               "scripts/perf_report.py", "tests/test_bench_json.py"}
 GONE = {
-    "PERF.md": _OLD_STACK | {"span_run.py"},
+    "PERF.md": {"bench.py", "scripts/mfu_decomposition.py",
+                "scripts/perf_report.py", "tests/test_bench_json.py",
+                "span_run.py"},
     "ROADMAP.md": _OLD_STACK | {"span_run.py", "BENCH_r05.json"},
 }
 _SAYS_GONE = re.compile(

@@ -134,9 +134,7 @@ TABLE = {
     "test_time": ("round", 1),
     "collect_pool": ("query_time", 1),
     "query/select": ("query_time", 1),
-    "reinit/model_init": ("init_network_weights_time", 1),
-    "reinit/overlay": ("init_network_weights_time", 1),
-    "reinit/pretrained_read": ("init_network_weights_time", 1),
+    "reinit/apply": ("init_network_weights_time", 1),
     "fit/prepare": ("train_time", 1),
     "epoch": ("train_time", 2),
     "fit/validate": ("train_time", 2),
@@ -200,8 +198,29 @@ class TestSpanSites:
             for e in mine:
                 assert by_id[e["args"]["parent"]]["name"] == parent
 
+    @pytest.mark.parametrize("name", ["reinit/pretrained_read",
+                                      "reinit/overlay"])
+    def test_template_is_built_in_round_0_only(self, toy_run, name):
+        """The file is read and overlaid in the round that builds the
+        re-initialisation template and in no later one."""
+        events = _spans(toy_run["traceEvents"])
+        by_id = {e["args"]["id"]: e for e in events}
+        mine = [e for e in events if e["name"] == name]
+        assert [e["args"]["round"] for e in mine] == [0]
+        assert by_id[mine[0]["args"]["parent"]]["name"] == \
+            "init_network_weights_time"
+        assert not any(e["name"] == "reinit/model_init" for e in events)
+
     def test_counters_ride_the_spans(self, toy_run):
         events = _spans(toy_run["traceEvents"])
+        applies = [e["args"] for e in events if e["name"] == "reinit/apply"]
+        assert [a["round"] for a in applies] == [0, 1, 2]
+        assert [a["template"] for a in applies] == ["built", "hit", "hit"]
+        for a in applies:
+            # The toy file holds the head; TinyClassifier's ``proj`` is
+            # drawn.  8x8x3 rows: proj 192x8 + 8, linear 8x4 + 4, float32.
+            assert (a["leaves_copied"], a["leaves_drawn"]) == (2, 2)
+            assert a["bytes"] == 4 * (192 * 8 + 8 + 8 * 4 + 4)
         for e in events:
             if e["name"] == "epoch":
                 assert e["args"]["steps_run"] >= e["args"]["steps_real"] >= 1
