@@ -84,6 +84,10 @@ def apply_view(images_u8: jnp.ndarray, view: ViewSpec,
     transform).
     """
     x = images_u8
+    if view.normalization is None:
+        # Rows of token ids: their view is the backbone's own input stage
+        # (models/backbone.input_stage), which runs right behind this.
+        return x
     s2d = len(view.normalization.mean) * 4 == x.shape[-1]
     if view.augment and train:
         assert key is not None, "augmentation requires a PRNG key"

@@ -42,11 +42,37 @@ class ViewSpec:
     augment: random crop (with ``pad`` zero-padding) + horizontal flip — the
       reference's train transform (custom_cifar10.py:47-49).  The al/test
       views use augment=False (custom_cifar10.py:36-40).
+    normalization None: rows that are not pixels (token ids) pass through
+      as they are; the backbone's own input stage (its embedding lookup)
+      is their view (models/backbone.py).
     """
 
-    normalization: Normalization
+    normalization: Optional[Normalization]
     augment: bool = False
     pad: int = 4
+
+
+# The view of a row of token ids: nothing to normalise, nothing to flip.
+TOKEN_VIEW = ViewSpec(None, augment=False, pad=0)
+
+
+def check_rows(rows: np.ndarray) -> None:
+    """The row schema: a pool row is its dtype and its shape, ``uint8
+    [H, W, C]`` an image or ``int32 [T]`` a sequence of T token ids.  The
+    pinned form, the gather and the view carry rows of either kind as they
+    are (parallel/resident.py, data/augment.py); nothing follows a model's
+    name."""
+    if not (rows.dtype == np.uint8 and rows.ndim == 4
+            or rows.dtype == np.int32 and rows.ndim == 2):
+        raise ValueError(
+            f"rows must be uint8 [N,H,W,C] images or int32 [N,T] token "
+            f"ids, not {rows.dtype}{list(rows.shape)}")
+
+
+def rows_are_tokens(dataset) -> bool:
+    """The dataset's rows are ``[T]`` token ids, not ``[H, W, C]`` images:
+    a row is T positions of work, counted in tokens."""
+    return len(dataset.image_shape) == 1
 
 
 class Dataset:
@@ -55,13 +81,16 @@ class Dataset:
     num_classes: int
     targets: np.ndarray  # int64 [N]
     view: ViewSpec
-    image_shape: Tuple[int, int, int]  # H, W, C of a gathered batch row
+    # Shape of a gathered batch row: (H, W, C) of an image, (T,) of a row
+    # of token ids (``check_rows``).
+    image_shape: Tuple[int, ...]
 
     def __len__(self) -> int:
         raise NotImplementedError
 
     def gather(self, idxs: np.ndarray) -> np.ndarray:
-        """Return uint8 images [len(idxs), H, W, C] for the given indices."""
+        """Return the rows ``[len(idxs), *image_shape]`` for the given
+        indices (uint8 images; int32 token ids)."""
         raise NotImplementedError
 
     def class_counts(self) -> np.ndarray:
@@ -70,7 +99,8 @@ class Dataset:
 
 
 class ArrayDataset(Dataset):
-    """In-memory uint8 dataset (CIFAR-scale data; fits in host RAM).
+    """In-memory dataset (CIFAR-scale data; fits in host RAM) of rows of
+    one schema: uint8 images or int32 token ids (``check_rows``).
 
     ``limit`` implements the reference's debug_mode truncation to 50
     samples (custom_cifar10.py:14-17) without copying.
@@ -79,8 +109,7 @@ class ArrayDataset(Dataset):
     def __init__(self, images: np.ndarray, targets: Sequence[int],
                  num_classes: int, view: ViewSpec,
                  limit: Optional[int] = None):
-        assert images.dtype == np.uint8 and images.ndim == 4, (
-            "images must be uint8 [N,H,W,C]")
+        check_rows(images)
         self.images = images
         self.targets = np.asarray(targets, dtype=np.int64)
         assert len(self.images) == len(self.targets)

@@ -136,6 +136,20 @@ ARG_POOLS.register("synthetic", {
 })
 
 
+# Rows of token ids under a frozen encoder (models/mla_moe.py): the
+# linear-evaluation recipe at the scale of a unit-RMS embedding, no
+# validation split at this size, a loader batch that is already a step of
+# batch x T tokens.
+_TOKENS_LINEAR = TrainConfig(
+    eval_split=0.0,
+    loader_tr=LoaderConfig(batch_size=16, num_workers=0),
+    loader_te=LoaderConfig(batch_size=16, num_workers=0),
+    optimizer=OptimizerConfig("sgd", lr=0.1, weight_decay=0.0, momentum=0.9),
+    scheduler=SchedulerConfig("step", step_size=20, gamma=0.1))
+SSP_LINEAR_EVALUATION_POOL["synthetic_tokens"] = _TOKENS_LINEAR
+ARG_POOLS.get("synthetic")["synthetic_tokens"] = _TOKENS_LINEAR
+
+
 def get_train_config(arg_pool: str, dataset: str,
                      pretrained_root: Optional[str] = None) -> TrainConfig:
     """Resolve ``(arg_pool, dataset) -> TrainConfig``; rebases any relative

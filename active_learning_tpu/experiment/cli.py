@@ -20,6 +20,13 @@ from ..config import (ExperimentConfig, ImbalanceConfig, TelemetryConfig,
                       VAALConfig)
 
 
+def _model_names() -> List[str]:
+    """What ``--model`` takes: the backbones the registry holds."""
+    from ..models import factory  # noqa: F401  (registers the models)
+    from ..registry import MODELS
+    return MODELS.names()
+
+
 def get_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         description="TPU-native active learning (parity with "
@@ -41,7 +48,8 @@ def get_parser() -> argparse.ArgumentParser:
     # Dataset (parser.py:27-39)
     p.add_argument("--dataset", type=str, default="cifar10",
                    choices=["cifar10", "imbalanced_cifar10", "imagenet",
-                            "imbalanced_imagenet", "synthetic"])
+                            "imbalanced_imagenet", "synthetic",
+                            "synthetic_tokens"])
     p.add_argument("--dataset_dir", type=str, default=None)
     p.add_argument("--arg_pool", type=str, default="default")
     p.add_argument("--pretrained_root", type=str, default=None,
@@ -61,7 +69,8 @@ def get_parser() -> argparse.ArgumentParser:
                    choices=["random", "random_balance"])
     # Training (parser.py:60-69)
     p.add_argument("--model", type=str, default="SSLResNet18",
-                   choices=["SSLResNet18", "SSLResNet50"])
+                   choices=_model_names(),
+                   help="a backbone the registry holds (models/factory.py)")
     p.add_argument("--resume_training", action="store_true")
     p.add_argument("--n_epoch", type=int, default=60)
     p.add_argument("--early_stop_patience", type=int, default=30,

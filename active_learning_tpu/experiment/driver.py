@@ -720,7 +720,9 @@ def _round_snapshot(strategy) -> dict:
     attempt's re-initialized model)."""
     variables = None
     if strategy.state is not None:
-        variables = jax.tree.map(np.asarray, strategy.state.variables)
+        # The trainable leaves: a frozen one cannot have moved.
+        variables = jax.tree.map(np.asarray,
+                                 strategy.state.trainable_variables)
     return {
         "pool": strategy.pool.to_arrays(),
         "rng_state": copy.deepcopy(strategy.rng.bit_generator.state),

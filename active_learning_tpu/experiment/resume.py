@@ -148,15 +148,12 @@ def load_experiment(strategy, cfg: ExperimentConfig) -> int:
     # whole object with its weights).  The state skeleton is built with a
     # throwaway key — NOT init_network_weights, which would consume a split
     # of the restored _init_key (diverging post-resume training from an
-    # uninterrupted run) and pointlessly overlay any pretrained checkpoint
-    # right before load_best_ckpt overwrites it.
+    # uninterrupted run).  It holds the frozen leaves, which no best
+    # checkpoint does (Strategy.skeleton_state).
     best = strategy.weight_paths()["best_ckpt"]
     if os.path.exists(best):
         if strategy.state is None:
-            import jax
-            sample = strategy.train_set.gather(np.zeros(1, dtype=np.int64))
-            strategy.state = strategy.trainer.init_state(
-                jax.random.PRNGKey(0), sample)
+            strategy.state = strategy.skeleton_state()
         strategy.load_best_ckpt()
     aux_path = os.path.join(directory, AUX_FILE)
     if os.path.exists(aux_path):

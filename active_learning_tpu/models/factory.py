@@ -13,7 +13,8 @@ from typing import Any, Optional
 import jax.numpy as jnp
 
 from ..registry import MODELS
-from .resnet import SSLClassifier, resnet18, resnet50
+from . import mla_moe as _mla_moe  # noqa: F401  (registers its presets)
+from .resnet import resnet18, resnet50
 
 MODELS.register("SSLResNet18", resnet18)
 MODELS.register("SSLResNet50", resnet50)
@@ -63,6 +64,7 @@ DATASET_NUM_CLASSES = {
     "imagenet": 1000,
     "imbalanced_imagenet": 1000,
     "synthetic": 10,
+    "synthetic_tokens": 16,
 }
 
 
@@ -74,7 +76,8 @@ def get_network(
     dtype: Any = "auto",
     stem: str = "default",
     bn_stats_dtype: Any = "auto",
-) -> SSLClassifier:
+) :
+    """A backbone (models/backbone.py) by its registered name."""
     if num_classes is None:
         try:
             num_classes = DATASET_NUM_CLASSES[dataset]
@@ -83,7 +86,8 @@ def get_network(
                 f"Unknown dataset '{dataset}'; pass num_classes explicitly")
     factory = MODELS.get(model_name)
     # The reference applies the SimCLR CIFAR stem whenever num_classes == 10
-    # (resnet_simclr.py:17-18); keep that behavior.
+    # (resnet_simclr.py:17-18); keep that behavior.  (The image models'
+    # options mean nothing to a token encoder, which takes what it knows.)
     cifar_stem = num_classes == 10
     if stem in (None, "auto"):
         stem = "default"

@@ -27,6 +27,7 @@ Key architectural differences (deliberate, TPU-first):
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 import os
@@ -41,6 +42,7 @@ from flax.traverse_util import flatten_dict, unflatten_dict
 from .. import faults
 from ..config import ExperimentConfig, TrainConfig
 from ..data.core import Dataset
+from ..models import backbone
 from ..parallel import mesh as mesh_lib
 from ..pool import PoolState
 from ..registry import STRATEGIES
@@ -55,7 +57,7 @@ from . import scoring
 
 # Where this module compiles (al_lint recompile-hazard): the
 # re-initialisation program, once per strategy.
-_STEP_BUILDERS = ("_build_reinit",)
+_STEP_BUILDERS = ("_build_reinit", "_draw_frozen")
 
 # Pool scoring is stateless (consumes no rng, reads frozen weights), so
 # a whole-pass retry after a transient failure — a dead prefetch feeder
@@ -65,6 +67,12 @@ _STEP_BUILDERS = ("_build_reinit",)
 _SCORE_RETRY = faults.RetryPolicy(site="pool_score",
                                   classify=faults.classify_exception,
                                   max_attempts=2)
+
+
+def _abstract_bytes(like: Dict) -> int:
+    """Bytes of a flat tree of (abstract) leaves, from shapes."""
+    return sum(math.prod(leaf.shape) * leaf.dtype.itemsize
+               for leaf in like.values())
 
 
 class Strategy:
@@ -144,6 +152,7 @@ class Strategy:
         # (init_network_weights builds both on its first call).
         self._reinit: Optional[Callable] = None
         self._reinit_template: Optional[Dict] = None
+        self._frozen_like: Optional[Dict] = None
 
     # -- identity --------------------------------------------------------
 
@@ -190,7 +199,10 @@ class Strategy:
         a fresh random draw for every leaf it does not cover — all from
         ONE compiled program whose inputs are the round's key and a
         device-resident template.  The file is read and overlaid only
-        when the template is built."""
+        when the template is built.  The leaves the backbone declares
+        frozen (models/backbone.py) are not among them: they are loaded
+        once, beside the template, and every round's state holds those
+        very arrays."""
         tracer = tele_spans.get_tracer()
         self._init_key, sub = jax.random.split(self._init_key)
         built = self._refresh_reinit_template(sub)
@@ -201,14 +213,17 @@ class Strategy:
                 "template": "built" if built else "hit",
                 "leaves_copied": len(template["leaves"]),
                 "leaves_drawn": template["drawn"],
+                "leaves_frozen": template["frozen_count"],
                 "bytes": template["bytes"]}):
             variables = self._reinit(sub, template["leaves"])
             if self.state is None:
-                self.state = self.trainer.state_of(variables)
+                self.state = self.trainer.state_of(
+                    variables, frozen=template["frozen"])
             else:
                 self.state = self.state.replace(
                     params=variables["params"],
-                    batch_stats=variables.get("batch_stats", {}))
+                    batch_stats=variables.get("batch_stats", {}),
+                    frozen=template["frozen"])
         if self.train_cfg.has_pretrained:
             self.logger.info(
                 f"Initialized network weights from "
@@ -216,24 +231,44 @@ class Strategy:
         else:
             self.logger.info("Initialized Network Weights Randomly.")
 
-    def _build_reinit(self) -> Callable:
-        """``reinit(key, template) -> variables``, compiled once.  The
-        covered set is read off the template itself (its paths are part
-        of the jit's cache key): a leaf the template holds is a COPY of
-        it — no donation: the fit donates the state it is given, and the
-        template must outlive it — and every other leaf keeps
-        ``model.init``'s draw, so XLA drops the forward pass and the
-        draws nothing reads as dead code.  ``out_shardings`` pins the
-        REPLICATED layout the epoch program was compiled against (the
-        lesson of Trainer.reinit_optimizer)."""
-        model = self.model
+    def skeleton_state(self) -> TrainState:
+        """A state to load a checkpoint into (experiment resume): the
+        template's leaves under a throwaway key, the frozen leaves as
+        every round holds them.  Consumes no split of ``_init_key``."""
+        key = jax.random.PRNGKey(0)
+        self._refresh_reinit_template(key)
+        template = self._reinit_template
+        return self.trainer.state_of(
+            self._reinit(key, template["leaves"]), frozen=template["frozen"])
+
+    def _init_variables(self, key):
+        """``model.init`` on a zero row, traced: the parameter and
+        statistics collections (what a forward sows is no variable)."""
         shape = self.train_set.gather(np.zeros(1, dtype=np.int64)).shape
+        variables = self.model.init(key, jnp.zeros(shape, jnp.float32),
+                                    train=False)
+        return {c: variables[c] for c in ("params", "batch_stats")
+                if c in variables}
+
+    def _build_reinit(self) -> Callable:
+        """``reinit(key, template) -> variables``, compiled once: the
+        TRAINABLE leaves only.  The covered set is read off the template
+        itself (its paths are part of the jit's cache key): a leaf the
+        template holds is a COPY of it — no donation: the fit donates the
+        state it is given, and the template must outlive it — and every
+        other leaf keeps ``model.init``'s draw, so XLA drops the forward
+        pass and the draws nothing reads (the frozen leaves' among them)
+        as dead code.  ``out_shardings`` pins the REPLICATED layout the
+        epoch program was compiled against (the lesson of
+        Trainer.reinit_optimizer)."""
+        prefixes = backbone.frozen_prefixes(self.model)
 
         @functools.partial(
             jax.jit, out_shardings=mesh_lib.replicated_sharding(self.mesh))
         def reinit(key, template):
-            flat = flatten_dict(model.init(
-                key, jnp.zeros(shape, jnp.float32), train=False))
+            flat = {path: leaf for path, leaf in flatten_dict(
+                        self._init_variables(key)).items()
+                    if not backbone.is_frozen_path(path, prefixes)}
             flat.update(template)
             return unflatten_dict(flat)
         tele_runtime.get_run().register_jit(f"reinit@{id(self):x}", reinit)
@@ -242,10 +277,13 @@ class Strategy:
     def _refresh_reinit_template(self, key: jax.Array) -> bool:
         """The re-initialisation program (built on the first call) and
         its template: the leaves the pretrained checkpoint covers,
-        overlaid on the host ONCE and kept replicated on the mesh.  It is
-        rebuilt only when the file's (path, mtime, size) has changed —
-        one ``os.stat`` a round honours a replaced file as the
-        reference's read-every-round would.  True when it was built."""
+        overlaid on the host ONCE and kept replicated on the mesh — the
+        trainable ones under ``leaves`` (copied every round), the frozen
+        ones under ``frozen`` (``encoder/load``: read, uploaded and, where
+        the file does not cover one, drawn, once).  It is rebuilt only
+        when the file's (path, mtime, size) has changed — one ``os.stat``
+        a round honours a replaced file as the reference's
+        read-every-round would.  True when it was built."""
         cfg = self.train_cfg.pretrained
         stamp = None
         if self.train_cfg.has_pretrained:
@@ -256,29 +294,65 @@ class Strategy:
         elif self._reinit_template["stamp"] == stamp:
             return False
         tracer = tele_spans.get_tracer()
+        prefixes = backbone.frozen_prefixes(self.model)
         # The model's leaves in the abstract (no draw, nothing fetched).
         like = flatten_dict(jax.eval_shape(self._reinit, key, {}))
-        leaves = {}
-        if stamp is not None:
-            from ..utils import pretrained as pretrained_lib
-            with tracer.span("reinit/pretrained_read"):
-                torch_state = pretrained_lib.load_torch_state_dict(cfg.path)
-            # Key surgery, the torch->flax mapping and the one upload.
-            with tracer.span("reinit/overlay"):
-                leaves = mesh_lib.replicate(
-                    pretrained_lib.pretrained_leaves(like, cfg, torch_state),
+        if prefixes and self._frozen_like is None:
+            self._frozen_like = {
+                p: v for p, v in flatten_dict(jax.eval_shape(
+                    self._init_variables, key)).items() if p not in like}
+        frozen_like = self._frozen_like or {}
+        every = {**like, **frozen_like}
+        leaves, frozen = {}, {}
+        load_span = (tracer.span("encoder/load", args={
+            "leaves": len(frozen_like), "bytes": _abstract_bytes(frozen_like)})
+            if frozen_like else contextlib.nullcontext())
+        with load_span:
+            if stamp is not None:
+                from ..utils import pretrained as pretrained_lib
+                with tracer.span("reinit/pretrained_read"):
+                    torch_state = pretrained_lib.load_torch_state_dict(
+                        cfg.path)
+                covered = pretrained_lib.pretrained_leaves(
+                    every, cfg, torch_state,
+                    key_map=getattr(self.model, "torch_key_to_flax", None))
+                leaves = {p: v for p, v in covered.items() if p in like}
+                frozen = mesh_lib.replicate(
+                    {p: v for p, v in covered.items() if p in frozen_like},
                     self.mesh)
+                del covered, torch_state
+            if len(frozen) < len(frozen_like):
+                frozen.update(self._draw_frozen(
+                    tuple(p for p in frozen_like if p not in frozen)))
+        if leaves:
+            # Key surgery and the torch->flax mapping are behind us: the
+            # one upload of the leaves every round copies.
+            with tracer.span("reinit/overlay"):
+                leaves = mesh_lib.replicate(leaves, self.mesh)
         self._reinit_template = {
             "stamp": stamp, "leaves": leaves,
             "drawn": len(like) - len(leaves),
-            "bytes": sum(math.prod(leaf.shape) * leaf.dtype.itemsize
-                         for leaf in like.values())}
+            "bytes": _abstract_bytes(like),
+            "frozen": unflatten_dict(frozen).get("params", {}),
+            "frozen_count": len(frozen_like)}
         return True
+
+    def _draw_frozen(self, paths: Tuple[Tuple[str, ...], ...]) -> Dict:
+        """The frozen leaves no checkpoint covers, drawn ONCE: from the
+        run's seed and not from the round's key, so that a resumed run
+        holds the encoder the first run held."""
+        @functools.partial(
+            jax.jit, out_shardings=mesh_lib.replicated_sharding(self.mesh))
+        def draw_frozen(key):
+            flat = flatten_dict(self._init_variables(key))
+            return {p: flat[p] for p in paths}
+        return draw_frozen(jax.random.fold_in(
+            jax.random.PRNGKey(int(self.cfg.run_seed) % (2 ** 31)), 0xF02E))
 
     def load_best_ckpt(self) -> None:
         path = self.weight_paths()["best_ckpt"]
         self.logger.info(f"Loading best ckpt so far from: {path}")
-        like = self.state.variables
+        like = self.state.trainable_variables
         with tele_spans.get_tracer().span(
                 "ckpt/load_best", args={"bytes": ckpt_lib.tree_bytes(like)}):
             variables = ckpt_lib.load_variables(path, like=like)

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..data.core import Dataset, ViewSpec
+from ..data.core import Dataset, ViewSpec, rows_are_tokens
 from ..parallel import mesh as mesh_lib
 from ..pool import bucket_size
 from ..data.pipeline import (batch_index_lists, iterate_batches,
@@ -110,13 +110,16 @@ def make_prob_stats_step(model, view: ViewSpec) -> Callable:
 
     @jax.jit
     def score_prob_stats(variables, batch):
-        logits = view_forward(model, view, variables, batch)
+        counters = {}
+        logits = view_forward(model, view, variables, batch,
+                              counters=counters)
         with jax.named_scope("score_head"):
             logits32 = logits.astype(jnp.float32)
             probs = jax.nn.softmax(logits32, axis=-1)
             logp = jax.nn.log_softmax(logits32, axis=-1)
             top2, top2_idx = jax.lax.top_k(probs, 2)
             return {
+                **counters,
                 "confidence": top2[:, 0],
                 "margin": top2[:, 0] - top2[:, 1],
                 # -sum p log p via log_softmax; a prob that underflowed
@@ -138,9 +141,10 @@ def make_embed_step(model, view: ViewSpec, with_probs: bool = False
     (margin_clustering_sampler.py:23-45)."""
 
     def step(variables, batch):
+        out = {}
         logits, embedding = view_forward(model, view, variables, batch,
-                                         return_features=True)
-        out = {"embedding": embedding}
+                                         counters=out, return_features=True)
+        out["embedding"] = embedding
         if with_probs:
             with jax.named_scope("score_head"):
                 probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
@@ -477,7 +481,26 @@ def collect_pool(
                 dataset, idxs, batch_size, step_fn, variables, mesh,
                 num_workers, prefetch, keys, host_s2d, dispatch_lock)
         sp.args.update(batches=batches, rows_run=batches * batch_size)
+        if rows_are_tokens(dataset):
+            # Rows of token ids: the pass in tokens, the real rows'.
+            sp.args["tokens"] = n * int(dataset.image_shape[0])
+        # The model's own counts of the pass (``counter/<name>`` of the
+        # step's output) are the span's counters, not scores.
+        for k in [k for k in out if k.startswith(COUNTER_PREFIX)]:
+            sp.args[k[len(COUNTER_PREFIX):]] = int(out.pop(k).sum())
     return out
+
+
+COUNTER_PREFIX = "counter/"
+
+
+def _wanted(out: Dict[str, Any], keys) -> Dict[str, Any]:
+    """The step's outputs a caller asked for by ``keys``, and its counters
+    whatever was asked for."""
+    if keys is None:
+        return out
+    return {k: v for k, v in out.items()
+            if k in keys or k.startswith(COUNTER_PREFIX)}
 
 
 # Bulk-fetch cadence of the streaming path AND the heartbeat-tick
@@ -516,8 +539,7 @@ def _collect_resident(dataset, idxs, batch_size, step_fn, variables, mesh,
             small = mesh_lib.replicate((ids.astype(np.int32), mask), mesh)
             out = run(variables, images_dev, *small)
             dispatch_lock.drain(out)
-        if keys is not None:
-            out = {k: out[k] for k in keys}
+        out = _wanted(out, keys)
         for k, v in out.items():
             # Keep DEVICE arrays: a per-batch np.asarray would block on
             # each batch and stall async dispatch (the host path hides
@@ -596,8 +618,7 @@ def _collect_stream(dataset, idxs, batch_size, step_fn, variables, mesh,
         with dispatch_lock:
             out = step_fn(variables, sharded)
             dispatch_lock.drain(out)
-        if keys is not None:
-            out = {k: out[k] for k in keys}
+        out = _wanted(out, keys)
         for k, v in out.items():
             # Multi-host: keep device arrays and cross-host-gather ONCE
             # after the loop — a per-batch gather would serialize a DCN

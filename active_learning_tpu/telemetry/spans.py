@@ -66,7 +66,7 @@ class Span:
     given there, else inherited from the parent."""
 
     __slots__ = ("name", "args", "t0", "t1", "tid", "id", "parent",
-                 "round")
+                 "round", "event")
 
     def __init__(self, name: str, args: Optional[Dict[str, Any]] = None,
                  parent: Optional["Span"] = None):
@@ -80,6 +80,7 @@ class Span:
         self.t0 = time.perf_counter()
         self.t1: Optional[float] = None
         self.tid = threading.get_ident()
+        self.event: Optional[Dict[str, Any]] = None   # its record, once closed
 
     @property
     def duration_s(self) -> float:
@@ -236,11 +237,20 @@ class SpanTracer:
         }
         event["args"] = {**(sp.args or {}), "id": sp.id,
                          "parent": sp.parent, "round": sp.round}
+        sp.event = event
         with self._lock:
             if len(self.events) >= self.max_events:
                 self.dropped += 1
             else:
                 self.events.append(event)
+
+    def amend(self, sp: Span, **counters: Any) -> None:
+        """Counters a site learns only after its span closed (a count the
+        device was still computing when the span ended at the enqueue):
+        written into the span's record, before the round's export."""
+        if sp.event is not None:
+            with self._lock:
+                sp.event["args"].update(counters)
 
     # -- export ------------------------------------------------------------
 

@@ -20,6 +20,7 @@ import numpy as np
 
 from ..data.augment import apply_view
 from ..data.core import ViewSpec
+from ..models import backbone
 # The calibration bin count is owned by the host-pure diagnostics layer
 # (telemetry/diagnostics.py) so the device counts here and the host ECE
 # there can never disagree on the ladder.
@@ -67,16 +68,32 @@ def batch_metric_counts(logits: jnp.ndarray, labels: jnp.ndarray,
 _STEP_BUILDERS = ("make_eval_step",)
 
 
-def view_forward(model, view: ViewSpec, variables, batch, **apply_kw):
+def view_forward(model, view: ViewSpec, variables, batch, counters=None,
+                 **apply_kw):
     """The front every forward-only step shares (evaluation and the
-    scoring steps of strategies/scoring.py): the eval view, then the
-    forward pass, each under its named scope (``view``, ``forward``) so a
-    device trace attributes the step's operations to them.  Scopes are
-    metadata; the compiled program is the same without them."""
+    scoring steps of strategies/scoring.py): the eval view (the dataset's,
+    then the backbone's own input stage), then the forward pass, each
+    under its named scope (``view``, ``forward``) so a device trace
+    attributes the step's operations to them.  Scopes are metadata; the
+    compiled program is the same without them.  A step that hands in a
+    ``counters`` dict gets the model's ``row_counters`` of this batch put
+    into it as ``counter/<name>`` (models/backbone.py)."""
     with jax.named_scope("view"):
         x = apply_view(batch["image"], view, train=False)
+        x = backbone.input_stage(model, variables, x)
     with jax.named_scope("forward"):
-        return model.apply(variables, x, train=False, **apply_kw)
+        names = tuple(getattr(model, "row_counters", ()))
+        if counters is None or not names:
+            return model.apply(variables, x, train=False, **apply_kw)
+        out, mutated = model.apply(variables, x, train=False,
+                                   mutable=["counters"], **apply_kw)
+        # The step's counts, as [batch] vectors with the count in row 0:
+        # they travel with the scores and add up over a pass.
+        for name, total in backbone.sum_counters(
+                mutated["counters"], names, batch["mask"]).items():
+            counters[f"counter/{name}"] = jnp.zeros(
+                batch["mask"].shape, jnp.int32).at[0].set(total)
+        return out
 
 
 def make_eval_step(model, view: ViewSpec, num_classes: int):

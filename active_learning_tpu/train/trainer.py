@@ -40,7 +40,8 @@ from ..data.augment import apply_view
 from ..faults import preempt as preempt_lib
 from ..telemetry import runtime as tele_runtime
 from ..telemetry import spans as tele_spans
-from ..data.core import Dataset
+from ..data.core import Dataset, rows_are_tokens
+from ..models import backbone
 from ..data.pipeline import (batch_index_lists, iterate_batches,
                              num_batches, padded_batch_layout,
                              train_feed_batches)
@@ -84,14 +85,73 @@ _DONATES = {"_train_step": (0,),
 
 
 class TrainState(struct.PyTreeNode):
+    """``params`` are the leaves a fit moves; ``frozen`` the leaves it
+    never does (models/backbone.py): the optimizer state, the gradients,
+    every snapshot and every checkpoint file are shaped like ``params``.
+    ``counters``: the model's ``row_counters`` summed over the fit's real
+    rows so far, on the device."""
     params: Any
     batch_stats: Any
     opt_state: Any
     step: jnp.ndarray
+    frozen: Any = struct.field(default_factory=dict)
+    counters: Any = struct.field(default_factory=dict)
 
     @property
     def variables(self) -> Dict[str, Any]:
+        """What ``model.apply`` reads: the trainable leaves over the
+        frozen ones (a new dict over the same arrays)."""
+        return {"params": backbone.merge_params(self.params, self.frozen),
+                "batch_stats": self.batch_stats}
+
+    @property
+    def trainable_variables(self) -> Dict[str, Any]:
+        """What a snapshot, a checkpoint file or the best-epoch copy
+        holds: no frozen leaf."""
         return {"params": self.params, "batch_stats": self.batch_stats}
+
+
+def full_variables(trainable: Dict[str, Any], frozen: Any) -> Dict[str, Any]:
+    """``trainable_variables`` (or a checkpoint's tree) -> the tree
+    ``model.apply`` reads."""
+    return {**trainable,
+            "params": backbone.merge_params(trainable["params"], frozen)}
+
+
+def frozen_beside(fn: Callable) -> Callable:
+    """``fn(state, ...) -> (state, ...)`` as ``step(state, frozen, ...)``:
+    the state's frozen leaves in an argument of their own, so that a
+    ``jax.jit`` which donates the state does not donate them.  The
+    program keeps ``fn``'s name."""
+    def step(state, frozen, *args, **kw):
+        out = fn(state.replace(frozen=frozen), *args, **kw)
+        return (out[0].replace(frozen={}),) + tuple(out[1:])
+    step.__name__ = step.__qualname__ = fn.__name__
+    return step
+
+
+class StateStep:
+    """A jitted ``frozen_beside`` step behind the signature it had before
+    the split, ``step(state, ...)``: the state is donated and its frozen
+    leaves are not, so the arrays a strategy loaded once are the arrays
+    every later state holds.  Called, lowered and counted like the
+    ``jax.jit`` object it wraps."""
+
+    def __init__(self, jitted):
+        self.jitted = jitted
+        self.__name__ = getattr(jitted, "__name__", "step")
+
+    def __call__(self, state, *args, **kw):
+        frozen = state.frozen
+        out = self.jitted(state.replace(frozen={}), frozen, *args, **kw)
+        return (out[0].replace(frozen=frozen),) + tuple(out[1:])
+
+    def lower(self, state, *args, **kw):
+        return self.jitted.lower(state.replace(frozen={}), state.frozen,
+                                 *args, **kw)
+
+    def _cache_size(self) -> int:
+        return self.jitted._cache_size()
 
 
 @dataclasses.dataclass
@@ -289,9 +349,14 @@ class Trainer:
         leave the MXU idle at small batches.  128 when the dataset (and
         so the row shape) is unknown."""
         bs = self.cfg.loader_te.batch_size
+        shape = getattr(dataset, "image_shape", None)
+        if shape and rows_are_tokens(dataset):
+            # A row of T token ids is T positions of work and of
+            # activations: the loader's batch is already a step of
+            # batch x T tokens, and no floor is put under it.
+            return bs
         if self.mesh.devices.flat[0].platform != "cpu":
             floor = 128
-            shape = getattr(dataset, "image_shape", None)
             if shape:
                 floor = 512 if shape[0] <= 64 else 256
             bs = max(bs, floor * self.n_devices)
@@ -307,15 +372,28 @@ class Trainer:
                                     train=False)
         return self.state_of(mesh_lib.replicate(variables, self.mesh))
 
-    def state_of(self, variables) -> TrainState:
+    def state_of(self, variables, frozen=None) -> TrainState:
         """A TrainState with a fresh optimizer state around variables
         that are already replicated on the mesh (the re-initialisation
-        program's own outputs, Strategy.init_network_weights)."""
-        opt_state = mesh_lib.replicate(self._opt_init(variables["params"]),
-                                       self.mesh)
-        return TrainState(params=variables["params"],
+        program's own outputs, Strategy.init_network_weights).  The
+        frozen leaves come beside them (``frozen``: the strategy's, loaded
+        once) or, from a whole ``model.init`` tree, are split off here."""
+        params = variables["params"]
+        if frozen is None:
+            params, frozen = backbone.split_params(
+                params, backbone.frozen_prefixes(self.model))
+        opt_state = mesh_lib.replicate(self._opt_init(params), self.mesh)
+        return TrainState(params=params,
                           batch_stats=variables.get("batch_stats", {}),
-                          opt_state=opt_state, step=jnp.zeros((), jnp.int32))
+                          opt_state=opt_state, step=jnp.zeros((), jnp.int32),
+                          frozen=frozen, counters=self._zero_counters())
+
+    def _zero_counters(self) -> Dict[str, Any]:
+        names = tuple(getattr(self.model, "row_counters", ()))
+        if not names:
+            return {}
+        return mesh_lib.replicate(
+            {n: np.zeros((), np.int32) for n in names}, self.mesh)
 
     @staticmethod
     def _opt_state_live(opt_state) -> bool:
@@ -358,16 +436,26 @@ class Trainer:
                 tele_runtime.get_run().register_jit(
                     f"reinit_opt@{id(self):x}", self._reinit_opt)
             return state.replace(opt_state=self._reinit_opt(state.opt_state),
-                                 step=jnp.zeros((), jnp.int32))
+                                 step=jnp.zeros((), jnp.int32),
+                                 counters=self._zero_counters())
         opt_state = mesh_lib.replicate(self._opt_init(state.params),
                                        self.mesh)
         return state.replace(opt_state=opt_state,
-                             step=jnp.zeros((), jnp.int32))
+                             step=jnp.zeros((), jnp.int32),
+                             counters=self._zero_counters())
 
     def replace_variables(self, state: TrainState, variables) -> TrainState:
-        variables = mesh_lib.replicate(variables, self.mesh)
+        """``variables`` shaped like ``state.trainable_variables`` (a
+        checkpoint file, a snapshot): the frozen leaves stay the arrays
+        they are.  A tree that still holds frozen keys (a checkpoint of
+        before the split) gives them up here."""
+        params, _ = backbone.split_params(variables["params"],
+                                          tuple(state.frozen))
+        variables = mesh_lib.replicate(
+            {"params": params,
+             "batch_stats": variables.get("batch_stats", {})}, self.mesh)
         return state.replace(params=variables["params"],
-                             batch_stats=variables.get("batch_stats", {}))
+                             batch_stats=variables["batch_stats"])
 
     # -- jitted steps ----------------------------------------------------
 
@@ -390,20 +478,14 @@ class Trainer:
         train_bn = self.train_bn
         apply_optimizer = self._apply_optimizer
 
-        def loss_fn(params, batch_stats, x, labels, weights):
-            variables = {"params": params, "batch_stats": batch_stats}
-            if train_bn:
-                logits, mutated = model.apply(
-                    variables, x, train=True, mutable=["batch_stats"])
-                new_stats = mutated["batch_stats"]
-            else:
-                logits = model.apply(variables, x, train=False)
-                new_stats = batch_stats
-            loss = weighted_cross_entropy(logits, labels, weights)
-            return loss, new_stats
+        forward = self._counted_forward(model, train_bn)
 
-        @functools.partial(jax.jit, static_argnames=("view",),
-                           donate_argnums=(0,))
+        def loss_fn(params, frozen, batch_stats, x, labels, weights, mask):
+            logits, new_stats, counts = forward(params, frozen, batch_stats,
+                                                x, mask)
+            loss = weighted_cross_entropy(logits, labels, weights)
+            return loss, (new_stats, counts)
+
         def train_step(state, batch, key, lr, class_weights, view):
             # The named scopes (view / forward_backward / optimizer) are
             # metadata on the operations: a device trace attributes the
@@ -412,9 +494,13 @@ class Trainer:
                 x = apply_view(batch["image"], view, key=key, train=True)
             with jax.named_scope("forward_backward"):
                 weights = class_weights[batch["label"]] * batch["mask"]
-                (loss, new_stats), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(state.params, state.batch_stats,
-                                           x, batch["label"], weights)
+                # Differentiated in ``state.params`` alone: the gradient
+                # tree has no frozen leaf.
+                (loss, (new_stats, counts)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(state.params, state.frozen,
+                                           state.batch_stats, x,
+                                           batch["label"], weights,
+                                           batch["mask"])
             # Telemetry rider: the global gradient norm, computed where
             # the grads already exist (~|params| FLOPs vs the backward
             # pass's billions) and fetched in the SAME deferred bulk
@@ -426,9 +512,41 @@ class Trainer:
                 params, new_opt_state = apply_optimizer(grads, state, lr)
             return state.replace(params=params, batch_stats=new_stats,
                                  opt_state=new_opt_state,
-                                 step=state.step + 1), loss, gnorm
+                                 step=state.step + 1,
+                                 counters=jax.tree.map(
+                                     jnp.add, state.counters, counts)
+                                 ), loss, gnorm
 
-        return train_step
+        return StateStep(jax.jit(frozen_beside(train_step),
+                                 static_argnames=("view",),
+                                 donate_argnums=(0,)))
+
+    @staticmethod
+    def _counted_forward(model, train_bn: bool):
+        """``(params, frozen, batch_stats, x, mask) -> (logits, new
+        batch_stats, counts)``: the one forward of every train step.  The
+        model's own input stage runs under the ``view`` scope; its
+        ``row_counters`` come back summed over the batch's real rows."""
+        names = tuple(getattr(model, "row_counters", ()))
+
+        def forward(params, frozen, batch_stats, x, mask):
+            variables = {"params": backbone.merge_params(params, frozen),
+                         "batch_stats": batch_stats}
+            with jax.named_scope("view"):
+                x = backbone.input_stage(model, variables, x)
+            mutable = (["batch_stats"] if train_bn else []) + (
+                ["counters"] if names else [])
+            if mutable:
+                logits, mutated = model.apply(variables, x, train=train_bn,
+                                              mutable=mutable)
+            else:
+                logits, mutated = model.apply(variables, x, train=False), {}
+            new_stats = mutated["batch_stats"] if train_bn else batch_stats
+            counts = backbone.sum_counters(mutated.get("counters", {}),
+                                           names, mask)
+            return logits, new_stats, counts
+
+        return forward
 
     def _build_train_step_int8(self):
         """The quantized-gradient-sync train step (DESIGN.md §4): the
@@ -468,15 +586,11 @@ class Trainer:
             self._int8_axis_fallback = True
         from jax import shard_map
 
-        def loss_fn(params, batch_stats, x, labels, weights):
-            variables = {"params": params, "batch_stats": batch_stats}
-            if train_bn:
-                logits, mutated = model.apply(
-                    variables, x, train=True, mutable=["batch_stats"])
-                new_stats = mutated["batch_stats"]
-            else:
-                logits = model.apply(variables, x, train=False)
-                new_stats = batch_stats
+        forward = self._counted_forward(model, train_bn)
+
+        def loss_fn(params, frozen, batch_stats, x, labels, weights, mask):
+            logits, new_stats, counts = forward(params, frozen, batch_stats,
+                                                x, mask)
             # The global weighted CE, written shard-locally: local
             # numerator over the GLOBAL (psum'd) denominator — the
             # per-shard losses SUM to the global loss, so summed local
@@ -486,7 +600,7 @@ class Trainer:
                 logp, labels[:, None].astype(jnp.int32), axis=1)[:, 0]
             denom = jnp.maximum(
                 jax.lax.psum(jnp.sum(weights), axis), 1e-12)
-            return jnp.sum(ce * weights) / denom, new_stats
+            return jnp.sum(ce * weights) / denom, (new_stats, counts)
 
         def body(state, batch, key, lr, class_weights, view):
             # Decorrelate per-shard augmentation draws: each shard sees
@@ -498,9 +612,11 @@ class Trainer:
                                train=True)
             with jax.named_scope("forward_backward"):
                 weights = class_weights[batch["label"]] * batch["mask"]
-                (loss_local, new_stats), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(state.params, state.batch_stats,
-                                           x, batch["label"], weights)
+                (loss_local, (new_stats, counts)), grads = \
+                    jax.value_and_grad(loss_fn, has_aux=True)(
+                        state.params, state.frozen, state.batch_stats, x,
+                        batch["label"], weights, batch["mask"])
+            counts = jax.lax.psum(counts, axis)
             if sync_form == "reduce_scatter":
                 grads = mesh_lib.int8_reduce_scatter(grads, ndev, axis)
             else:
@@ -511,10 +627,11 @@ class Trainer:
                 params, new_opt_state = apply_optimizer(grads, state, lr)
             return state.replace(params=params, batch_stats=new_stats,
                                  opt_state=new_opt_state,
-                                 step=state.step + 1), loss, gnorm
+                                 step=state.step + 1,
+                                 counters=jax.tree.map(
+                                     jnp.add, state.counters, counts)
+                                 ), loss, gnorm
 
-        @functools.partial(jax.jit, static_argnames=("view",),
-                           donate_argnums=(0,))
         def train_step(state, batch, key, lr, class_weights, view):
             sharded = shard_map(
                 functools.partial(body, view=view), mesh=mesh,
@@ -524,7 +641,9 @@ class Trainer:
                 check_vma=False)
             return sharded(state, batch, key, lr, class_weights)
 
-        return train_step
+        return StateStep(jax.jit(frozen_beside(train_step),
+                                 static_argnames=("view",),
+                                 donate_argnums=(0,)))
 
     def _build_chained_train_step(self):
         """The host-batched fit path's step with the per-batch PRNG split
@@ -536,15 +655,16 @@ class Trainer:
         three paths stay bit-identical (tests/test_trainer_parallel.py)."""
         train_step = self._train_step
 
-        @functools.partial(jax.jit, static_argnames=("view",),
-                           donate_argnums=(0, 2))
         def chained(state, batch, key, lr, class_weights, view):
             new_key, sub = jax.random.split(key)
             new_state, loss, gnorm = train_step(state, batch, sub, lr,
                                                 class_weights, view=view)
             return new_state, new_key, loss, gnorm
 
-        return chained
+        # (Behind the frozen leaves at 1, the call's key at 2 is at 3.)
+        return StateStep(jax.jit(frozen_beside(chained),
+                                 static_argnames=("view",),
+                                 donate_argnums=(0, 3)))
 
     def _get_eval_step(self, view):
         if view not in self._eval_steps:
@@ -573,8 +693,6 @@ class Trainer:
         mesh = self.mesh
         from ..parallel import resident as resident_lib
 
-        @functools.partial(jax.jit, static_argnames=("view", "sharded"),
-                           donate_argnums=(0, 5))
         def resident_batch_step(state, images, labels, ids, mask, key,
                                 lr, class_weights, view, sharded=False):
             img, lab = resident_lib.pool_gather(
@@ -586,7 +704,10 @@ class Trainer:
                                                 class_weights, view=view)
             return new_state, new_key, loss, gnorm
 
-        return resident_batch_step
+        # (Behind the frozen leaves at 1, the call's key at 5 is at 6.)
+        return StateStep(jax.jit(frozen_beside(resident_batch_step),
+                                 static_argnames=("view", "sharded"),
+                                 donate_argnums=(0, 6)))
 
     def _build_epoch_scan(self, row_shape: Tuple[int, ...]):
         """One jitted call = one full epoch over device-resident data
@@ -612,12 +733,16 @@ class Trainer:
         mesh = self.mesh
         from ..parallel import resident as resident_lib
 
-        @functools.partial(jax.jit, static_argnames=("view", "sharded"),
-                           donate_argnums=(0,))
         def epoch_scan(state, images, labels, idx_mat, mask_mat, valid,
                        key, lr, class_weights, view, sharded=False):
+            # The frozen leaves are read inside the loop, never carried
+            # by it: a carried leaf is a buffer the loop owns, and these
+            # are not this program's to own.
+            frozen = state.frozen
+
             def body(i, carry):
                 state, key, losses, gnorms = carry
+                state = state.replace(frozen=frozen)
                 new_key, sub = jax.random.split(key)
                 # Row-sharded pool: batch rows assembled from their
                 # owning shards into the SAME batch sharding the
@@ -629,16 +754,20 @@ class Trainer:
                 batch = {"image": img, "label": lab, "mask": mask_mat[i]}
                 state, loss, gnorm = train_step(state, batch, sub, lr,
                                                 class_weights, view=view)
-                return (state, new_key, losses.at[i].set(loss),
-                        gnorms.at[i].set(gnorm))
+                return (state.replace(frozen={}), new_key,
+                        losses.at[i].set(loss), gnorms.at[i].set(gnorm))
 
             # ``valid`` is replicated: every device reads the same count.
             steps_real = jnp.sum(valid > 0).astype(jnp.int32)
             zeros = jnp.zeros(valid.shape, jnp.float32)
-            return jax.lax.fori_loop(0, steps_real, body,
-                                     (state, key, zeros, zeros))
+            out = jax.lax.fori_loop(
+                0, steps_real, body,
+                (state.replace(frozen={}), key, zeros, zeros))
+            return (out[0].replace(frozen=frozen),) + tuple(out[1:])
 
-        return epoch_scan
+        return StateStep(jax.jit(frozen_beside(epoch_scan),
+                                 static_argnames=("view", "sharded"),
+                                 donate_argnums=(0,)))
 
     # Steps (and uploaded rows) are bucketed so the epoch scan compiles
     # once per BUCKET, not once per AL round as the labeled set grows:
@@ -1250,10 +1379,11 @@ class Trainer:
                         ckpt_lib.delete_fit_state(weight_paths["fit_state"])
                         saved = None
                 if saved is not None:
-                    host = jax.tree.map(np.asarray, state.variables)
+                    host = jax.tree.map(np.asarray,
+                                        state.trainable_variables)
                     variables = serialization.from_state_dict(
                         host, saved["variables"])
-                    state = TrainState(
+                    state = state.replace(
                         params=mesh_lib.replicate(variables["params"],
                                                   self.mesh),
                         batch_stats=mesh_lib.replicate(
@@ -1302,6 +1432,7 @@ class Trainer:
             rt = tele_runtime.get_run()
             collect = rt.train_metrics
             n_real = len(labeled_idxs)
+            epoch_counts: List[Tuple[Any, Dict[str, Any]]] = []
 
         epochs_run = 0
         for epoch in range(start_epoch, n_epoch + 1):
@@ -1443,6 +1574,16 @@ class Trainer:
                 # the real steps (the scan's bucket is only its shape).
                 epoch_sp.args.update(steps_real=steps_run,
                                      steps_run=steps_run)
+                if rows_are_tokens(train_set):
+                    # Rows of token ids: what the epoch moved, in tokens.
+                    epoch_sp.args["tokens"] = (
+                        n_real * int(train_set.image_shape[0]))
+                if state.counters:
+                    # Still being computed: fetched with the losses in
+                    # fit/finish and written into this span's record.
+                    # (Copies: the next epoch donates the state.)
+                    epoch_counts.append(
+                        (epoch_sp, jax.tree.map(jnp.copy, state.counters)))
 
             if use_es:
                 # Ends at the fetch of the counts — on the scan path this
@@ -1476,12 +1617,13 @@ class Trainer:
                     # to the periodic checkpoint cadence below and to the
                     # end of the fit — the on-disk best a resume consumes
                     # stays coherent with the fit state saved alongside.
-                    best_variables = jax.tree.map(jnp.copy,
-                                                  state.variables)
+                    best_variables = jax.tree.map(
+                        jnp.copy, state.trainable_variables)
                     best_dirty = True
                     if on_best is not None:
                         try:
-                            on_best(round_idx, epoch, best_variables)
+                            on_best(round_idx, epoch, full_variables(
+                                best_variables, state.frozen))
                         except Exception:  # noqa: BLE001 - best-effort bus
                             self.logger.exception(
                                 "on_best subscriber failed; continuing fit")
@@ -1503,7 +1645,8 @@ class Trainer:
                         self._publish_best(weight_paths, best_variables,
                                            round_idx, best_epoch)
                         best_dirty = False
-                    self._save_current(weight_paths, state.variables)
+                    self._save_current(weight_paths,
+                                       state.trainable_variables)
             if collect:
                 # AFTER validation on purpose: on the epoch-scan path the
                 # eval-accuracy fetch above is the sync that makes the
@@ -1540,11 +1683,11 @@ class Trainer:
                     best_dirty = False
                 with tracer.span("ckpt/save_fit_state", args={
                         "bytes": ckpt_lib.tree_bytes(
-                            (state.variables, state.opt_state))}):
+                            (state.trainable_variables, state.opt_state))}):
                     _CKPT_RETRY.call(
                         ckpt_lib.save_fit_state,
                         weight_paths["fit_state"],
-                        variables=state.variables,
+                        variables=state.trainable_variables,
                         opt_state=state.opt_state, step=state.step,
                         epoch=epoch, round_idx=round_idx,
                         best_perf=best_perf, best_epoch=best_epoch,
@@ -1560,13 +1703,13 @@ class Trainer:
 
         if best_variables is None:
             best_epoch = epochs_run
-            best_variables = state.variables
+            best_variables = state.trainable_variables
             best_dirty = True
         if best_dirty and weight_paths and mesh_lib.is_coordinator():
             self._publish_best(weight_paths, best_variables, round_idx,
                                best_epoch)
         if weight_paths and mesh_lib.is_coordinator():
-            self._save_current(weight_paths, state.variables)
+            self._save_current(weight_paths, state.trainable_variables)
         with tracer.span("fit/finish"):
             if weight_paths and mesh_lib.is_coordinator():
                 # The round completed: a later restart must re-run it from
@@ -1581,6 +1724,14 @@ class Trainer:
                 multihost_utils.sync_global_devices("fit_ckpts_written")
             self.logger.info(
                 f"Sanity Check: Best ckpt occurs on epoch {best_epoch}")
+            seen: Dict[str, int] = {}
+            for sp, counts in epoch_counts:
+                # The state's counters run over the whole fit: an epoch's
+                # own are the difference to the epoch before.
+                total = {k: int(v) for k, v in counts.items()}
+                tracer.amend(sp, **{k: v - seen.get(k, 0)
+                                    for k, v in total.items()})
+                seen = total
             ema_loss = ema_gnorm = None
             for rec in history:
                 # Deferred train-loss fetch (see the epoch loop): one bulk

@@ -45,11 +45,29 @@ def load_torch_state_dict(path: str) -> Dict[str, np.ndarray]:
     Handles the common ``{"state_dict": ...}`` wrapper (MoCo et al.),
     matching load_pretrained_weights.py:24-26."""
     import torch
-    ckpt = torch.load(path, map_location="cpu", weights_only=False)
+    try:
+        # Mapped, not read: a tensor's bytes come off the disk when it is
+        # used (an encoder of gigabytes is uploaded leaf by leaf).
+        ckpt = torch.load(path, map_location="cpu", weights_only=False,
+                          mmap=True)
+    except (RuntimeError, ValueError):     # a pre-zipfile checkpoint
+        ckpt = torch.load(path, map_location="cpu", weights_only=False)
     if isinstance(ckpt, dict) and "state_dict" in ckpt:
         ckpt = ckpt["state_dict"]
-    return {k: np.asarray(v.detach().numpy() if hasattr(v, "detach") else v)
-            for k, v in ckpt.items()}
+    return {k: _host_array(v) for k, v in ckpt.items()}
+
+
+def _host_array(value) -> np.ndarray:
+    """A checkpoint tensor as a host array of its own dtype (bfloat16,
+    which numpy lacks, as ``ml_dtypes.bfloat16`` over the same bytes)."""
+    if not hasattr(value, "detach"):
+        return np.asarray(value)
+    import torch
+    value = value.detach()
+    if value.dtype == torch.bfloat16:
+        import ml_dtypes
+        return value.view(torch.int16).numpy().view(ml_dtypes.bfloat16)
+    return np.asarray(value.numpy())
 
 
 def surgery(
@@ -160,18 +178,23 @@ def _transform(value: np.ndarray, kind: Optional[str]) -> np.ndarray:
 
 def map_torch_state(like: Mapping[FlaxPath, Any],
                     torch_state: Mapping[str, np.ndarray],
-                    strict: bool = True) -> Dict[FlaxPath, np.ndarray]:
+                    strict: bool = True,
+                    key_map=None) -> Dict[FlaxPath, np.ndarray]:
     """Every mappable checkpoint tensor as the model's own leaf: keyed
     by its Flax path, transposed to the Flax layout and cast to the
     dtype of ``like[path]``.  ``like`` is the flattened variable tree;
     only ``.shape`` and ``.dtype`` of its leaves are read, so an abstract
     tree (``jax.eval_shape`` of ``model.init``) serves and no device
     array is fetched.  Shape mismatches always raise; unknown keys raise
-    when ``strict``."""
+    when ``strict``.  ``key_map``: the backbone's own checkpoint layout
+    (``torch_key_to_flax`` of models/backbone.py's contract; default the
+    ResNets').  A transform ``("slot", i)`` puts the tensor at index i
+    of a stacked leaf (one expert of a layer's experts)."""
+    key_map = key_map or torch_key_to_flax
     covered: Dict[FlaxPath, np.ndarray] = {}
     for key, value in torch_state.items():
         try:
-            mapped = torch_key_to_flax(key)
+            mapped = key_map(key)
         except KeyError:
             if strict:
                 raise
@@ -179,11 +202,23 @@ def map_torch_state(like: Mapping[FlaxPath, Any],
         if mapped is None:
             continue
         path, kind = mapped
-        arr = _transform(np.asarray(value), kind)
         if path not in like:
             raise KeyError(
                 f"Checkpoint key '{key}' maps to {'/'.join(path)}, absent "
                 f"from the model (wrong depth/variant?)")
+        if isinstance(kind, tuple):
+            slot, shape = kind[1], tuple(like[path].shape)
+            if slot >= shape[0]:
+                continue               # an expert another chip holds
+            if tuple(value.shape) != shape[1:]:
+                raise ValueError(
+                    f"Shape mismatch for '{key}' -> {'/'.join(path)}"
+                    f"[{slot}]: ckpt {value.shape} vs model {shape[1:]}")
+            if path not in covered:
+                covered[path] = np.empty(shape, like[path].dtype)
+            covered[path][slot] = value
+            continue
+        arr = _transform(np.asarray(value), kind)
         shape = tuple(like[path].shape)
         if (path[-2:] == ("conv_stem", "kernel") and arr.shape[:2] == (7, 7)
                 and shape[:2] == (4, 4)):
@@ -196,7 +231,7 @@ def map_torch_state(like: Mapping[FlaxPath, Any],
             raise ValueError(
                 f"Shape mismatch for '{key}' -> {'/'.join(path)}: "
                 f"ckpt {arr.shape} vs model {shape}")
-        covered[path] = arr.astype(like[path].dtype)
+        covered[path] = arr.astype(like[path].dtype, copy=False)
     get_logger().info(f"Overlaid {len(covered)} pretrained tensors")
     return covered
 
@@ -214,7 +249,7 @@ def overlay_torch_state(variables: Dict[str, Any],
 
 
 def pretrained_leaves(like: Mapping[FlaxPath, Any], cfg: PretrainedConfig,
-                      state: Mapping[str, np.ndarray]
+                      state: Mapping[str, np.ndarray], key_map=None
                       ) -> Dict[FlaxPath, np.ndarray]:
     """Surgery -> mapping: the leaves the checkpoint ``state`` covers
     under ``cfg``'s key filters, as ``map_torch_state`` gives them.
@@ -222,7 +257,7 @@ def pretrained_leaves(like: Mapping[FlaxPath, Any], cfg: PretrainedConfig,
     from these, once per checkpoint file."""
     state = surgery(state, required_key=cfg.required_key,
                     skip_key=cfg.skip_key, replace_map=cfg.replace_map)
-    return map_torch_state(like, state)
+    return map_torch_state(like, state, key_map=key_map)
 
 
 def apply_pretrained(variables: Dict[str, Any], cfg: PretrainedConfig

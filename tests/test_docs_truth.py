@@ -36,9 +36,7 @@ _OLD_STACK = {"bench.py", "bench_cache.json", "bench_evidence_r05.json",
               "mfu_decomposition.json", "scripts/mfu_decomposition.py",
               "scripts/perf_report.py", "tests/test_bench_json.py"}
 GONE = {
-    "PERF.md": {"bench.py", "scripts/mfu_decomposition.py",
-                "scripts/perf_report.py", "tests/test_bench_json.py",
-                "span_run.py"},
+    "PERF.md": {"bench.py"},
     "ROADMAP.md": _OLD_STACK | {"span_run.py", "BENCH_r05.json"},
 }
 _SAYS_GONE = re.compile(
@@ -182,3 +180,37 @@ def test_preflight_parses_and_runs_files_that_exist():
     gates = re.findall(r"== preflight (\d+)/(\d+):", text)
     assert [int(a) for a, _ in gates] == list(range(1, len(gates) + 1))
     assert {int(b) for _, b in gates} == {len(gates)} == {3}
+
+
+# What PR 33 made the documents say, each with a path that exists: the pool
+# row's schema, the backbone contract and the frozen/trainable split.
+_PR33_NAMES = ("active_learning_tpu/models/backbone.py",
+               "active_learning_tpu/data/core.py",
+               "active_learning_tpu/models/mla_moe.py")
+
+
+@pytest.mark.parametrize("doc", ("README.md", "MIGRATION.md", "DESIGN.md"))
+def test_documents_name_the_row_schema_the_contract_and_the_split(doc):
+    with open(os.path.join(REPO, doc)) as fh:
+        text = fh.read()
+    assert "check_rows" in text and "frozen" in text
+    assert "models/backbone.py" in text
+    assert "TrainState.frozen" in text or "TrainState.params" in text \
+        or "frozen by the backbone" in text
+    for path in _PR33_NAMES:
+        assert os.path.isfile(os.path.join(REPO, path))
+
+
+def test_readme_lists_the_models_the_registry_holds():
+    """README's model list is the registry's (read from the source text:
+    no import of jax)."""
+    with open(os.path.join(REPO, "README.md")) as fh:
+        readme = fh.read()
+    names = set()
+    for rel in ("active_learning_tpu/models/factory.py",
+                "active_learning_tpu/models/mla_moe.py"):
+        with open(os.path.join(REPO, rel)) as fh:
+            names |= set(re.findall(r'MODELS\.register\("(\w+)"', fh.read()))
+    assert names >= {"SSLResNet18", "SSLResNet50", "AXK1_TOY",
+                     "AXK1_EP16_L7"}
+    assert all(f"`{n}`" in readme for n in names)
