@@ -718,11 +718,16 @@ def _round_snapshot(strategy) -> dict:
     (round r's query scores with round r-1's best weights — re-running
     the query without restoring them would score with the failed
     attempt's re-initialized model)."""
-    variables = None
+    variables, fetched = None, 0
     if strategy.state is not None:
-        # The trainable leaves: a frozen one cannot have moved.
-        variables = jax.tree.map(np.asarray,
-                                 strategy.state.trainable_variables)
+        # The trainable leaves: a frozen one cannot have moved.  The
+        # host copy the fit fetched for best_ckpt is shared while the
+        # state is still the one it was fetched from; anything that
+        # replaced the state since is fetched here.
+        variables = strategy.host_variables()
+        if variables is None:
+            variables = jax.device_get(strategy.state.trainable_variables)
+            fetched = ckpt_lib.tree_bytes(variables)
     return {
         "pool": strategy.pool.to_arrays(),
         "rng_state": copy.deepcopy(strategy.rng.bit_generator.state),
@@ -730,6 +735,8 @@ def _round_snapshot(strategy) -> dict:
         "best_epoch": int(strategy.best_epoch),
         "resume_next_fit": bool(strategy.resume_next_fit),
         "variables": variables,
+        # Bytes this snapshot moved device -> host (0: the copy is shared).
+        "fetched": fetched,
     }
 
 
@@ -1210,6 +1217,7 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
                     snapshot = _round_snapshot(strategy)
                     sp.args["bytes"] = ckpt_lib.tree_bytes(
                         snapshot["variables"])
+                    sp.args["fetched"] = snapshot["fetched"]
                 for attempt in range(ladder.max_attempts()):
                     try:
                         # The device-truth capture window (DESIGN.md

@@ -28,11 +28,13 @@ Key architectural differences (deliberate, TPU-first):
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 import math
 import os
 import time
-from typing import Callable, Dict, Optional, Tuple
+import weakref
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,7 +52,7 @@ from ..telemetry import diagnostics as diag_lib
 from ..telemetry import runtime as tele_runtime
 from ..telemetry import spans as tele_spans
 from ..train import checkpoint as ckpt_lib
-from ..train.trainer import Trainer, TrainState
+from ..train.trainer import FitResult, Trainer, TrainState
 from ..utils.logging import get_logger
 from ..utils.metrics import MetricsSink, NullSink
 from . import scoring
@@ -73,6 +75,26 @@ def _abstract_bytes(like: Dict) -> int:
     """Bytes of a flat tree of (abstract) leaves, from shapes."""
     return sum(math.prod(leaf.shape) * leaf.dtype.itemsize
                for leaf in like.values())
+
+
+@dataclasses.dataclass
+class KeptBest:
+    """What this process's last fit left of its best epoch, beside the
+    file: the tree on the device until ``load_best_ckpt`` installs it,
+    and the one host copy ``ckpt/publish_best`` fetched.  ``state`` names
+    the TrainState they belong to — the fit's result, then (``installed``)
+    the state ``load_best_ckpt`` made of it, whose trainable leaves
+    ``host`` then describes bit for bit — WEAKLY: the record must not
+    keep a state's device arrays alive once the strategy has let go of
+    it (the next re-initialisation)."""
+    tag: Tuple[int, int]                  # (round, best_epoch)
+    variables: Optional[Dict[str, Any]]   # None: the best is in the file
+    host: Optional[Dict[str, Any]]        # None: this process wrote none
+    state: "weakref.ReferenceType[TrainState]"
+    installed: bool = False
+
+    def belongs_to(self, state: Optional[TrainState]) -> bool:
+        return state is not None and self.state() is state
 
 
 class Strategy:
@@ -113,6 +135,9 @@ class Strategy:
         self.state: Optional[TrainState] = None
         self.best_epoch: int = 0
         self.best_perf: float = 0.0
+        # The last fit's best weights where this process still holds
+        # them (``_keep_fit``); None after a resume or a restart.
+        self.kept_best: Optional[KeptBest] = None
         # The last test() accuracy — the driver's run_report rows read
         # it (test() already computes it; storing beats re-plumbing the
         # return through the round loop).
@@ -349,15 +374,60 @@ class Strategy:
         return draw_frozen(jax.random.fold_in(
             jax.random.PRNGKey(int(self.cfg.run_seed) % (2 ** 31)), 0xF02E))
 
+    def _keep_fit(self, result: FitResult) -> None:
+        """The fit's outcome: its final state, and its best weights kept
+        under the tag ``best_ckpt`` was published with."""
+        self.state = result.state
+        self.best_epoch = result.best_epoch
+        self.kept_best = KeptBest(
+            tag=(self.round, result.best_epoch),
+            variables=result.best_variables, host=result.best_host,
+            state=weakref.ref(result.state))
+
     def load_best_ckpt(self) -> None:
+        """The state the round goes on with holds the fit's best epoch.
+        Where this process's own fit of THIS round left that tree on the
+        device and nothing has replaced the state since, it is installed
+        as it is: the bits ``best_ckpt`` was written from, no file read
+        and no transfer.  Otherwise (experiment resume, a mid-round
+        resume whose best epoch ran in an earlier process, a service
+        restart, a state set from outside) the file is read."""
         path = self.weight_paths()["best_ckpt"]
-        self.logger.info(f"Loading best ckpt so far from: {path}")
+        kept = self.kept_best
+        on_device = (kept is not None and kept.variables is not None
+                     and kept.tag == (self.round, self.best_epoch)
+                     and kept.belongs_to(self.state))
+        self.logger.info(
+            "Installing the fit's best weights (on the device)"
+            if on_device else f"Loading best ckpt so far from: {path}")
         like = self.state.trainable_variables
         with tele_spans.get_tracer().span(
-                "ckpt/load_best", args={"bytes": ckpt_lib.tree_bytes(like)}):
-            variables = ckpt_lib.load_variables(path, like=like)
-            self.state = self.trainer.replace_variables(self.state,
-                                                        variables)
+                "ckpt/load_best",
+                args={"bytes": ckpt_lib.tree_bytes(like),
+                      "source": "device" if on_device else "file"}):
+            if on_device:
+                self.state = self.state.replace(
+                    params=kept.variables["params"],
+                    batch_stats=kept.variables["batch_stats"])
+                # The state holds them now: no second reference keeps
+                # them alive past the next re-initialisation.
+                kept.variables, kept.state = None, weakref.ref(self.state)
+                kept.installed = True
+            else:
+                self.kept_best = None
+                variables = ckpt_lib.load_variables(path, like=like)
+                self.state = self.trainer.replace_variables(self.state,
+                                                            variables)
+
+    def host_variables(self) -> Optional[Dict[str, Any]]:
+        """The host copy of ``state.trainable_variables`` where one
+        already exists (``ckpt/publish_best`` fetched it and
+        ``load_best_ckpt`` installed the tree it was fetched from); None
+        once anything has replaced the state."""
+        kept = self.kept_best
+        if kept is not None and kept.installed and kept.belongs_to(self.state):
+            return kept.host
+        return None
 
     # -- auxiliary round-level state (resume seam) ------------------------
 
@@ -454,8 +524,7 @@ class Strategy:
             # scorer keeps working from the final one through
             # load_best_ckpt/test until the next query consumes it.
             self.pipeline.finalize(self.round, result.best_epoch)
-        self.state = result.state
-        self.best_epoch = result.best_epoch
+        self._keep_fit(result)
         # The fit's best validation accuracy: collapse detectors (e.g.
         # the evidence protocol's re-init guard,
         # scripts/cifar10_evidence.py) read it to tell a dead round —

@@ -283,8 +283,7 @@ class VAALSampler(Strategy):
             es_patience=self.cfg.early_stop_patience, rng=self.rng,
             round_idx=self.round, weight_paths=self.weight_paths(),
             metric_cb=metric_cb, batch_hook=batch_hook)
-        self.state = result.state
-        self.best_epoch = result.best_epoch
+        self._keep_fit(result)
         self.logger.info(f"Finished training on round {self.round}")
 
     # -- acquisition ------------------------------------------------------

@@ -44,17 +44,31 @@ def tree_bytes(tree: Any) -> int:
                    for leaf in jax.tree.leaves(tree)))
 
 
-def save_variables(path: str, variables: Dict[str, Any]) -> None:
+def serialize(tree: Any) -> bytes:
+    """The bytes of a checkpoint file: msgpack of the tree's host copy
+    (``jax.device_get``: every leaf's transfer is started before the
+    first is waited for; a tree already on the host passes through).
+    Two files that hold the same tree may be written from one
+    serialisation."""
+    return serialization.msgpack_serialize(jax.device_get(tree))
+
+
+def write_bytes(path: str, data: bytes) -> None:
     """Atomic write (tmp + rename): a reader never sees a half-written
     checkpoint — mid-round resume (experiment/resume.py) and non-writer
-    pod processes both read these files."""
+    pod processes both read these files.  Every file passes its own
+    ``ckpt_write`` fault site."""
     faults.site("ckpt_write")
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    host_vars = jax.tree.map(np.asarray, variables)
     tmp = f"{path}.tmp"
     with open(tmp, "wb") as fh:
-        fh.write(serialization.msgpack_serialize(host_vars))
+        fh.write(data)
     os.replace(tmp, path)
+
+
+def save_variables(path: str, variables: Dict[str, Any]) -> None:
+    """Fetch, serialise, write atomically."""
+    write_bytes(path, serialize(variables))
 
 
 def load_variables(path: str, like: Dict[str, Any] = None) -> Dict[str, Any]:
@@ -134,7 +148,15 @@ def publish_best(path: str, variables: Dict[str, Any], *, round_idx: int,
                  epoch: int) -> None:
     """Atomically publish a best checkpoint plus its monotonic
     (round, epoch) tag — the writer side of the best-ckpt bus."""
-    save_variables(path, variables)
+    publish_best_bytes(path, serialize(variables), round_idx=round_idx,
+                       epoch=epoch)
+
+
+def publish_best_bytes(path: str, data: bytes, *, round_idx: int,
+                       epoch: int) -> None:
+    """``publish_best`` of a tree already serialised (``serialize``): a
+    retried publish writes the same bytes again, weights THEN tag."""
+    write_bytes(path, data)
     # Torn point between the pair's two renames: a crash here leaves
     # weights WITHOUT their tag — exactly the partial publish the
     # watcher's legacy/tag-mismatch rules must absorb (chaos-tested via

@@ -26,7 +26,8 @@ import functools
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -156,11 +157,28 @@ class StateStep:
 
 @dataclasses.dataclass
 class FitResult:
+    """``best_variables``: the best epoch's trainable tree where THIS fit
+    computed it, on the device (the final state's own leaves when the
+    best is the last epoch without validation, else the copy taken at
+    the best epoch); None when it is only in ``best_ckpt`` (a mid-round
+    resume whose best epoch ran in an earlier process).  ``best_host``:
+    the one host copy of it, fetched for ``best_ckpt``; None when this
+    process wrote no file."""
     state: TrainState
     best_epoch: int
     best_perf: float
     epochs_run: int
     history: List[Dict[str, float]]
+    best_variables: Optional[Dict[str, Any]] = None
+    best_host: Optional[Dict[str, Any]] = None
+
+
+class _Published(NamedTuple):
+    """What ``ckpt/publish_best`` fetched and wrote: the best epoch, its
+    host tree and the file's bytes."""
+    epoch: int
+    host: Dict[str, Any]
+    data: bytes
 
 
 def weighted_cross_entropy(logits, labels, sample_weights):
@@ -1192,27 +1210,36 @@ class Trainer:
     # -- the fit loop ----------------------------------------------------
 
     def _publish_best(self, weight_paths: Dict[str, str], variables,
-                      round_idx: int, epoch: int) -> None:
-        """``ckpt/publish_best``: the device->host fetch AND the atomic
-        write + monotonic (round, epoch) tag, as one span that ends at
-        the rename."""
+                      round_idx: int, epoch: int) -> _Published:
+        """``ckpt/publish_best``: the device->host fetch (ONE a best:
+        every leaf's transfer is started before the first is waited for)
+        AND the atomic write + monotonic (round, epoch) tag, as one span
+        that ends at the rename.  Returns what it fetched and wrote, for
+        the consumers that need the same bytes."""
         with tele_spans.get_tracer().span(
                 "ckpt/publish_best",
                 args={"bytes": ckpt_lib.tree_bytes(variables)}):
-            _CKPT_RETRY.call(ckpt_lib.publish_best,
-                             weight_paths["best_ckpt"],
-                             jax.tree.map(np.asarray, variables),
+            host = jax.device_get(variables)
+            data = ckpt_lib.serialize(host)
+            _CKPT_RETRY.call(ckpt_lib.publish_best_bytes,
+                             weight_paths["best_ckpt"], data,
                              round_idx=round_idx, epoch=epoch)
+        return _Published(epoch, host, data)
 
-    def _save_current(self, weight_paths: Dict[str, str], variables
-                      ) -> None:
-        """``ckpt/save_current``: fetch + write, ends at the rename."""
+    def _save_current(self, weight_paths: Dict[str, str], variables,
+                      data: Optional[bytes] = None) -> None:
+        """``ckpt/save_current``: fetch + write, ends at the rename.
+        ``data``: the serialisation ``ckpt/publish_best`` made of the
+        same state (the best IS the current one); ``shared`` says it was
+        written in place of a fetch and a serialisation of its own."""
         with tele_spans.get_tracer().span(
                 "ckpt/save_current",
-                args={"bytes": ckpt_lib.tree_bytes(variables)}):
-            _CKPT_RETRY.call(ckpt_lib.save_variables,
-                             weight_paths["current_ckpt"],
-                             jax.tree.map(np.asarray, variables))
+                args={"bytes": ckpt_lib.tree_bytes(variables),
+                      "shared": data is not None}):
+            if data is None:
+                data = ckpt_lib.serialize(variables)
+            _CKPT_RETRY.call(ckpt_lib.write_bytes,
+                             weight_paths["current_ckpt"], data)
 
     def fit(
         self,
@@ -1329,6 +1356,8 @@ class Trainer:
             best_perf, best_epoch, es_count = 0.0, 0, 0
             best_variables = None  # device tree after an improvement this fit
             best_dirty = False  # True = best_variables newer than best_ckpt
+            best_resumed = False  # True = best_variables read from best_ckpt
+            published = None  # the newest ckpt/publish_best of this fit
             history: List[Dict[str, float]] = []
             key = jax.random.PRNGKey(int(rng.integers(0, 2 ** 31 - 1)))
 
@@ -1413,6 +1442,7 @@ class Trainer:
                         if have_best:
                             best_variables = ckpt_lib.load_variables(
                                 weight_paths["best_ckpt"], like=host)
+                            best_resumed = True
                         else:
                             # The weights best_perf refers to are gone; keeping
                             # the stale score would make the no-improvement
@@ -1619,7 +1649,7 @@ class Trainer:
                     # stays coherent with the fit state saved alongside.
                     best_variables = jax.tree.map(
                         jnp.copy, state.trainable_variables)
-                    best_dirty = True
+                    best_dirty, best_resumed = True, False
                     if on_best is not None:
                         try:
                             on_best(round_idx, epoch, full_variables(
@@ -1642,8 +1672,9 @@ class Trainer:
                         # publish_best = atomic write + monotonic
                         # (round, best_epoch) tag for the concurrent
                         # readers (serve hot-reload, speculative scorer).
-                        self._publish_best(weight_paths, best_variables,
-                                           round_idx, best_epoch)
+                        published = self._publish_best(
+                            weight_paths, best_variables, round_idx,
+                            best_epoch)
                         best_dirty = False
                     self._save_current(weight_paths,
                                        state.trainable_variables)
@@ -1678,8 +1709,8 @@ class Trainer:
                     # best_epoch; without this publish the resumed fit
                     # would find best_ckpt missing and restart best-model
                     # tracking — diverging from the uninterrupted run.
-                    self._publish_best(weight_paths, best_variables,
-                                       round_idx, best_epoch)
+                    published = self._publish_best(
+                        weight_paths, best_variables, round_idx, best_epoch)
                     best_dirty = False
                 with tracer.span("ckpt/save_fit_state", args={
                         "bytes": ckpt_lib.tree_bytes(
@@ -1706,10 +1737,14 @@ class Trainer:
             best_variables = state.trainable_variables
             best_dirty = True
         if best_dirty and weight_paths and mesh_lib.is_coordinator():
-            self._publish_best(weight_paths, best_variables, round_idx,
-                               best_epoch)
+            published = self._publish_best(weight_paths, best_variables,
+                                           round_idx, best_epoch)
         if weight_paths and mesh_lib.is_coordinator():
-            self._save_current(weight_paths, state.trainable_variables)
+            # The best IS the final state (always so without validation):
+            # rd_{n} holds best_rd_{n}'s bytes, serialised once.
+            same = published is not None and best_epoch == epochs_run
+            self._save_current(weight_paths, state.trainable_variables,
+                               data=published.data if same else None)
         with tracer.span("fit/finish"):
             if weight_paths and mesh_lib.is_coordinator():
                 # The round completed: a later restart must re-run it from
@@ -1753,6 +1788,8 @@ class Trainer:
                               tele_step)
                     metric_cb("grad_norm_ema", round(ema_gnorm, 6),
                               tele_step)
-        return FitResult(state=state, best_epoch=best_epoch,
-                         best_perf=best_perf, epochs_run=epochs_run,
-                         history=history)
+        return FitResult(
+            state=state, best_epoch=best_epoch, best_perf=best_perf,
+            epochs_run=epochs_run, history=history,
+            best_variables=None if best_resumed else best_variables,
+            best_host=published.host if published is not None else None)

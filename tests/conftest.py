@@ -40,6 +40,16 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture(autouse=True)
+def _no_leaked_preemption():
+    """A recorded SIGTERM is process state (``faults/preempt.py``): a test
+    that stops its service that way and ends must not preempt the fit of
+    whichever test the worker runs next."""
+    yield
+    from active_learning_tpu.faults import preempt as preempt_lib
+    preempt_lib.reset()
+
+
 @pytest.fixture
 def collective_lock(tmp_path_factory):
     """At most one of the eight-device CPU collective tests at a time,

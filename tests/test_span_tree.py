@@ -152,7 +152,8 @@ TABLE = {
 
 
 @pytest.fixture(scope="module")
-def toy_run(tmp_path_factory):
+def toy_dir(tmp_path_factory):
+    """The toy run's directory: logs, trace and checkpoints."""
     import torch
     from active_learning_tpu.experiment.driver import run_experiment
     tmp = str(tmp_path_factory.mktemp("span_tree"))
@@ -174,7 +175,12 @@ def toy_run(tmp_path_factory):
         telemetry=TelemetryConfig(enabled=True, export_trace=True))
     run_experiment(cfg, data=data, train_cfg=train_cfg,
                    model=TinyClassifier(num_classes=4))
-    with open(os.path.join(tmp, "trace.json")) as fh:
+    return tmp
+
+
+@pytest.fixture(scope="module")
+def toy_run(toy_dir):
+    with open(os.path.join(toy_dir, "trace.json")) as fh:
         return json.load(fh)
 
 
@@ -243,6 +249,35 @@ class TestSpanSites:
         assert first["args"]["recompiled"]
         last = [e for e in events if e["name"] == "round_epilogue"][-1]
         assert "recompiled" not in last["args"]
+
+    def test_best_handoff_counters(self, toy_run, toy_dir):
+        """The three counters that say the best weights stayed where
+        they were: ``load_best`` installed the device tree, the snapshot
+        moved nothing, and the end-of-fit current file was written from
+        the best file's serialisation exactly when the best is the last
+        epoch (the periodic saves serialise on their own)."""
+        from active_learning_tpu.train import checkpoint as ckpt_lib
+        events = _spans(toy_run["traceEvents"])
+
+        def of(name, rd):
+            return [e["args"] for e in events
+                    if e["name"] == name and e["args"]["round"] == rd]
+        for rd in (0, 1, 2):
+            assert [a["source"] for a in of("ckpt/load_best", rd)] == [
+                "device"]
+            snap, = of("ckpt/round_snapshot", rd)
+            # Round 0 has no model yet; from round 1 on the host copy
+            # ckpt/publish_best fetched is the snapshot's.
+            assert snap["fetched"] == 0
+            assert (snap["bytes"] > 0) == (rd > 0)
+            paths = ckpt_lib.weight_paths(
+                toy_dir, ExperimentConfig.exp_name, "spantree", rd)
+            best, cur = paths["best_ckpt"], paths["current_ckpt"]
+            best_is_last = ckpt_lib.read_best_tag(best)[1] == 2
+            assert [a["shared"] for a in of("ckpt/save_current", rd)] == [
+                False, False, best_is_last]
+            with open(best, "rb") as a, open(cur, "rb") as b:
+                assert (a.read() == b.read()) == best_is_last
 
     def test_round_subtree_self_times_add_up(self, toy_run):
         events = _spans(toy_run["traceEvents"])
