@@ -1,7 +1,9 @@
 """The backbone contract: what the trainer, the strategies and the
 checkpoint path ask of a model, whatever its family.
 
-A backbone is a ``flax.linen.Module`` with
+Implementers: the ResNets (models/resnet.py), the MLA + sparse-experts token
+encoder (models/mla_moe.py) and the shortcut-connected MoE token encoder
+(models/shortcut_moe.py).  A backbone is a ``flax.linen.Module`` with
 
   ``apply(variables, x, train=..., return_features=False)``
       -> logits, or ``(logits, embedding)``; ``x`` is a batch of rows as the
@@ -15,19 +17,24 @@ A backbone is a ``flax.linen.Module`` with
       it has not: ``TrainState.batch_stats`` is then ``{}``.
   ``frozen_prefixes`` (optional)
       the top-level keys of ``params`` a fit never moves: the token
-      encoder, which is built under ``freeze_feature`` only (its factory
-      refuses otherwise), declares ``("encoder",)``.  A model that
+      encoders (models/mla_moe.py, models/shortcut_moe.py), which are built
+      under ``freeze_feature`` only (their factory refuses otherwise),
+      declare ``("encoder",)``.  A model that
       declares nothing keeps every leaf in ``params`` (the ResNets,
       whose frozen leaves get a zero gradient as they always did: they
       opt in with one line once nothing reads their encoder out of
       ``state.params`` any more, ROADMAP R2).
   ``input_stage(x)`` (optional method)
       the model's own part of the view, applied under the ``view`` scope in
-      front of the forward: the token encoder's embedding lookup.
+      front of the forward: a token encoder's embedding lookup.
   ``row_counters`` (optional)
       names of the per-row counts the forward sows into the ``counters``
       collection (``[batch]`` int32 each): they ride the scoring pass's and
-      the epoch's span as counters.
+      the epoch's span as counters.  The token encoders sow ``pairs_real``
+      and ``pairs_run`` (held (token, expert) pairs chosen, and the pairs
+      the tiles were shaped for); the shortcut-connected one also
+      ``pairs_zero`` (picks that fell on a zero-compute expert) and
+      ``pairs_routed`` (all picks: k x tokens x layers).
   ``torch_key_to_flax(key)`` (optional)
       the checkpoint layout: a torch state-dict key -> (flax path, transform)
       as ``utils/pretrained.torch_key_to_flax`` maps the ResNets'.

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from ..registry import MODELS
 from . import mla_moe as _mla_moe  # noqa: F401  (registers its presets)
+from . import shortcut_moe as _shortcut_moe  # noqa: F401  (likewise)
 from .resnet import resnet18, resnet50
 
 MODELS.register("SSLResNet18", resnet18)

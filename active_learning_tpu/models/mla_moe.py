@@ -85,6 +85,10 @@ class MlaMoeConfig:
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 1.0
     expert_tile: int = 512
+    # Factors on the two low-rank paths of the attention (models/
+    # shortcut_moe.py states them; 1 here, as published).
+    mla_q_scale: float = 1.0
+    mla_kv_scale: float = 1.0
 
     @property
     def qk_head_dim(self) -> int:
@@ -240,11 +244,15 @@ class _Attention(_Part):
         q = _dot(c_q, self.weight("q_b_proj", heads * cfg.qk_head_dim,
                                   cfg.q_lora_rank), dtype
                  ).reshape(b, t, heads, cfg.qk_head_dim)
+        if cfg.mla_q_scale != 1.0:
+            q = q * cfg.mla_q_scale
         kv_a = _dot(x, self.weight("kv_a_proj_with_mqa",
                                    cfg.kv_lora_rank + rope, d), dtype)
         c_kv = rms_norm(kv_a[..., :cfg.kv_lora_rank],
                         self.scale("kv_a_layernorm", cfg.kv_lora_rank),
                         cfg.rms_norm_eps)
+        if cfg.mla_kv_scale != 1.0:
+            c_kv = c_kv * cfg.mla_kv_scale
         kv = _dot(c_kv, self.weight("kv_b_proj", heads * (nope + vd),
                                     cfg.kv_lora_rank), dtype
                   ).reshape(b, t, heads, nope + vd)
@@ -277,6 +285,60 @@ class _DenseFFN(_Part):
                        self.weight("down_proj", d, f), self.dtype)
 
 
+def held_gates(idx, gate, held_first: int, held: int):
+    """This chip's part of a routing: ``idx`` / ``gate`` [N, k] (chosen
+    expert ids, their gates) -> gate[n, e] of held expert e, 0 where token n
+    did not choose it."""
+    n = idx.shape[0]
+    local = idx - held_first
+    mine = (local >= 0) & (local < held)
+    return jnp.zeros((n, held), jnp.float32).at[
+        jnp.arange(n)[:, None], jnp.where(mine, local, held)
+    ].add(jnp.where(mine, gate, 0.0), mode="drop")
+
+
+def run_held_pairs(flat, gates, stacks, tile: int, dtype):
+    """The held experts' work over the (token, expert) pairs that were
+    chosen: ``flat`` [N, d] tokens, ``gates`` [N, held] (``held_gates``),
+    ``stacks`` the experts' (gate, up, down) kernels stacked ``[held, ...]``.
+    Each expert's tokens are gathered into tiles of ``tile`` rows and a loop
+    whose trip count is read off the routing runs one tile a step.  Returns
+    (the gated sum [N, d] float32, the pairs the tiles were shaped for)."""
+    w_gate, w_up, w_down = stacks
+    n, d = flat.shape
+    with jax.named_scope("moe_route"):
+        chosen = gates > 0
+        count = jnp.sum(chosen, axis=0).astype(jnp.int32)
+        # Per expert its tokens first; behind them row numbers that
+        # no token has (n and up, each once: a gather fills them with
+        # zeros, a scatter drops them).
+        tile = min(tile, n)
+        order = jnp.argsort(jnp.where(chosen.T, 0, 1), axis=1,
+                            stable=True).astype(jnp.int32)
+        slots = jnp.arange(n + tile, dtype=jnp.int32)[None, :]
+        order = jnp.where(slots < count[:, None],
+                          jnp.pad(order, ((0, 0), (0, tile))), n + slots)
+        tiles = -(-count // tile)
+        tile_end = jnp.cumsum(tiles)
+    with jax.named_scope("moe_experts"):
+        xs = flat.astype(dtype)
+
+        def one_tile(i, acc):
+            e = jnp.searchsorted(tile_end, i, side="right").astype(
+                jnp.int32)
+            first = (i - (tile_end[e] - tiles[e])) * tile
+            rows = jax.lax.dynamic_slice(order, (e, first), (1, tile))[0]
+            xt = jnp.take(xs, rows, axis=0, mode="fill", fill_value=0)
+            y = _swiglu(xt, w_gate[e], w_up[e], w_down[e], dtype)
+            g = jnp.take(gates[:, e], rows, mode="fill", fill_value=0.0)
+            return acc.at[rows].add(y * g[:, None], mode="drop",
+                                    unique_indices=True)
+
+        routed = jax.lax.fori_loop(0, tile_end[-1], one_tile,
+                                   jnp.zeros((n, d), jnp.float32))
+    return routed, (tile_end[-1] * tile).astype(jnp.int32)
+
+
 class _Experts(_Part):
     """The expert layer of one chip: routes ``x`` [B, T, d] over all the
     experts, computes the held ones' part and the shared expert."""
@@ -289,51 +351,19 @@ class _Experts(_Part):
         n = b * t
         flat = x.reshape(n, d)
         router = self.weight("gate", cfg.n_routed_experts, d)
-        w_gate = self.weight("experts_gate_proj", held, f, d)
-        w_up = self.weight("experts_up_proj", held, f, d)
-        w_down = self.weight("experts_down_proj", held, d, f)
+        stacks = (self.weight("experts_gate_proj", held, f, d),
+                  self.weight("experts_up_proj", held, f, d),
+                  self.weight("experts_down_proj", held, d, f))
         fs = f * cfg.n_shared_experts
         with jax.named_scope("moe_route"):
             p = jax.nn.sigmoid(jnp.einsum(
                 "ni,oi->no", flat, router.astype(jnp.float32),
                 precision=_HI))
             idx, gate = route(p, cfg)
-            # This chip's part of the routing: gate[n, e] of held expert e
-            # (0 where token n did not choose it).
-            local = idx - cfg.held_first
-            mine = (local >= 0) & (local < held)
-            gates = jnp.zeros((n, held), jnp.float32).at[
-                jnp.arange(n)[:, None], jnp.where(mine, local, held)
-            ].add(jnp.where(mine, gate, 0.0), mode="drop")
-            chosen = gates > 0
-            count = jnp.sum(chosen, axis=0).astype(jnp.int32)
-            # Per expert its tokens first; behind them row numbers that
-            # no token has (n and up, each once: a gather fills them with
-            # zeros, a scatter drops them).
-            tile = min(cfg.expert_tile, n)
-            order = jnp.argsort(jnp.where(chosen.T, 0, 1), axis=1,
-                                stable=True).astype(jnp.int32)
-            slots = jnp.arange(n + tile, dtype=jnp.int32)[None, :]
-            order = jnp.where(slots < count[:, None],
-                              jnp.pad(order, ((0, 0), (0, tile))), n + slots)
-            tiles = -(-count // tile)
-            tile_end = jnp.cumsum(tiles)
+            gates = held_gates(idx, gate, cfg.held_first, held)
+        routed, pairs_run = run_held_pairs(flat, gates, stacks,
+                                           cfg.expert_tile, dtype)
         with jax.named_scope("moe_experts"):
-            xs = flat.astype(dtype)
-
-            def one_tile(i, acc):
-                e = jnp.searchsorted(tile_end, i, side="right").astype(
-                    jnp.int32)
-                first = (i - (tile_end[e] - tiles[e])) * tile
-                rows = jax.lax.dynamic_slice(order, (e, first), (1, tile))[0]
-                xt = jnp.take(xs, rows, axis=0, mode="fill", fill_value=0)
-                y = _swiglu(xt, w_gate[e], w_up[e], w_down[e], dtype)
-                g = jnp.take(gates[:, e], rows, mode="fill", fill_value=0.0)
-                return acc.at[rows].add(y * g[:, None], mode="drop",
-                                        unique_indices=True)
-
-            routed = jax.lax.fori_loop(0, tile_end[-1], one_tile,
-                                       jnp.zeros((n, d), jnp.float32))
             shared = _swiglu(
                 flat, self.weight("shared_experts_gate_proj", fs, d),
                 self.weight("shared_experts_up_proj", fs, d),
@@ -341,9 +371,8 @@ class _Experts(_Part):
         # What the device did, as counters: the chosen-and-held pairs of
         # each row, and the pairs the tiles were shaped for.
         self.sow("counters", "pairs_real", jnp.sum(
-            chosen.reshape(b, t * held), axis=1).astype(jnp.int32))
-        self.sow("counters", "pairs_run",
-                 (tile_end[-1] * tile).astype(jnp.int32))
+            (gates > 0).reshape(b, t * held), axis=1).astype(jnp.int32))
+        self.sow("counters", "pairs_run", pairs_run)
         return (routed + shared).reshape(b, t, d)
 
 
@@ -369,12 +398,14 @@ class MlaMoeEncoder(nn.Module):
     cfg: MlaMoeConfig
     dtype: Any = jnp.float32
 
+    block_cls = _Block
+
     def setup(self):
         cfg = self.cfg
         self.embed_tokens = self.param(
             "embed_tokens", _embed_normal,
             (cfg.vocab_size, cfg.hidden_size), jnp.bfloat16)
-        self.layers = [_Block(cfg, self.dtype, layer)
+        self.layers = [self.block_cls(cfg, self.dtype, layer)
                        for layer in range(cfg.num_hidden_layers)]
         self.norm = self.param("norm", _ones, (cfg.hidden_size,),
                                jnp.bfloat16)
@@ -384,8 +415,11 @@ class MlaMoeEncoder(nn.Module):
         return jnp.take(self.embed_tokens, ids.astype(jnp.int32),
                         axis=0).astype(jnp.float32)
 
+    def rope(self, length: int):
+        return rope_tables(self.cfg, length)
+
     def __call__(self, h):
-        cos, sin = rope_tables(self.cfg, h.shape[1])
+        cos, sin = self.rope(h.shape[1])
         for block in self.layers:
             h = block(h, cos, sin)
         return rms_norm(h[:, -1], self.norm, self.cfg.rms_norm_eps)
@@ -401,6 +435,8 @@ class MlaMoeClassifier(nn.Module):
     num_classes: int
     dtype: Any = jnp.float32
 
+    encoder_cls = MlaMoeEncoder
+
     # The backbone contract's optional parts (models/backbone.py).
     row_counters = ("pairs_real", "pairs_run")
 
@@ -411,7 +447,8 @@ class MlaMoeClassifier(nn.Module):
     frozen_prefixes = ("encoder",)
 
     def setup(self):
-        self.encoder = MlaMoeEncoder(self.cfg, self.dtype, name="encoder")
+        self.encoder = self.encoder_cls(self.cfg, self.dtype,
+                                        name="encoder")
         self.linear = nn.Dense(
             self.num_classes, kernel_init=dense_kernel_init,
             bias_init=nn.initializers.zeros, name="linear")
@@ -465,7 +502,7 @@ class MlaMoeClassifier(nn.Module):
                          else (part,)), None)
 
 
-def _factory(name: str, cfg: MlaMoeConfig):
+def forward_only_factory(name: str, cfg, classifier=MlaMoeClassifier):
     def make(num_classes: int, freeze_feature: bool = False,
              dtype: Any = jnp.float32, **_image_model_options):
         if not freeze_feature:
@@ -476,9 +513,10 @@ def _factory(name: str, cfg: MlaMoeConfig):
             raise ValueError(
                 f"model {name} is a forward-only encoder: it runs linear "
                 f"evaluation only; pass --freeze_feature")
-        return MlaMoeClassifier(cfg, num_classes, dtype=dtype)
+        return classifier(cfg, num_classes, dtype=dtype)
     return make
 
 
-MODELS.register("AXK1_EP16_L7", _factory("AXK1_EP16_L7", AXK1_EP16_L7))
-MODELS.register("AXK1_TOY", _factory("AXK1_TOY", AXK1_TOY))
+MODELS.register("AXK1_EP16_L7",
+                forward_only_factory("AXK1_EP16_L7", AXK1_EP16_L7))
+MODELS.register("AXK1_TOY", forward_only_factory("AXK1_TOY", AXK1_TOY))
