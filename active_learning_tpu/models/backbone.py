@@ -30,9 +30,10 @@ encoder (models/mla_moe.py) and the shortcut-connected MoE token encoder
   ``row_counters`` (optional)
       names of the per-row counts the forward sows into the ``counters``
       collection (``[batch]`` int32 each): they ride the scoring pass's and
-      the epoch's span as counters.  The token encoders sow ``pairs_real``
-      and ``pairs_run`` (held (token, expert) pairs chosen, and the pairs
-      the tiles were shaped for); the shortcut-connected one also
+      the epoch's span as counters.  The token encoders sow ``pairs_real``,
+      ``pairs_run`` and ``expert_trips`` (held (token, expert) pairs
+      chosen, the slots the tiles were shaped for, and the chunks of slots
+      the expert layers ran); the shortcut-connected one also
       ``pairs_zero`` (picks that fell on a zero-compute expert) and
       ``pairs_routed`` (all picks: k x tokens x layers).
   ``torch_key_to_flax(key)`` (optional)

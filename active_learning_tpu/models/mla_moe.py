@@ -18,11 +18,15 @@ routed to them — no token is dropped whatever the routing — plus the
 shared expert, whole.  That is what expert parallelism asks of a chip; on
 one chip the layer runs without the exchange, and what the absent experts
 would have added is left out.  The held experts' work runs over the
-(token, expert) pairs that were chosen: the tokens of each held expert are
-gathered into tiles of ``expert_tile`` rows and a loop whose trip count
-the program reads off the routing runs one tile a step, so the matmuls are
-shaped for the pairs that exist (rounded up to tiles) and not for the
-worst case.
+(token, expert) pairs that were chosen: each held expert's tokens take
+slots in tiles of ``expert_tile`` rows, a chunk of slots of every held
+expert is gathered once and multiplied by all their kernels in one batched
+matmul a projection, and the rows come back without a scatter of every
+slot (``run_held_pairs``).  The chunk is sized from the layer's shapes for
+the load an even routing gives; a loop whose trip count the program reads
+off the routing takes a busier expert's tokens a chunk at a time, so no
+token is dropped and the matmuls are shaped for the load, not the worst
+case.
 
 Precision: encoder leaves are stored bfloat16; contractions take
 ``dtype`` operands (bfloat16 on the TPU) and accumulate in float32;
@@ -53,8 +57,9 @@ _HI = jax.lax.Precision.HIGHEST
 class MlaMoeConfig:
     """One encoder.  Names follow the published ``config.json`` where it
     has one; ``held_first`` / ``held_count`` (this chip's experts),
-    ``vocab_size`` (this chip's slice) and ``expert_tile`` are this
-    program's."""
+    ``vocab_size`` (this chip's slice) and ``expert_tile`` (the rows of a
+    held expert's tokens one tile takes: its slots are rounded up to
+    tiles) are this program's."""
 
     vocab_size: int
     hidden_size: int
@@ -201,10 +206,10 @@ def route(p, cfg: MlaMoeConfig):
                                                         keepdims=True)
 
 
-def _dot(x, w, dtype):
-    """``x [..., in] @ w[out, in]^T``: ``dtype`` operands, float32
-    accumulation."""
-    return jnp.einsum("...i,oi->...o", x.astype(dtype), w.astype(dtype),
+def _dot(x, w, dtype, spec="...i,oi->...o"):
+    """``x [..., in] @ w[out, in]^T`` (or ``spec``'s contraction):
+    ``dtype`` operands, float32 accumulation."""
+    return jnp.einsum(spec, x.astype(dtype), w.astype(dtype),
                       precision=_HI, preferred_element_type=jnp.float32)
 
 
@@ -297,46 +302,87 @@ def held_gates(idx, gate, held_first: int, held: int):
     ].add(jnp.where(mine, gate, 0.0), mode="drop")
 
 
-def run_held_pairs(flat, gates, stacks, tile: int, dtype):
+def chunk_rows(n: int, picks: int, outputs: int, tile: int) -> int:
+    """The rows each held expert takes in one chunk, from the layer's
+    shapes: the tokens an even routing sends an expert (``n x picks /
+    outputs``) and three standard deviations of a count that random, in
+    whole tiles, and never more tiles than ``n`` tokens fill."""
+    tile = min(tile, n)
+    even = n * picks / outputs
+    want = -(-math.ceil(even + 3 * math.sqrt(even)) // tile)
+    return min(want, -(-n // tile)) * tile
+
+
+def run_held_pairs(flat, gates, stacks, tile: int, chunk: int, dtype):
     """The held experts' work over the (token, expert) pairs that were
     chosen: ``flat`` [N, d] tokens, ``gates`` [N, held] (``held_gates``),
     ``stacks`` the experts' (gate, up, down) kernels stacked ``[held, ...]``.
-    Each expert's tokens are gathered into tiles of ``tile`` rows and a loop
-    whose trip count is read off the routing runs one tile a step.  Returns
-    (the gated sum [N, d] float32, the pairs the tiles were shaped for)."""
-    w_gate, w_up, w_down = stacks
+    Each expert's tokens take slots rounded up to tiles of ``tile`` rows.
+    A chunk is ``chunk`` slots of every held expert (``chunk_rows``): its
+    tokens are gathered once and multiplied by all the held experts'
+    kernels at once, one batched matmul a projection.  A token's first held
+    pick is taken back by a gather over the tokens, its later picks (a
+    token may choose several held experts) are added a tile of rows at a
+    time.  A loop whose trip count is read off the routing runs the
+    chunks, so no token is dropped whatever the routing.  Returns (the
+    gated sum [N, d] float32, the slots the tiles were shaped for, the
+    chunks run)."""
     n, d = flat.shape
+    held = gates.shape[1]
+    tile = min(tile, n)
     with jax.named_scope("moe_route"):
         chosen = gates > 0
         count = jnp.sum(chosen, axis=0).astype(jnp.int32)
-        # Per expert its tokens first; behind them row numbers that
-        # no token has (n and up, each once: a gather fills them with
-        # zeros, a scatter drops them).
-        tile = min(tile, n)
+        # Per expert its tokens first, in row order; a token's place among
+        # an expert's tokens; its picks numbered in expert order.
         order = jnp.argsort(jnp.where(chosen.T, 0, 1), axis=1,
                             stable=True).astype(jnp.int32)
-        slots = jnp.arange(n + tile, dtype=jnp.int32)[None, :]
-        order = jnp.where(slots < count[:, None],
-                          jnp.pad(order, ((0, 0), (0, tile))), n + slots)
-        tiles = -(-count // tile)
-        tile_end = jnp.cumsum(tiles)
+        place = jnp.cumsum(chosen, axis=0, dtype=jnp.int32) - 1
+        rank = jnp.cumsum(chosen, axis=1, dtype=jnp.int32) - 1
+        picked = jnp.any(chosen, axis=1)
+        first = jnp.argmax(chosen, axis=1).astype(jnp.int32)
+        first_place = jnp.take_along_axis(place, first[:, None], axis=1)[:, 0]
+        slots = jnp.sum(-(-count // tile) * tile)
+        trips = -(-jnp.max(count) // chunk)
     with jax.named_scope("moe_experts"):
         xs = flat.astype(dtype)
 
-        def one_tile(i, acc):
-            e = jnp.searchsorted(tile_end, i, side="right").astype(
-                jnp.int32)
-            first = (i - (tile_end[e] - tiles[e])) * tile
-            rows = jax.lax.dynamic_slice(order, (e, first), (1, tile))[0]
+        def one_chunk(c, acc):
+            k = c * chunk + jnp.arange(chunk, dtype=jnp.int32)
+            real = k[None, :] < count[:, None]                 # [held, chunk]
+            # A slot no token fills reads row n: the gather fills it with
+            # zeros, the add drops it.
+            rows = jnp.where(real, order[:, jnp.minimum(k, n - 1)], n)
             xt = jnp.take(xs, rows, axis=0, mode="fill", fill_value=0)
-            y = _swiglu(xt, w_gate[e], w_up[e], w_down[e], dtype)
-            g = jnp.take(gates[:, e], rows, mode="fill", fill_value=0.0)
-            return acc.at[rows].add(y * g[:, None], mode="drop",
-                                    unique_indices=True)
+            h = (jax.nn.silu(_dot(xt, stacks[0], dtype, "ecd,efd->ecf"))
+                 * _dot(xt, stacks[1], dtype, "ecd,efd->ecf"))
+            y = _dot(h, stacks[2], dtype, "ecf,edf->ecd")
+            g = jnp.where(real, jnp.take_along_axis(
+                gates.T, jnp.minimum(rows, n - 1), axis=1), 0.0)
+            z = (y * g[..., None]).reshape(held * chunk, d)
+            # First picks: each token reads its row back, no scatter.
+            at = first_place - c * chunk
+            mine = picked & (at >= 0) & (at < chunk)
+            acc = acc + jnp.where(mine[:, None], jnp.take(
+                z, first * chunk + jnp.clip(at, 0, chunk - 1), axis=0), 0.0)
+            # Later picks, packed to the front and added a tile at a time.
+            later = (real & (jnp.take_along_axis(
+                rank.T, jnp.minimum(rows, n - 1), axis=1) > 0)).reshape(-1)
+            packed = jnp.argsort(~later, stable=True).astype(jnp.int32)
+            n_later = jnp.sum(later)
+            flat_rows = rows.reshape(-1)
 
-        routed = jax.lax.fori_loop(0, tile_end[-1], one_tile,
+            def one_tile(i, acc):
+                j = i * tile + jnp.arange(tile, dtype=jnp.int32)
+                at = jnp.take(packed, jnp.minimum(j, held * chunk - 1))
+                to = jnp.where(j < n_later, flat_rows[at], n)
+                return acc.at[to].add(jnp.take(z, at, axis=0), mode="drop")
+
+            return jax.lax.fori_loop(0, -(-n_later // tile), one_tile, acc)
+
+        routed = jax.lax.fori_loop(0, trips, one_chunk,
                                    jnp.zeros((n, d), jnp.float32))
-    return routed, (tile_end[-1] * tile).astype(jnp.int32)
+    return routed, slots.astype(jnp.int32), trips.astype(jnp.int32)
 
 
 class _Experts(_Part):
@@ -361,18 +407,21 @@ class _Experts(_Part):
                 precision=_HI))
             idx, gate = route(p, cfg)
             gates = held_gates(idx, gate, cfg.held_first, held)
-        routed, pairs_run = run_held_pairs(flat, gates, stacks,
-                                           cfg.expert_tile, dtype)
+        routed, pairs_run, trips = run_held_pairs(
+            flat, gates, stacks, cfg.expert_tile,
+            chunk_rows(n, cfg.num_experts_per_tok, cfg.n_routed_experts,
+                       cfg.expert_tile), dtype)
         with jax.named_scope("moe_experts"):
             shared = _swiglu(
                 flat, self.weight("shared_experts_gate_proj", fs, d),
                 self.weight("shared_experts_up_proj", fs, d),
                 self.weight("shared_experts_down_proj", d, fs), dtype)
         # What the device did, as counters: the chosen-and-held pairs of
-        # each row, and the pairs the tiles were shaped for.
+        # each row, the slots the tiles were shaped for, the chunks run.
         self.sow("counters", "pairs_real", jnp.sum(
             (gates > 0).reshape(b, t * held), axis=1).astype(jnp.int32))
         self.sow("counters", "pairs_run", pairs_run)
+        self.sow("counters", "expert_trips", trips)
         return (routed + shared).reshape(b, t, d)
 
 
@@ -438,7 +487,7 @@ class MlaMoeClassifier(nn.Module):
     encoder_cls = MlaMoeEncoder
 
     # The backbone contract's optional parts (models/backbone.py).
-    row_counters = ("pairs_real", "pairs_run")
+    row_counters = ("pairs_real", "pairs_run", "expert_trips")
 
     # A forward-only encoder: linear evaluation is the one protocol it runs
     # (the registry's factory refuses to build it without
@@ -507,9 +556,10 @@ def forward_only_factory(name: str, cfg, classifier=MlaMoeClassifier):
              dtype: Any = jnp.float32, **_image_model_options):
         if not freeze_feature:
             # No fit moves this encoder: its leaves are stored bfloat16, the
-            # expert loop's trip count is read off the routing (no reverse
-            # mode), and 12 bytes a parameter of float32 weight, gradient
-            # and momentum do not exist for billions of leaves.
+            # expert layer's loops read their trip counts off the routing
+            # (no reverse mode), and 12 bytes a parameter of float32
+            # weight, gradient and momentum do not exist for billions of
+            # leaves.
             raise ValueError(
                 f"model {name} is a forward-only encoder: it runs linear "
                 f"evaluation only; pass --freeze_feature")

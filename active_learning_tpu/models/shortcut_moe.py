@@ -3,7 +3,7 @@ shortcut-connected mixture of experts, zero-compute experts, multi-head
 latent attention with rescaled low-rank paths.  The third implementer of the
 backbone contract (models/backbone.py) and the second token encoder: what it
 shares with models/mla_moe.py it imports from there (the attention, the dense
-SwiGLU, the held-experts tile loop, the classifier's head and freeze
+SwiGLU, the held experts' batched matmuls, the classifier's head and freeze
 contract).
 
 One "layer" of the published config is a **double layer**: two attention
@@ -33,9 +33,9 @@ expert; a pick among the last ``zero_expert_num`` is an identity
 (``gate * x``): it holds no weight, lives on no chip and is computed where
 the token is.  As in models/mla_moe.py the layer is told which experts it
 holds (``held_first``, ``held_count``), routes over ALL the outputs, runs its
-own (token, expert) pairs through the tile loop — no token dropped whatever
-the routing — adds the zero-expert term for every token, and leaves out what
-the absent experts would have added.
+own (token, expert) pairs through ``mla_moe.run_held_pairs`` — no token
+dropped whatever the routing — adds the zero-expert term for every token,
+and leaves out what the absent experts would have added.
 
 Precision as models/mla_moe.py states it; the router (matmul, softmax, bias,
 top-k) and the zero-expert term are float32, the correction bias is a
@@ -62,7 +62,8 @@ from .mla_moe import _HI, _Attention, _DenseFFN, _Part, rms_norm
 class ShortcutMoeConfig:
     """One encoder.  Names follow the published ``config.json``;
     ``held_first`` / ``held_count`` (this chip's experts), ``vocab_size``
-    (this chip's slice) and ``expert_tile`` are this program's."""
+    (this chip's slice) and ``expert_tile`` (the rows of a held expert's
+    tokens one tile takes) are this program's."""
 
     vocab_size: int
     hidden_size: int
@@ -192,15 +193,19 @@ class _ShortcutExperts(_Part):
             gates = mla_moe.held_gates(idx, gate, cfg.held_first, held)
             zero_pick = idx >= cfg.n_routed_experts
             zero_gate = jnp.sum(jnp.where(zero_pick, gate, 0.0), axis=1)
-        routed, pairs_run = mla_moe.run_held_pairs(flat, gates, stacks,
-                                                   cfg.expert_tile, dtype)
+        routed, pairs_run, trips = mla_moe.run_held_pairs(
+            flat, gates, stacks, cfg.expert_tile,
+            mla_moe.chunk_rows(n, cfg.moe_topk, cfg.router_outputs,
+                               cfg.expert_tile), dtype)
         with jax.named_scope("moe_zero"):
             zero = zero_gate[:, None] * flat
-        # Counters, a row each but the pairs the tiles were shaped for:
-        # held pairs, picks that fell on a zero expert, all picks.
+        # Counters, a row each but the slots the tiles were shaped for and
+        # the chunks run: held pairs, picks that fell on a zero expert, all
+        # picks.
         self.sow("counters", "pairs_real", jnp.sum(
             (gates > 0).reshape(b, t * held), axis=1).astype(jnp.int32))
         self.sow("counters", "pairs_run", pairs_run)
+        self.sow("counters", "expert_trips", trips)
         self.sow("counters", "pairs_zero", jnp.sum(
             zero_pick.reshape(b, t * cfg.moe_topk), axis=1
         ).astype(jnp.int32))
@@ -246,7 +251,8 @@ class ShortcutMoeClassifier(mla_moe.MlaMoeClassifier):
     """``MlaMoeClassifier`` over the double-layer encoder."""
 
     encoder_cls = ShortcutMoeEncoder
-    row_counters = ("pairs_real", "pairs_run", "pairs_zero", "pairs_routed")
+    row_counters = ("pairs_real", "pairs_run", "expert_trips",
+                    "pairs_zero", "pairs_routed")
 
     def torch_key_to_flax(self, key: str) -> Optional[Tuple]:
         """The published names: the list-valued sub-modules of a double

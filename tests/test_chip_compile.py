@@ -18,7 +18,8 @@ repo's own builders on ``make_mesh(devices=<described devices>)``:
     gradient all-reduce must be in the program);
   * the 224 px scoring step at the accelerator floor of 256 rows, as the
     gather runner over a pinned pool;
-  * the batched k-center scan over ``[50000, 2048]``.
+  * the batched k-center scan over ``[50000, 2048]``;
+  * the A.X-K1 expert layer at its published widths.
 
 ``dtype="auto"`` reads the live backend and would pick float32 here, so the
 tests name bfloat16 themselves.  The topology is described inside a
@@ -28,6 +29,7 @@ TPU library, and every xdist worker imports every test file.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -250,3 +252,30 @@ def test_batched_kcenter_scan_50000_by_2048(one_chip):
         _spec((n,), jnp.float32, rep), _spec((n,), jnp.float32, rep),
         budget=1000, q=kcenter.DEFAULT_BATCH_Q).compile()
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_axk1_expert_layer_runs_its_held_experts_batched(one_chip):
+    """The A.X-K1 expert layer at full widths (16 x 512 tokens, twelve held
+    experts of 7168 -> 2048 -> 7168, tile 512): each projection is ONE
+    matmul over all twelve held experts a chunk (``[12, 512, ...]``), no
+    matmul takes one expert's tile of 512 rows as the parent's loop did
+    (``[512, 2048]`` and ``[512, 7168]``: the trip that paid a 512-row
+    scatter-add each, PERF.md section 5), and the temporaries stay under
+    1 GB."""
+    from active_learning_tpu.models import mla_moe
+    cfg = mla_moe.AXK1_EP16_L7
+    layer = mla_moe._Experts(cfg, jnp.bfloat16)
+    rep = mesh_lib.replicated_sharding(one_chip)
+    d = cfg.hidden_size
+    params = jax.tree.map(
+        lambda s: _spec(s.shape, s.dtype, rep),
+        jax.eval_shape(lambda k: layer.init(k, jnp.zeros((1, 8, d))),
+                       jax.random.PRNGKey(0)))
+    compiled = jax.jit(
+        lambda p, x: layer.apply(p, x, mutable=["counters"])).lower(
+        params, _spec((16, 512, d), jnp.float32, rep)).compile()
+    shapes = set(re.findall(r"= f32\[([0-9,]+)\]\{[^}]*\} convolution\(",
+                            compiled.as_text()))
+    assert {"12,512,2048", "12,512,7168"} <= shapes
+    assert not {"512,2048", "512,7168"} & shapes
+    assert compiled.memory_analysis().temp_size_in_bytes < 10 ** 9

@@ -172,27 +172,115 @@ def test_the_programs_shares_add_up_to_the_uncut_layer(fam):
                                rtol=2e-4, atol=2e-5)
 
 
-def test_no_token_is_dropped_under_a_lopsided_router(fam):
-    """Every token to ONE held expert: its tokens fill six tiles of eight
-    rows, and each of them gets that expert's part."""
+@pytest.mark.parametrize("planted", ((2,), (0, 1, 2, 3)))
+def test_no_token_is_dropped_under_a_lopsided_router(fam, planted):
+    """Every token to the planted held experts: to ONE, its 48 tokens fill
+    six tiles of eight rows, twice the 24 rows a chunk gives an expert, and
+    the other held experts' tokens bring the slots to 120; to all four, the
+    slots are 192, in two chunks too.  Each token gets every planted
+    expert's part."""
     config = _toy_config()
     key, x = _layer_inputs(fam, config, 9)
     x = x + 4.0                       # a component the router can key on
     w = dict(fam.layer_tensors(key, 1, config))
     lop = np.asarray(w["mlp.gate"]).copy()
-    lop[2] = 1.0                      # expert 2 scores sigmoid(~256) on all
+    lop[list(planted)] = 1.0          # each scores sigmoid(~256) on all
     w["mlp.gate"] = jnp.asarray(lop)
     out, state = _experts_module(config).apply(
         _experts_params(fam, w, config), x[None], mutable=["counters"])
     routed, shared = fam.moe_parts(x, w, config)
     gates = np.asarray(fam.router_gates(x, w["mlp.gate"], config))
-    assert (gates[:, 2] > 0).all()
+    assert (gates[:, list(planted)] > 0).all()
     np.testing.assert_allclose(out[0], routed + shared, rtol=2e-4, atol=2e-5)
     counters = state["counters"]
     real = int(np.sum(counters["pairs_real"][0]))
     run = int(counters["pairs_run"][0])
-    assert real == int(np.sum(gates[:, :4] > 0)) >= len(x)
+    trips = int(counters["expert_trips"][0])
+    assert real == int(np.sum(gates[:, :4] > 0)) >= len(x) * len(planted)
     assert run >= real and run % 8 == 0 and run - real < 4 * 8
+    chunk = mla_moe.chunk_rows(len(x), 4, 16, 8)
+    assert chunk == 24 and trips == 2
+    assert run == (120 if len(planted) == 1 else 192)
+
+
+def _plain_sum(flat, gates, stacks):
+    """The held experts' part as a plain float32 sum: every expert over
+    every token, weighted by its gate (0 where it was not chosen)."""
+    out = jnp.zeros(flat.shape, jnp.float32)
+    for e in range(gates.shape[1]):
+        y = mla_moe._swiglu(flat, stacks[0][e], stacks[1][e], stacks[2][e],
+                            jnp.float32)
+        out = out + gates[:, e:e + 1] * y
+    return out
+
+
+def _routing(case, n, rng):
+    """Seeded [n, 4] gates: what each held expert was chosen by."""
+    gates = np.zeros((n, 4), np.float32)
+    if case == "an_expert_empty":
+        for t in range(n):
+            gates[t, rng.choice([0, 1, 3], size=rng.integers(0, 3),
+                                replace=False)] = 1.0
+    elif case == "one_expert_many_chunks":
+        gates[:, 1] = 1.0
+    elif case == "padding_slots":
+        for e, c in enumerate((3, 9, 17, 8)):
+            gates[rng.choice(n, size=c, replace=False), e] = 1.0
+    elif case == "every_token_on_every_expert":
+        gates[:] = 1.0
+    return gates * rng.uniform(0.1, 2.5, gates.shape).astype(np.float32)
+
+
+# (routing, rows an expert takes a chunk: None for what chunk_rows gives a
+# toy A.X-K1 layer of 40 tokens)
+GROUPED_CASES = (("an_expert_empty", None), ("one_expert_many_chunks", 16),
+                 ("padding_slots", 16), ("every_token_on_every_expert", None),
+                 ("no_held_pair", None))
+
+
+@pytest.mark.parametrize("case,chunk", GROUPED_CASES)
+def test_the_grouped_layer_is_the_plain_per_expert_sum(case, chunk):
+    """``run_held_pairs`` against a plain float32 sum over experts: an
+    expert no token chose, every token on one expert (several chunks),
+    counts that leave padding slots (chunks that split an expert), every
+    token on every expert, no held pair at all.  The slots it reports are
+    the parent's tile loop's (each expert's tokens rounded up to tiles of
+    eight: ``tile_end[-1] * tile``), its trips the chunks the busiest
+    expert's tokens take."""
+    n, d, f, tile = 40, 16, 8, 8
+    rng = np.random.default_rng(sum(map(ord, case)))
+    flat = jnp.asarray(rng.standard_normal((n, d)).astype(np.float32))
+    gates = jnp.asarray(_routing(case, n, rng))
+    stacks = tuple(jnp.asarray(rng.standard_normal(s).astype(np.float32)
+                               * s[-1] ** -0.5)
+                   for s in ((4, f, d), (4, f, d), (4, d, f)))
+    chunk = chunk or mla_moe.chunk_rows(n, 4, 16, tile)
+    got, run, trips = jax.jit(
+        lambda x, g, s: mla_moe.run_held_pairs(x, g, s, tile, chunk,
+                                               jnp.float32))(
+        flat, gates, stacks)
+    np.testing.assert_allclose(got, _plain_sum(flat, gates, stacks),
+                               rtol=1e-5, atol=1e-6)
+    count = np.sum(np.asarray(gates) > 0, axis=0)
+    assert int(run) == int(np.sum(-(-count // tile))) * tile
+    assert int(trips) == -(-int(count.max()) // chunk)
+    if case in ("one_expert_many_chunks", "every_token_on_every_expert"):
+        assert int(trips) >= 2
+    if case == "no_held_pair":
+        assert int(run) == int(trips) == 0 and not np.any(np.asarray(got))
+
+
+def test_a_chunk_holds_the_cells_routing():
+    """An expert's rows a chunk, from the layer's shapes alone: an even
+    routing's tokens an expert and three standard deviations, in whole
+    tiles.  The A.X-K1 cell's experts see about 341 tokens a step (at most
+    367 in the chip probe of PR 36), the LongCat cell's about 128 (at most
+    153): one chunk each."""
+    assert mla_moe.chunk_rows(8192, 8, 192, 512) == 512
+    assert mla_moe.chunk_rows(8192, 12, 768, 128) == 256
+    # Never more tiles than n tokens fill; a tile no larger than n.
+    assert mla_moe.chunk_rows(48, 4, 4, 8) == 48
+    assert mla_moe.chunk_rows(6, 4, 16, 8) == 6
 
 
 def _trainer(model, lr=0.1):
@@ -340,6 +428,8 @@ def test_spans_count_the_head_alone_and_the_pairs(rounds):
     for a in by["collect_pool"] + by["epoch"]:
         assert a["tokens"] == a["rows"] * 32
         assert 0 < a["pairs_real"] <= a["pairs_run"]
+        # A chunk of slots a trip, each trip at least a tile of eight.
+        assert 0 < a["expert_trips"] <= a["pairs_run"] // 8
 
 
 def test_presets_are_the_benchmarks_configurations():

@@ -262,15 +262,19 @@ def test_the_programs_shares_add_up_to_the_uncut_layer(fam):
                                    **TOL)
 
 
-def test_no_token_is_dropped_under_a_lopsided_router(fam):
-    """Every token to ONE held expert: its 48 tokens fill six tiles of eight
-    rows, and each of them gets that expert's part."""
+@pytest.mark.parametrize("planted", ((2,), (0, 1, 2, 3)))
+def test_no_token_is_dropped_under_a_lopsided_router(fam, planted):
+    """Every token to the planted held experts: to ONE, its 48 tokens fill
+    six tiles of eight rows, twice the 24 rows a chunk gives an expert, and
+    the other held experts' bring the slots to 144; to all four, the slots
+    are 192, in two chunks too.  Each token gets every planted expert's
+    part."""
     config = _toy_config()
     key, h = _layer_inputs(fam, config, 9)
     x = h.reshape(-1, h.shape[-1]) + 4.0
     w = dict(fam.layer_tensors(key, 1, config))
     lop = np.asarray(w["mlp.router.classifier"]).copy()
-    lop[2] = 0.05                     # output 2's logit ~ 13 on every token
+    lop[list(planted)] = 0.05         # each one's logit ~ 13 on every token
     w["mlp.router.classifier"] = jnp.asarray(lop)
     params = {"params": _block_params(fam, w, config)["params"]["mlp"]}
     out, state = shortcut_moe._ShortcutExperts(
@@ -280,12 +284,16 @@ def test_no_token_is_dropped_under_a_lopsided_router(fam):
     gates = np.asarray(fam.router_gates(
         x, w["mlp.router.classifier"],
         w["mlp.router.e_score_correction_bias"], config))
-    assert (gates[:, 2] > 0).all()
+    assert (gates[:, list(planted)] > 0).all()
     np.testing.assert_allclose(out[0], routed + zero, **TOL)
     real = int(np.sum(state["counters"]["pairs_real"][0]))
     run = int(state["counters"]["pairs_run"][0])
-    assert real == int(np.sum(gates[:, :4] > 0)) >= len(x)
+    trips = int(state["counters"]["expert_trips"][0])
+    assert real == int(np.sum(gates[:, :4] > 0)) >= len(x) * len(planted)
     assert run >= real and run % 8 == 0 and run - real < 4 * 8
+    chunk = mla_moe.chunk_rows(len(x), 4, 24, 8)
+    assert chunk == 24 and trips == 2
+    assert run == (144 if len(planted) == 1 else 192)
 
 
 @pytest.mark.parametrize("fault", ("zero_bias", "no_zero_experts"))
@@ -454,6 +462,8 @@ def test_spans_carry_the_four_pair_counters(rounds):
     for a in by["collect_pool"] + by["epoch"]:
         assert a["tokens"] == a["rows"] * 32
         assert 0 < a["pairs_real"] <= a["pairs_run"]
+        # A chunk of slots a trip, each trip at least a tile of eight.
+        assert 0 < a["expert_trips"] <= a["pairs_run"] // 8
         # k x tokens x layers, and about a third of it on zero experts.
         assert a["pairs_routed"] == 4 * a["tokens"] * 3
         assert 0.2 < a["pairs_zero"] / a["pairs_routed"] < 0.5
@@ -505,19 +515,36 @@ def test_the_registry_and_the_cli_take_the_encoder():
     assert isinstance(model, shortcut_moe.ShortcutMoeClassifier)
     assert model.freeze_feature is True
     assert backbone.frozen_prefixes(model) == ("encoder",)
-    assert model.row_counters == ("pairs_real", "pairs_run", "pairs_zero",
-                                  "pairs_routed")
+    assert model.row_counters == ("pairs_real", "pairs_run", "expert_trips",
+                                  "pairs_zero", "pairs_routed")
 
 
-# What the parent commit (PR 34, ``029b215``) gave for the A.X-K1 toy on seed
-# 5's checkpoint and six rows, eagerly and under jit: SHA-256 of the logits'
-# and the embedding's bytes, and the counters' sums.  The tile loop now
-# lives in one function that both encoders call; the same bytes go through
-# the same arithmetic, so the digests stand.
+# What the parent commits gave for the A.X-K1 toy on seed 5's checkpoint and
+# six rows, eagerly and under jit: SHA-256 of the logits' and the embedding's
+# bytes (PR 34, ``029b215``, read again at PR 35, ``c01e7df``), the
+# counters' sums, and the first four logits and embedding entries of each
+# row.  PR 36 runs the held experts as batched matmuls with a rank-split
+# combine in place of the tile loop; on the CPU the same products are added
+# in the same order, so the digests still stand, and the numbers bound what
+# a change of order may move (rounding).
 AXK1_TOY_AT_THE_PARENT = {
     False: "c2ce475ede0b3a507f0ad8789c01e106955bc44691013123e76d4a9ca149f16f",
     True: "65b8c415ca70aba01f998e46116664698b206fa976cd45203e03b952458324d3"}
 AXK1_TOY_COUNTERS_AT_THE_PARENT = [179, 192, 235, 256]
+AXK1_TOY_LOGITS_AT_THE_PARENT = [
+    [-0.569698, 0.8512685, -0.0449729, 0.2893252],
+    [0.0601626, -0.5961638, 0.9875636, 1.5175548],
+    [-0.836235, 1.6962279, -2.1775069, -0.8681808],
+    [-1.6422144, 0.9140904, -0.5834407, 1.0271271],
+    [1.4848852, 1.1694596, 0.0245845, 0.7964868],
+    [0.8117865, 0.5022219, 0.0094892, 0.9988652]]
+AXK1_TOY_EMBEDDING_AT_THE_PARENT = [
+    [-1.5283871, 1.898744, 0.6113962, 1.0297315],
+    [-1.7751796, 1.1563722, -2.0223563, 0.2461153],
+    [0.5952956, 0.650323, -0.7714401, 0.8016225],
+    [-1.0611624, 0.2789146, 1.4928739, 1.5292411],
+    [-0.2133103, -0.5460781, 0.7902985, -1.1981463],
+    [1.7705948, -0.8023418, -1.5696836, -0.8596426]]
 
 
 @pytest.mark.parametrize("jit", (False, True))
@@ -539,8 +566,16 @@ def test_the_axk1_toy_reads_the_parents_bits_through_the_shared_loop(
                            mutable=["counters"])
     (logits, emb), state = (jax.jit(forward) if jit else forward)(
         variables, jnp.asarray(rows))
+    np.testing.assert_allclose(np.asarray(logits)[:, :4],
+                               AXK1_TOY_LOGITS_AT_THE_PARENT, atol=2e-6)
+    np.testing.assert_allclose(np.asarray(emb)[:, :4],
+                               AXK1_TOY_EMBEDDING_AT_THE_PARENT, atol=2e-6)
     digest = hashlib.sha256(np.asarray(logits).tobytes()
                             + np.asarray(emb).tobytes()).hexdigest()
     assert digest == AXK1_TOY_AT_THE_PARENT[jit]
-    assert [int(np.asarray(x).sum()) for x in jax.tree.leaves(state)] == \
-        AXK1_TOY_COUNTERS_AT_THE_PARENT
+    counters = flatten_dict(state["counters"])
+    # The same slots give the same counters; one chunk an expert layer.
+    assert [int(np.asarray(v).sum()) for k, v in sorted(counters.items())
+            if k[-1] != "expert_trips"] == AXK1_TOY_COUNTERS_AT_THE_PARENT
+    assert [int(np.asarray(v).sum()) for k, v in sorted(counters.items())
+            if k[-1] == "expert_trips"] == [1, 1]
