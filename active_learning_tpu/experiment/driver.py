@@ -43,6 +43,7 @@ from ..parallel import mesh as mesh_lib
 from ..pool import PoolState
 from ..strategies import get_strategy
 from ..telemetry import diagnostics as diag_lib
+from ..telemetry import jax_events as tele_jax_events
 from ..telemetry import profiler as tele_profiler
 from ..telemetry import runtime as tele_runtime
 from ..telemetry import spans as tele_spans
@@ -97,29 +98,9 @@ def resolve_compilation_cache_dir(cache_dir: Optional[str] = None
     return cache_dir or DEFAULT_COMPILATION_CACHE_DIR
 
 
-# Persistent-cache traffic of THIS process, counted off JAX's own
-# monitoring events (a hit = an executable read back from the directory,
-# a miss = one compiled and written there).  The driver journals the
-# counts at every round end: whether a second process really found the
-# first one's programs is otherwise invisible.
-_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
-                 "/jax/compilation_cache/cache_misses": "misses"}
-_cache_counts = {"hits": 0, "misses": 0}
-
-
-def _count_cache_event(event: str, **_kwargs) -> None:
-    key = _CACHE_EVENTS.get(event)
-    if key is not None:
-        _cache_counts[key] += 1
-
-
-jax.monitoring.register_event_listener(_count_cache_event)
-
-
-def compilation_cache_counts() -> dict:
-    """{"hits", "misses"} of the persistent compilation cache in this
-    process so far."""
-    return dict(_cache_counts)
+# Persistent-cache traffic of THIS process (telemetry/jax_events.py, the
+# home of every jax.monitoring listener): journaled at every round end.
+compilation_cache_counts = tele_jax_events.compilation_cache_counts
 
 
 def enable_compilation_cache(cache_dir: Optional[str] = None
@@ -1123,8 +1104,8 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
             accounting; raises to the attempt loop on failure."""
             phase_s = {}
             with tele_spans.get_tracer().span(
-                    "round", args={"round": rd,
-                                   "attempt": attempt}) as round_sp:
+                    "round", args={"round": rd, "attempt": attempt},
+                    counters=True) as round_sp:
                 strategy.round = rd
                 telemetry.tick(force=True, round=rd,
                                phase="round_start", epoch=0, step=0)
@@ -1140,7 +1121,8 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
                 # no-op for explicit integer budgets; already-uploaded
                 # pools stay resident regardless —
                 # parallel/resident.cached).
-                budget = strategy.trainer.refresh_resident_budget()
+                with tele_spans.get_tracer().span("round/resident_budget"):
+                    budget = strategy.trainer.refresh_resident_budget()
                 logger.info(
                     f"Resident pool budget for round {rd}: "
                     f"{budget / 1e9:.2f} GB "
@@ -1160,7 +1142,8 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
                         labeled_idxs, cur_cost = strategy.query(
                             cfg.round_budget)
                     phase_s["query"] = sp.duration_s
-                    strategy.update(labeled_idxs, cur_cost)
+                    with tele_spans.get_tracer().span("query/update"):
+                        strategy.update(labeled_idxs, cur_cost)
                     _boundary(rd, "query")
 
                 with phase_timer("init_network_weights_time", rd, sink,
@@ -1213,7 +1196,8 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
                 # rollback point): a device->host fetch outside the
                 # round span, so it is one of the ckpt/* spans.
                 with tele_spans.get_tracer().span(
-                        "ckpt/round_snapshot", args={"round": rd}) as sp:
+                        "ckpt/round_snapshot", args={"round": rd},
+                        counters=True) as sp:
                     snapshot = _round_snapshot(strategy)
                     sp.args["bytes"] = ckpt_lib.tree_bytes(
                         snapshot["variables"])
@@ -1253,7 +1237,8 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
                 # the incremental trace export — host work with the
                 # device idle, so it gets a span of its own.
                 with tele_spans.get_tracer().span(
-                        "round_epilogue", args={"round": rd}) as epilogue:
+                        "round_epilogue", args={"round": rd},
+                        counters=True) as epilogue:
                     pipe = strategy.pipeline
                     if pipe is not None:
                         # Scorer busy minus the round's gate contention on
@@ -1269,20 +1254,27 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
                             - strategy.trainer.dispatch_lock.take_wait_s())
                     else:
                         spec_s = 0.0
-                    _emit_overlap_telemetry(
-                        telemetry, sink, rd, round_sp.duration_s, phase_s,
-                        spec_s, pipeline_mode if pipe is not None else "off")
-                    _emit_round_telemetry(telemetry, sink, rd, strategy,
-                                          ladder,
-                                          retries_baseline=run_retries0)
+                    # The round's metrics, gauges and scrape file; the
+                    # trace's rewrite and the heartbeat inside it have
+                    # spans of their own.
+                    with tele_spans.get_tracer().span("round/emit"):
+                        _emit_overlap_telemetry(
+                            telemetry, sink, rd, round_sp.duration_s,
+                            phase_s, spec_s,
+                            pipeline_mode if pipe is not None else "off")
+                        _emit_round_telemetry(telemetry, sink, rd, strategy,
+                                              ladder,
+                                              retries_baseline=run_retries0)
                     if telemetry.jit_grown:
                         # A recompile says which step it was: the
                         # registered programs whose cache grew since the
                         # last round's jit_cache_miss_delta.
                         epilogue.args["recompiled"] = list(
                             telemetry.jit_grown)
+                    with tele_spans.get_tracer().span("round/placement"):
+                        placement = _placement_record(strategy)
                     journal.write(compile_cache=compilation_cache_counts(),
-                                  placement=_placement_record(strategy))
+                                  placement=placement)
                     if write_report:
                         row = {
                             "round": rd,
@@ -1306,8 +1298,9 @@ def run_experiment(cfg: ExperimentConfig, sink: Optional[MetricsSink] = None,
                         if diag is not None:
                             row.update(diag.last_row)
                         report_rows.append(row)
-                        diag_lib.write_run_report(run_report_path,
-                                                  report_header, report_rows)
+                        with tele_spans.get_tracer().span("report/write"):
+                            diag_lib.write_run_report(
+                                run_report_path, report_header, report_rows)
                 if len(strategy.available_query_idxs(shuffle=False)) == 0:
                     logger.info("Finished querying all Images!")
                     break

@@ -19,7 +19,9 @@ its ``seq`` — the monotonic tag never restarts within an experiment
 directory, so two records can always be ordered even across process
 restarts within one filesystem.
 
-Stdlib-only on purpose: telemetry/status.py reads it with NO jax import.
+Stdlib-only on purpose: telemetry/status.py reads it with NO jax import
+(the span around a write comes from telemetry/spans.py, which imports no
+jax either).
 """
 
 from __future__ import annotations
@@ -29,6 +31,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, Optional
+
+from ..telemetry import spans as spans_lib
 
 JOURNAL_FILE = "round_journal.json"
 
@@ -66,6 +70,10 @@ class RoundJournal:
         the write failed)."""
         if not self.enabled:
             return None
+        with spans_lib.get_tracer().span("journal/write"):
+            return self._write(fields)
+
+    def _write(self, fields: Dict[str, Any]) -> Optional[Dict[str, Any]]:
         with self._lock:
             for k, v in fields.items():
                 if v is None:

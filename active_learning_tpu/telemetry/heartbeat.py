@@ -31,6 +31,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, Optional
 
+from . import spans as spans_lib
+
 
 def heartbeat_filename(process_index: int = 0, process_count: int = 1) -> str:
     if process_count > 1:
@@ -104,12 +106,13 @@ class HeartbeatWriter:
 
     def _write(self, payload: Dict[str, Any]) -> None:
         try:
-            directory = os.path.dirname(os.path.abspath(self.path))
-            os.makedirs(directory, exist_ok=True)
-            tmp = f"{self.path}.tmp.{os.getpid()}"
-            with open(tmp, "w") as fh:
-                json.dump(payload, fh)
-            os.replace(tmp, self.path)
+            with spans_lib.get_tracer().span("heartbeat/write"):
+                directory = os.path.dirname(os.path.abspath(self.path))
+                os.makedirs(directory, exist_ok=True)
+                tmp = f"{self.path}.tmp.{os.getpid()}"
+                with open(tmp, "w") as fh:
+                    json.dump(payload, fh)
+                os.replace(tmp, self.path)
             self.writes += 1
         except OSError:
             # Liveness reporting must never take the run down (full disk,

@@ -164,9 +164,12 @@ class RunTelemetry:
 
     def export_trace(self, metadata: Optional[Dict[str, Any]] = None
                      ) -> Optional[str]:
+        """Rewrite ``trace.json`` (every round, so a crash still leaves
+        it); the rewrite is the recorder's own cost, under its own span."""
         if not self.trace_path:
             return None
-        return self.tracer.export(self.trace_path, metadata=metadata)
+        with self.tracer.span("trace/export"):
+            return self.tracer.export(self.trace_path, metadata=metadata)
 
     def finish(self, status: str = "finished") -> None:
         """Final heartbeat + trace export + watchdog stop.  Idempotent —
@@ -175,6 +178,7 @@ class RunTelemetry:
         if self.finished:
             return
         self.finished = True
+        self.tracer.stop_host_hooks()
         if self.watchdog is not None:
             self.watchdog.stop()
         if self.heartbeat is not None:
@@ -204,6 +208,7 @@ def uninstall(rt: Optional[RunTelemetry] = None) -> None:
     """Restore the inert default (only if ``rt`` is still the installed
     one — a nested run that already swapped must not be clobbered)."""
     global _CURRENT
+    (rt or _CURRENT).tracer.stop_host_hooks()
     if rt is None or _CURRENT is rt:
         _CURRENT = _DEFAULT
         spans_lib.set_tracer(None)
@@ -239,6 +244,9 @@ def start_run(cfg, log_dir: str, process_index: int = 0,
     tracer = spans_lib.SpanTracer(
         enabled=cfg.export_trace,
         annotate=profiler_lib.trace_annotation)
+    # The collector hook, only while recording (a flag-off run keeps
+    # gc.callbacks as it found them).
+    tracer.start_host_hooks()
     trace_path = (os.path.join(log_dir, f"trace{suffix}.json")
                   if cfg.export_trace else None)
     watchdog = None

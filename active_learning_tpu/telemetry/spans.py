@@ -32,11 +32,32 @@ Design constraints, each load-bearing:
   * **No jax dependency.**  Importable from the status verb and tests
     without touching a backend; the device annotation is a callable the
     run hands in (telemetry/runtime.start_run), never an import here.
+    JAX's compile stages reach the tracer through ``complete`` from
+    telemetry/jax_events.py, the one home of the program's
+    ``jax.monitoring`` listeners.
+
+What the host does besides the program's own code is recorded too, and
+only while the recorder is on (``start_host_hooks``, installed by
+``start_run`` and removed by ``finish``/``uninstall``):
+
+  * ``host/gc`` — a ``gc.callbacks`` hook times every collection and
+    keeps their running total (``gc_s``); a collection of
+    generation 1 or 2, or one of generation 0 that took
+    ``GC_SPAN_MIN_S`` or more, becomes a span under the span open on the
+    collecting thread.  The hook never takes ``_lock`` (a collection can
+    start inside ``_record`` while it is held, and the lock is not
+    re-entrant): it stages the span in a deque that the next
+    ``_record``, ``snapshot_events`` or ``export`` drains.
+  * process counters — a span opened with ``counters=True`` (the round,
+    its epilogue, the round's snapshot and the five phases) carries the
+    delta of ``gc_s`` over its interval.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import gc
 import itertools
 import json
 import os
@@ -57,6 +78,12 @@ _GUARDED_BY = {"events": "_lock", "dropped": "_lock",
 # recorded by the spec-scorer thread and one recorded by the main thread
 # can never collide, and ids stay unique across a run that swaps tracers.
 _SPAN_IDS = itertools.count(1)
+
+GC_SPAN = "host/gc"
+# A generation-0 collection shorter than this is counted in the totals but
+# is not a span of its own: hundreds of them run in a round, and one event
+# each would fill the bounded buffer with sub-millisecond pauses.
+GC_SPAN_MIN_S = 1e-3
 
 
 class Span:
@@ -105,6 +132,11 @@ class SpanTracer:
         self._wall_origin = time.time()
         self._local = threading.local()
         self._thread_names: Dict[int, str] = {}
+        # The collector hook's total and its staged spans (appended
+        # without the lock; see the module docstring).
+        self.gc_s = 0.0
+        self._gc_t0: Optional[float] = None
+        self._staged: collections.deque = collections.deque()
 
     # -- span stack (per thread, for nesting introspection) ---------------
 
@@ -124,13 +156,17 @@ class SpanTracer:
     # -- recording ---------------------------------------------------------
 
     @contextlib.contextmanager
-    def span(self, name: str, args: Optional[Dict[str, Any]] = None
-             ) -> Iterator[Span]:
+    def span(self, name: str, args: Optional[Dict[str, Any]] = None,
+             counters: bool = False) -> Iterator[Span]:
         """Open a nested span.  Always measures; records only when
         enabled.  The yielded Span's ``duration_s`` is valid after the
         block exits (phase_timer reads it for the metrics sink).  A site
         that learns a count only inside the block (steps run, bytes
-        written) adds it to ``sp.args`` before the block exits."""
+        written) adds it to ``sp.args`` before the block exits.  With
+        ``counters`` (and the recorder on) the span also carries the
+        process counters' deltas over its interval."""
+        base = self.process_counters() if counters and self.enabled \
+            else None
         stack = self._stack()
         sp = Span(name, args, parent=stack[-1] if stack else None)
         stack.append(sp)
@@ -144,19 +180,45 @@ class SpanTracer:
             sp.t1 = time.perf_counter()
             stack.pop()
             if self.enabled:
+                if base is not None:
+                    now = self.process_counters()
+                    sp.args = {**(sp.args or {}),
+                               **{k: now[k] - base[k] for k in now}}
                 self._record(sp)
+
+    def process_counters(self) -> Dict[str, Any]:
+        """The process-wide counters a span carries as deltas: collector
+        seconds (this tracer's hook)."""
+        return {"gc_s": self.gc_s}
 
     def complete(self, name: str, t0: float, t1: float,
                  args: Optional[Dict[str, Any]] = None) -> None:
-        """Record a span retroactively from perf_counter stamps — for
-        loop bodies only (the stream path's collect_pool chunks), where
-        a ``with`` per chunk would contort the control flow.  Its parent
-        is whatever span is open on the calling thread at the time of
-        the call; a retroactive span cannot be a device annotation."""
+        """Record a span retroactively from perf_counter stamps: the
+        stream path's collect_pool chunks, where a ``with`` per chunk
+        would contort the control flow, and a JAX compile stage, which
+        JAX reports only once it has ended (telemetry/jax_events.py).
+        Its parent is whatever span is open on the calling thread at the
+        time of the call, and it becomes the parent of what that span's
+        record already holds inside its interval on this thread (a
+        collection during a compile stage), so the tree stays a tree.  A
+        retroactive span cannot be a device annotation."""
         if not self.enabled:
             return
         sp = Span(name, args, parent=self.current())
         sp.t0, sp.t1 = t0, t1
+        self._drain()
+        ts, tid = (t0 - self._origin) * 1e6, sp.tid % 2**31
+        with self._lock:
+            # Newest first; a span of this thread that ended before ``t0``
+            # was recorded before anything that ran inside ``sp``.
+            for e in reversed(self.events):
+                a = e.get("args") or {}
+                if e.get("tid") != tid or "id" not in a:
+                    continue
+                if e["ts"] + e["dur"] < ts:
+                    break
+                if a["parent"] == sp.parent and e["ts"] >= ts:
+                    a["parent"] = sp.id
         self._record(sp)
 
     def name_thread(self, name: str) -> None:
@@ -204,6 +266,7 @@ class SpanTracer:
         """A consistent copy of the recorded events (the profiler's
         per-phase attribution intersects device ops with the host phase
         spans recorded here)."""
+        self._drain()
         with self._lock:
             return list(self.events)
 
@@ -228,21 +291,68 @@ class SpanTracer:
             self.events.extend(events)
         return len(events)
 
-    def _record(self, sp: Span) -> None:
-        event = {
-            "name": sp.name, "ph": "X", "cat": "host",
-            "ts": (sp.t0 - self._origin) * 1e6,
-            "dur": (sp.t1 - sp.t0) * 1e6,
-            "pid": os.getpid(), "tid": sp.tid % 2**31,
-        }
-        event["args"] = {**(sp.args or {}), "id": sp.id,
-                         "parent": sp.parent, "round": sp.round}
-        sp.event = event
+    def _record(self, sp: Optional[Span]) -> None:
+        """Append the span's event, and those of the collections staged
+        since the last call."""
+        spans = []
+        while True:
+            try:
+                spans.append(self._staged.popleft())
+            except IndexError:      # another thread may drain it too
+                break
+        if sp is not None:
+            spans.append(sp)
+        if not spans:
+            return
+        for s in spans:
+            s.event = {
+                "name": s.name, "ph": "X", "cat": "host",
+                "ts": (s.t0 - self._origin) * 1e6,
+                "dur": (s.t1 - s.t0) * 1e6,
+                "pid": os.getpid(), "tid": s.tid % 2**31,
+                "args": {**(s.args or {}), "id": s.id,
+                         "parent": s.parent, "round": s.round},
+            }
         with self._lock:
-            if len(self.events) >= self.max_events:
-                self.dropped += 1
-            else:
-                self.events.append(event)
+            for s in spans:
+                if len(self.events) >= self.max_events:
+                    self.dropped += 1
+                else:
+                    self.events.append(s.event)
+
+    def _drain(self) -> None:
+        if self.enabled:
+            self._record(None)
+
+    # -- the host's own activity ---------------------------------------------
+
+    def start_host_hooks(self) -> None:
+        """Install the collector hook (recording tracers only)."""
+        if self.enabled and self._on_gc not in gc.callbacks:
+            gc.callbacks.append(self._on_gc)
+
+    def stop_host_hooks(self) -> None:
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        """``gc.callbacks`` hook.  Takes no lock: the collection may have
+        started inside ``_record`` with ``_lock`` held."""
+        now = time.perf_counter()
+        if phase == "start":
+            self._gc_t0 = now
+            return
+        t0, self._gc_t0 = self._gc_t0, None
+        if t0 is None:
+            return
+        self.gc_s += now - t0
+        if info["generation"] > 0 or now - t0 >= GC_SPAN_MIN_S:
+            sp = Span(GC_SPAN, {"generation": info["generation"],
+                                "collected": info["collected"],
+                                "uncollectable": info["uncollectable"]},
+                      parent=self.current())
+            sp.t0, sp.t1 = t0, now
+            self._staged.append(sp)
 
     def amend(self, sp: Span, **counters: Any) -> None:
         """Counters a site learns only after its span closed (a count the
@@ -261,6 +371,7 @@ class SpanTracer:
         (None when recording is off — nothing to export)."""
         if not self.enabled:
             return None
+        self._drain()
         with self._lock:
             events = list(self.events)
             dropped = self.dropped
@@ -280,8 +391,11 @@ class SpanTracer:
         directory = os.path.dirname(os.path.abspath(path))
         os.makedirs(directory, exist_ok=True)
         tmp = f"{path}.tmp"
+        # One string, one write: ``json.dump`` encodes chunk by chunk in
+        # pure Python, ``dumps`` in one pass of the C encoder.
+        text = json.dumps(out)
         with open(tmp, "w") as fh:
-            json.dump(out, fh)
+            fh.write(text)
         os.replace(tmp, path)
         return path
 

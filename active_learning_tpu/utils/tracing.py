@@ -30,14 +30,16 @@ def phase_timer(name: str, round_idx: int, sink=None,
     """Wall-clock a phase, log it, and emit ``rd_{name}`` to the metrics
     sink — the reference's per-phase prints (main_al.py:160-178).  The
     timing IS the host span's: metric, log, trace event, device
-    annotation and heartbeat all read one measurement.  Yields
+    annotation and heartbeat all read one measurement.  With the
+    recorder on the span carries the collector's seconds over the
+    phase.  Yields
     the span so callers can read the same ``duration_s`` afterwards (the
     driver's overlap_frac accounting sums phase walls from it — still
     one measurement, never a second clock)."""
     logger = logger or get_logger()
     _tele_runtime.get_run().tick(force=True, phase=name, round=round_idx)
     with _tele_spans.get_tracer().span(
-            name, args={"round": round_idx}) as sp:
+            name, args={"round": round_idx}, counters=True) as sp:
         yield sp
     seconds = sp.duration_s
     logger.info(f"Rd {round_idx} {name} is {seconds:.3f}s")

@@ -148,7 +148,19 @@ TABLE = {
     # Not in the issue's table: the ladder's rollback copy of the model,
     # a device->host fetch before the round span opens.
     "ckpt/round_snapshot": ("experiment", 1),
+    # The round's bookkeeping: file writes and device queries between
+    # the phases and in the epilogue.
+    "round/resident_budget": ("round", 1),
+    "query/update": ("round", 1),
+    "round/emit": ("round_epilogue", 1),
+    "trace/export": ("round/emit", 1),
+    "round/placement": ("round_epilogue", 1),
+    "report/write": ("round_epilogue", 1),
 }
+# The spans that carry the process counters while the recorder is on.
+COUNTED = ("round", "round_epilogue", "ckpt/round_snapshot", "query_time",
+           "init_network_weights_time", "train_time", "load_best_ckpt_time",
+           "test_time")
 
 
 @pytest.fixture(scope="module")
@@ -249,6 +261,45 @@ class TestSpanSites:
         assert first["args"]["recompiled"]
         last = [e for e in events if e["name"] == "round_epilogue"][-1]
         assert "recompiled" not in last["args"]
+
+    def test_journal_and_heartbeat_writes_are_named(self, toy_run):
+        """Every journal rewrite and forced heartbeat of a round has a
+        span: the round start's and the boundaries' under the round, the
+        epilogue's under it and its emitters."""
+        events = _spans(toy_run["traceEvents"])
+        by_id = {e["args"]["id"]: e for e in events}
+        for rd in (1, 2):
+            def parents(name):
+                return sorted(by_id[e["args"]["parent"]]["name"]
+                              for e in events if e["name"] == name
+                              and e["args"]["round"] == rd)
+            journal = parents("journal/write")
+            # round start, after query / init / train, round end; then
+            # the epilogue's placement record.
+            assert journal.count("round") == 5
+            assert journal.count("round_epilogue") == 1
+            beats = parents("heartbeat/write")
+            assert "round" in beats and "round/emit" in beats
+
+    def test_process_counters_and_host_activity(self, toy_run):
+        events = _spans(toy_run["traceEvents"])
+        by_id = {e["args"]["id"]: e for e in events}
+        for e in events:
+            if e["name"] in COUNTED:
+                a = e["args"]
+                assert a["gc_s"] >= 0
+            elif "gc_s" in e["args"]:
+                raise AssertionError(f"{e['name']} carries counters")
+        # Round 0 compiles: each stage is a span inside the round's tree.
+        stages = [e for e in events if e["name"] == "jax/compile"
+                  and e["args"]["round"] == 0]
+        assert {e["args"]["stage"] for e in stages} == {"trace", "lower",
+                                                        "compile"}
+        assert all(e["args"]["fun_name"] for e in stages)
+        for e in events:
+            if e["name"] in ("host/gc", "jax/compile") \
+                    and e["args"]["parent"] is not None:
+                assert e["args"]["parent"] in by_id
 
     def test_best_handoff_counters(self, toy_run, toy_dir):
         """The three counters that say the best weights stayed where
