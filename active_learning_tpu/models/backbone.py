@@ -35,7 +35,9 @@ encoder (models/mla_moe.py) and the shortcut-connected MoE token encoder
       chosen, the slots the tiles were shaped for, and the chunks of slots
       the expert layers ran); the shortcut-connected one also
       ``pairs_zero`` (picks that fell on a zero-compute expert) and
-      ``pairs_routed`` (all picks: k x tokens x layers).
+      ``pairs_routed`` (all picks: k x tokens x layers); both
+      ``attn_fused`` (the attention calls that ran the fused causal
+      kernel, ops/attention.py: one a call a row on the TPU, 0 elsewhere).
   ``torch_key_to_flax(key)`` (optional)
       the checkpoint layout: a torch state-dict key -> (flax path, transform)
       as ``utils/pretrained.torch_key_to_flax`` maps the ResNets'.

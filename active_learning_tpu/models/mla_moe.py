@@ -31,13 +31,18 @@ case.
 Precision: encoder leaves are stored bfloat16; contractions take
 ``dtype`` operands (bfloat16 on the TPU) and accumulate in float32;
 RMSNorm, the softmax, the router (its matmul included) and the head are
-float32.  Kernels lie in the checkpoint's ``[out, in]`` layout, so a file
-is uploaded as it is read.
+float32.  The attention's causal core runs as one kernel where the program
+is lowered for the TPU on one device (``fused_causal_core``,
+ops/attention.py: the scores stay on chip and no block above the diagonal
+is computed) and as einsums elsewhere (``causal_core``), in the same
+precisions.  Kernels lie in the checkpoint's ``[out, in]`` layout, so a
+file is uploaded as it is read.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import re
 from typing import Any, Optional, Tuple
@@ -233,6 +238,54 @@ class _Part(nn.Module):
         return self.param(name, _ones, (width,), jnp.bfloat16)
 
 
+def causal_core(q, kv, k_r, *, scale: float, dtype):
+    """Causal attention over ``q`` ``[B, T, H, d_nope + r]`` (rotary part
+    last), ``kv`` ``[B, T, H, d_nope + d_v]`` (key part, then value) and
+    the one rotary key ``k_r`` ``[B, T, r]`` every head shares, float32:
+    the scores as one ``[B, H, T, T]`` float32 array, scaled, masked and
+    normalised in float32, the probabilities cast to ``dtype`` for the
+    product with the values.  Returns (``[B, T, H, d_v]`` in ``dtype``, 0 a
+    row: no call ran the fused kernel)."""
+    b, t, heads = q.shape[:3]
+    rope = k_r.shape[-1]
+    nope = q.shape[-1] - rope
+    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+        k_r[:, :, None, :], (b, t, heads, rope))], axis=-1)
+    scores = jnp.einsum("bthd,bshd->bhts", q.astype(dtype),
+                        k.astype(dtype), precision=_HI,
+                        preferred_element_type=jnp.float32)
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    probs = jax.nn.softmax(
+        jnp.where(causal, scores * scale, -jnp.inf), axis=-1)
+    out = jnp.einsum("bhts,bshd->bthd", probs.astype(dtype),
+                     kv[..., nope:].astype(dtype), precision=_HI,
+                     preferred_element_type=jnp.float32)
+    return out.astype(dtype), jnp.zeros((b,), jnp.int32)
+
+
+def fused_causal_core(q, kv, k_r, *, scale: float, dtype,
+                      interpret: bool = False):
+    """``causal_core`` as one kernel (ops/attention.py): the same steps in
+    the same precisions, the scores kept on chip and no block above the
+    diagonal computed, over the projections' own layouts.  Returns 1 a row: the
+    call ran it."""
+    from ..ops import attention  # Pallas: imported by the programs that run it
+    b, t, heads = q.shape[:3]
+    rope = k_r.shape[-1]
+    nope = q.shape[-1] - rope
+    d_v = kv.shape[-1] - nope
+
+    def padded(a):  # the rotary part, zero-padded to whole vregs
+        return jnp.pad(a, [(0, 0)] * (a.ndim - 1)
+                       + [(0, attention.lanes(rope) - rope)])
+    q = jnp.concatenate([q[..., :nope], padded(q[..., nope:])], axis=-1)
+    out = attention.causal_attention(
+        q.astype(dtype).reshape(b, t, -1), kv.astype(dtype).reshape(b, t, -1),
+        padded(k_r).astype(dtype), heads=heads, d_nope=nope, d_v=d_v,
+        scale=scale, interpret=interpret)
+    return out.reshape(b, t, heads, d_v), jnp.ones((b,), jnp.int32)
+
+
 class _Attention(_Part):
     """Multi-head latent attention over normalised rows ``x`` [B, T, d]."""
 
@@ -266,17 +319,20 @@ class _Attention(_Part):
         q_r = apply_rope(q[..., nope:], cos, sin)
         k_r = apply_rope(kv_a[..., cfg.kv_lora_rank:], cos, sin)
         q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
-        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
-            k_r[:, :, None, :], (b, t, heads, rope))], axis=-1)
-        scores = jnp.einsum("bthd,bshd->bhts", q.astype(dtype),
-                            k.astype(dtype), precision=_HI,
-                            preferred_element_type=jnp.float32)
-        causal = jnp.tril(jnp.ones((t, t), bool))
-        probs = jax.nn.softmax(
-            jnp.where(causal, scores * cfg.softmax_scale, -jnp.inf), axis=-1)
-        out = jnp.einsum("bhts,bshd->bthd", probs.astype(dtype),
-                         kv[..., nope:].astype(dtype), precision=_HI,
-                         preferred_element_type=jnp.float32)
+        # The causal core: one fused kernel where the program is lowered
+        # for the TPU on a process of one device and a grid step holds a
+        # head; the einsum form elsewhere, which the compiler partitions
+        # over a mesh where it cannot partition the kernel.  The count of
+        # calls that ran the kernel, a row each, is a counter.
+        how = dict(scale=cfg.softmax_scale, dtype=dtype)
+        cores = {"default": functools.partial(causal_core, **how)}
+        if jax.device_count() == 1:
+            from ..ops import attention
+            if attention.heads_per_step(heads, t, nope, rope, vd,
+                                        jnp.dtype(dtype).itemsize):
+                cores["tpu"] = functools.partial(fused_causal_core, **how)
+        out, fused = jax.lax.platform_dependent(q, kv, k_r, **cores)
+        self.sow("counters", "attn_fused", fused)
         return _dot(out.reshape(b, t, heads * vd),
                     self.weight("o_proj", d, heads * vd), dtype)
 
@@ -487,7 +543,7 @@ class MlaMoeClassifier(nn.Module):
     encoder_cls = MlaMoeEncoder
 
     # The backbone contract's optional parts (models/backbone.py).
-    row_counters = ("pairs_real", "pairs_run", "expert_trips")
+    row_counters = ("pairs_real", "pairs_run", "expert_trips", "attn_fused")
 
     # A forward-only encoder: linear evaluation is the one protocol it runs
     # (the registry's factory refuses to build it without

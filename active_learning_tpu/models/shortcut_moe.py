@@ -252,7 +252,7 @@ class ShortcutMoeClassifier(mla_moe.MlaMoeClassifier):
 
     encoder_cls = ShortcutMoeEncoder
     row_counters = ("pairs_real", "pairs_run", "expert_trips",
-                    "pairs_zero", "pairs_routed")
+                    "pairs_zero", "pairs_routed", "attn_fused")
 
     def torch_key_to_flax(self, key: str) -> Optional[Tuple]:
         """The published names: the list-valued sub-modules of a double

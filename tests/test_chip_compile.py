@@ -279,3 +279,40 @@ def test_axk1_expert_layer_runs_its_held_experts_batched(one_chip):
     assert {"12,512,2048", "12,512,7168"} <= shapes
     assert not {"512,2048", "512,7168"} & shapes
     assert compiled.memory_analysis().temp_size_in_bytes < 10 ** 9
+
+
+@pytest.mark.parametrize("devices", (1, 4))
+@pytest.mark.parametrize("preset", ("AXK1_EP16_L7", "LONGCAT_FLASH_EP32_L4"))
+def test_token_encoders_attention_is_one_fused_kernel(preset, devices,
+                                                       one_chip, monkeypatch):
+    """One attention call of each token-encoder cell at its published
+    widths (64 heads, qk 192, v 128) over 16 x 512 rows, lowered for the
+    TPU.  In a process of one device the causal core is the Mosaic kernel
+    (ops/attention.py), and no float32 ``[16, 64, 512, 512]`` scores
+    buffer is left in the module; in a process of four, which the compiler
+    could not partition the kernel over, it is the einsum form, which
+    writes them.  The call sows its ``attn_fused`` count, one entry a
+    row."""
+    from active_learning_tpu.models import mla_moe, shortcut_moe
+    monkeypatch.setattr(jax, "device_count", lambda *a: devices)
+    cfg = getattr(mla_moe, preset, None) or getattr(shortcut_moe, preset)
+    rope = (shortcut_moe if preset.startswith("LONGCAT") else
+            mla_moe).rope_tables
+    layer = mla_moe._Attention(cfg, jnp.bfloat16)
+    rep = mesh_lib.replicated_sharding(one_chip)
+    d = cfg.hidden_size
+    cos, sin = (_spec(a.shape, a.dtype, rep) for a in rope(cfg, 512))
+    params = jax.tree.map(
+        lambda s: _spec(s.shape, s.dtype, rep),
+        jax.eval_shape(lambda k, c, s: {"params": layer.init(
+            k, jnp.zeros((1, 512, d)), c, s)["params"]},
+            jax.random.PRNGKey(0), cos, sin))
+    lowered = jax.jit(
+        lambda p, x, c, s: layer.apply(p, x, c, s, mutable=["counters"])
+    ).lower(params, _spec((16, 512, d), jnp.float32, rep), cos, sin)
+    text = lowered.compile().as_text()
+    fused = devices == 1
+    assert ("tpu_custom_call" in text) == fused
+    assert bool(re.search(r"f32\[16,64,512,512\]", text)) != fused
+    counts = lowered.out_info[1]["counters"]["attn_fused"]
+    assert [(c.shape, c.dtype) for c in counts] == [((16,), jnp.int32)]

@@ -8,6 +8,7 @@ lopsided on purpose, and the frozen leaves after a fit and after rounds."""
 import dataclasses
 import json
 import os
+import subprocess
 import sys
 
 import jax
@@ -283,6 +284,94 @@ def test_a_chunk_holds_the_cells_routing():
     assert mla_moe.chunk_rows(6, 4, 16, 8) == 6
 
 
+def _attention_inputs(cfg, heads, t=512, seed=0):
+    """``q`` ``[1, t, heads, d_qk]``, ``kv`` ``[1, t, heads, d_nope +
+    d_v]`` and the shared rotary key ``[1, t, d_rope]``, float32 at a
+    configuration's head widths (unit-variance heads, so the scores spread
+    as the cells' do)."""
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.standard_normal(s), jnp.float32)
+                 for s in ((1, t, heads, cfg.qk_head_dim),
+                           (1, t, heads, cfg.qk_nope_head_dim
+                            + cfg.v_head_dim),
+                           (1, t, cfg.qk_rope_head_dim)))
+
+
+def _encoder_presets():
+    from active_learning_tpu.models import shortcut_moe
+    return (mla_moe.AXK1_EP16_L7, shortcut_moe.LONGCAT_FLASH_EP32_L4)
+
+
+@pytest.mark.parametrize("cfg", _encoder_presets(), ids=("axk1", "longcat"))
+def test_the_fused_core_is_the_einsum_core(cfg):
+    """The fused causal kernel (interpret mode) against the einsum form the
+    CPU runs, at the cells' head widths (qk 128 + 64, v 128) and T = 512
+    over four heads: both bfloat16 outputs of the same float32 steps, the
+    kernel adding the key part's scores to the rotary part's where the
+    einsum adds them in one product, so nearly every output is the same
+    bits and the rest differ by one bfloat16 rounding, of the output or,
+    where the output cancels to near zero, of a probability (a few
+    2**-12 of the output's scale); the count says which form ran."""
+    q, kv, k_r = _attention_inputs(cfg, heads=4)
+    how = dict(scale=cfg.softmax_scale, dtype=jnp.bfloat16)
+    want, none = mla_moe.causal_core(q, kv, k_r, **how)
+    got, ran = mla_moe.fused_causal_core(q, kv, k_r, interpret=True, **how)
+    assert got.dtype == want.dtype == jnp.bfloat16
+    assert got.shape == want.shape == (1, 512, 4, cfg.v_head_dim)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    # One bfloat16 step (2**-7 of the value's binade) at each output.
+    ulp = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(want), 2 ** -30)))
+                  - 7)
+    near_zero = 2.0 ** -12 * np.max(np.abs(want))
+    assert np.all(np.abs(got - want) <= np.maximum(ulp, near_zero))
+    assert np.mean(got == want) > 0.99
+    assert none.tolist() == [0] and ran.tolist() == [1]
+
+
+@pytest.mark.parametrize("cfg", _encoder_presets(), ids=("axk1", "longcat"))
+def test_the_fused_core_is_causal(cfg):
+    """A token changed at position s (its query, key, value and rotary key)
+    leaves every output before s bit-identical and moves the outputs from
+    s on: the kernel's mask is the causal one, across its blocks of
+    queries."""
+    inputs = _attention_inputs(cfg, heads=2)
+    how = dict(scale=cfg.softmax_scale, dtype=jnp.bfloat16, interpret=True)
+    base = np.asarray(mla_moe.fused_causal_core(*inputs, **how)[0],
+                      np.float32)
+    for s in (1, 127, 255, 256, 300, 511):
+        moved = np.asarray(mla_moe.fused_causal_core(
+            *(a.at[:, s].add(1.0) for a in inputs), **how)[0], np.float32)
+        assert np.array_equal(moved[:, :s], base[:, :s]), s
+        assert not np.array_equal(moved[:, s], base[:, s]), s
+
+
+def test_a_step_holds_the_heads_its_vmem_allows():
+    """Heads a grid step holds, from the sequence and head widths: eight of
+    the cells' 64 at T = 512 in bfloat16, fewer as T grows, and none where
+    one head's blocks outgrow VMEM or a key or value width is not whole
+    vregs (the model then keeps the einsum form on every platform)."""
+    from active_learning_tpu.ops.attention import heads_per_step
+    assert heads_per_step(64, 512, 128, 64, 128, 2) == 8
+    assert heads_per_step(64, 512, 128, 64, 128, 4) == 4
+    assert heads_per_step(64, 2048, 128, 64, 128, 2) == 1
+    assert heads_per_step(64, 4096, 128, 64, 128, 2) == 0
+    assert heads_per_step(4, 32, 16, 8, 16, 4) == 0
+
+
+def test_the_model_factory_imports_no_pallas():
+    """The kernel's module is imported by the programs that run it: building
+    any model, the ResNets' among them, and the experiment driver leave
+    Pallas out of the process."""
+    code = ("import sys, active_learning_tpu.models.factory, "
+            "active_learning_tpu.experiment.driver; "
+            "print(sorted(m for m in sys.modules if 'pallas' in m "
+            "or m.endswith('ops.attention')))")
+    out = subprocess.run([sys.executable, "-c", code], check=True,
+                         capture_output=True, text=True,
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert out.stdout.strip() == "[]"
+
+
 def _trainer(model, lr=0.1):
     from active_learning_tpu.config import (LoaderConfig, OptimizerConfig,
                                             SchedulerConfig, TrainConfig)
@@ -430,6 +519,8 @@ def test_spans_count_the_head_alone_and_the_pairs(rounds):
         assert 0 < a["pairs_real"] <= a["pairs_run"]
         # A chunk of slots a trip, each trip at least a tile of eight.
         assert 0 < a["expert_trips"] <= a["pairs_run"] // 8
+        # The CPU runs the einsum core: no call ran the fused kernel.
+        assert a["attn_fused"] == 0
 
 
 def test_presets_are_the_benchmarks_configurations():

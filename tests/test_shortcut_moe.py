@@ -467,6 +467,8 @@ def test_spans_carry_the_four_pair_counters(rounds):
         # k x tokens x layers, and about a third of it on zero experts.
         assert a["pairs_routed"] == 4 * a["tokens"] * 3
         assert 0.2 < a["pairs_zero"] / a["pairs_routed"] < 0.5
+        # The CPU runs the einsum core: no call ran the fused kernel.
+        assert a["attn_fused"] == 0
 
 
 def test_presets_are_the_benchmarks_configurations():
@@ -516,7 +518,7 @@ def test_the_registry_and_the_cli_take_the_encoder():
     assert model.freeze_feature is True
     assert backbone.frozen_prefixes(model) == ("encoder",)
     assert model.row_counters == ("pairs_real", "pairs_run", "expert_trips",
-                                  "pairs_zero", "pairs_routed")
+                                  "pairs_zero", "pairs_routed", "attn_fused")
 
 
 # What the parent commits gave for the A.X-K1 toy on seed 5's checkpoint and
@@ -576,6 +578,10 @@ def test_the_axk1_toy_reads_the_parents_bits_through_the_shared_loop(
     counters = flatten_dict(state["counters"])
     # The same slots give the same counters; one chunk an expert layer.
     assert [int(np.asarray(v).sum()) for k, v in sorted(counters.items())
-            if k[-1] != "expert_trips"] == AXK1_TOY_COUNTERS_AT_THE_PARENT
+            if k[-1] not in ("expert_trips", "attn_fused")
+            ] == AXK1_TOY_COUNTERS_AT_THE_PARENT
     assert [int(np.asarray(v).sum()) for k, v in sorted(counters.items())
             if k[-1] == "expert_trips"] == [1, 1]
+    # Three attention calls, none of them the fused kernel on the CPU.
+    assert [int(np.asarray(v).sum()) for k, v in sorted(counters.items())
+            if k[-1] == "attn_fused"] == [0, 0, 0]
